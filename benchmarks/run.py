@@ -19,6 +19,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (table5, table6, fig1_2_suite_vs_k,
                             fig3_4_per_benchmark, scheduler_ablation,
                             npb_kernels, tpu_campaign, roofline_bench,

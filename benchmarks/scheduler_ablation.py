@@ -55,6 +55,7 @@ from repro.core import (JSCC_SYSTEMS, FaultConfig, Scheduler, make_policy,
                         policy_names)
 from repro.core.engine import _batched_run
 from repro.core.systems import ComputeSystem
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.scenarios import (load_swf, make_stream_workload,
                                   swf_lines, synthetic_swf_arrays,
                                   workload_from_arrays, workload_from_trace)
@@ -444,8 +445,11 @@ def _median_campaign_sec(sched, w, repeats: int = 3) -> float:
 def _shard_scaling_row(J):
     """Sharded-vs-single-device wall-clock ratio on an 8-virtual-device
     CPU mesh (subprocess: the XLA device-count flag must be set before
-    jax initializes).  The ratio is machine-invariant — both sides run on
-    the same box in the same process — so it is gated directly: sharding
+    jax initializes).  The child is pinned to the CPU
+    (``JAX_PLATFORMS=cpu``) so it never contends for a chip its parent
+    holds, and its row says ``platform=cpu``.  The ratio is
+    machine-invariant — both sides run on the same box in the same
+    process — so it is gated directly: sharding
     the grid must never cost more than GATE x the single-device vmap
     (on a multi-core runner it should win; 8 virtual devices on one
     physical core merely round-trip through shard_map)."""
@@ -466,12 +470,14 @@ def med(**kw):
     return _median_campaign_sec(s, w)
 single = med()
 sharded = med(shards="auto")
-print(json.dumps({{"devices": len(jax.devices()),
+print(json.dumps({{"platform": jax.devices()[0].platform,
+                   "devices": len(jax.devices()),
                    "single_us": single * 1e6,
                    "sharded_us": sharded * 1e6}}))
 """
     here = pathlib.Path(__file__).resolve().parent
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"    # never contend for the parent's chip
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", "")).strip()
     env["PYTHONPATH"] = f"{here.parent / 'src'}:{here}"
@@ -481,7 +487,8 @@ print(json.dumps({{"devices": len(jax.devices()),
     rep = json.loads(out.stdout.splitlines()[-1])
     ratio = rep["sharded_us"] / rep["single_us"]
     return [("campaign_shard_scaling", rep["sharded_us"],
-             f"devices={rep['devices']};jobs={Js};lanes=8"
+             f"platform={rep['platform']};devices={rep['devices']}"
+             f";jobs={Js};lanes=8"
              f";single_us={rep['single_us']:.0f}"
              f";ratio_vs_single={ratio:.2f}")]
 
@@ -540,6 +547,7 @@ def main(argv=None):
                     help="comma-separated subset of: "
                          + ",".join(n for n, _ in SUITES))
     args = ap.parse_args(argv)
+    enable_compile_cache()
     wanted = set(args.suites.split(",")) if args.suites else None
     if wanted is not None:
         unknown = wanted - {n for n, _ in SUITES}

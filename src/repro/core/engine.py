@@ -68,8 +68,7 @@ from repro.core.workload_model import NPB_PROFILES, npb_tables
 from repro.kernels.kth_free import (kth_free_time, kth_free_time_rows,
                                     kth_free_time_shared)
 from repro.sharding.grid import (grid_spec as _grid_spec,
-                                 replicated as _replicated,
-                                 shard_map as _shard_map)
+                                 replicated as _replicated)
 
 
 @dataclass(frozen=True)
@@ -1800,8 +1799,8 @@ def _sharded_run(arrs, policy, seeds, faults, *, mesh, warm_start, placer,
             lambda p_, s_, f_: _scan_sim(arrs_, p_, warm_start, placer,
                                          totals_only, s_, f_, easy_eval,
                                          core, retries))(pol, sd, fv)
-    return _shard_map(
-        body, mesh=mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(_replicated, _grid_spec, _grid_spec, _grid_spec),
         out_specs=_grid_spec)(arrs, policy, seeds, faults)
 
@@ -1820,8 +1819,8 @@ def _chunk_init(arrs, policy, seeds, faults, *, mesh, warm_start, placer,
                 easy_eval, core, retries).carry0)(pol, sd, fv)
     if mesh is None:
         return body(arrs, policy, seeds, faults)
-    return _shard_map(
-        body, mesh=mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(_replicated, _grid_spec, _grid_spec, _grid_spec),
         out_specs=_grid_spec)(arrs, policy, seeds, faults)
 
@@ -1845,8 +1844,8 @@ def _chunk_advance(arrs, policy, seeds, faults, carries, xs, *, mesh,
         return jax.vmap(lane)(pol, sd, fv, carry)
     if mesh is None:
         return body(arrs, policy, seeds, faults, carries, xs)
-    return _shard_map(
-        body, mesh=mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(_replicated, _grid_spec, _grid_spec, _grid_spec,
                   _grid_spec, _replicated),
         out_specs=_grid_spec)(arrs, policy, seeds, faults, carries, xs)
@@ -1867,8 +1866,8 @@ def _chunk_finish(arrs, policy, seeds, faults, carries, ys, *, mesh,
             pol, sd, fv, carry, ys_)
     if mesh is None:
         return body(arrs, policy, seeds, faults, carries, ys)
-    return _shard_map(
-        body, mesh=mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(_replicated, _grid_spec, _grid_spec, _grid_spec,
                   _grid_spec, _grid_spec),
         out_specs=_grid_spec)(arrs, policy, seeds, faults, carries, ys)
@@ -2059,7 +2058,11 @@ class Scheduler:
         """Deprecated read alias of ``engine`` (docs/API.md migration)."""
         return self.engine
 
-    def run(self, w: Workload, *, totals_only: bool = False):
+    def _grid(self, w: Workload, totals_only: bool):
+        """The flat-batch inputs of one ``run``: workload arrays, the
+        leaf-batched policy, seeds and fault rows (padded to the mesh),
+        the static compile keys, and the axis bookkeeping ``run`` needs
+        to reshape and label the result."""
         pol = self.policy
         k = jnp.asarray(pol.k, jnp.float32)
         u = jnp.asarray(pol.ucb_scale, jnp.float32)
@@ -2124,22 +2127,11 @@ class Scheduler:
                 kb, ub, pb, fwb, sb, fbB = map(
                     padb, (kb, ub, pb, fwb, sb, fbB))
 
-        arrs = _workload_arrays(w)
         polb = replace(pol, k=kb, ucb_scale=ub, power_cap=pb,
                        freq_weight=fwb)
         common = dict(warm_start=self.warm_start, placer=self.placer,
                       totals_only=totals_only, easy_eval=self.easy_eval,
                       core=core, retries=retries)
-        if self.chunk is not None:
-            out = _run_chunked(arrs, polb, sb, fbB, chunk=self.chunk,
-                               mesh=mesh, **common)
-        elif mesh is not None:
-            out = _sharded_run(arrs, polb, sb, fbB, mesh=mesh, **common)
-        else:
-            out = _batched_run(arrs, polb, sb, fbB, **common)
-        if pad:
-            out = jax.tree.map(lambda x: x[:B], out)
-
         axes, lead = [], []
         for name, present, size in (("fault", has_fault_axis, F),
                                     ("policy", has_policy_axis, G),
@@ -2147,20 +2139,59 @@ class Scheduler:
             if present:
                 axes.append(name)
                 lead.append(size)
-        out = jax.tree.map(
-            lambda x: x.reshape(tuple(lead) + x.shape[1:]), out)
+        return dict(args=(_workload_arrays(w), polb, sb, fbB), mesh=mesh,
+                    common=common, B=B, pad=pad, axes=tuple(axes),
+                    lead=tuple(lead),
+                    policy_coord=replace(pol, k=k, ucb_scale=u, power_cap=pc,
+                                         freq_weight=fw))
 
-        meta = dict(axes=tuple(axes), n_jobs=int(len(w.prog)),
+    def lower(self, w: Workload, *, totals_only: bool = False):
+        """The ``jax.stages.Lowered`` program that runs this
+        configuration's scan over ``w``: the per-chunk advance at full
+        chunk length when ``chunk`` is set, else the batched (or sharded)
+        run.  ``.compile().as_text()`` shows what the backend made of it
+        — e.g. whether the Pallas placement kernel (``tpu_custom_call``)
+        is in the compiled step."""
+        g = self._grid(w, totals_only)
+        args, mesh, common = g["args"], g["mesh"], g["common"]
+        if self.chunk is None:
+            if mesh is not None:
+                return _sharded_run.lower(*args, mesh=mesh, **common)
+            return _batched_run.lower(*args, **common)
+        xs, length = _stream_xs(args[0], args[1], common["core"],
+                                common["retries"])
+        n = min(self.chunk, length)
+        carries = jax.eval_shape(
+            partial(_chunk_init, mesh=mesh, **common), *args)
+        xs_c = None if xs is None else jax.tree.map(lambda x: x[:n], xs)
+        return _chunk_advance.lower(*args, carries, xs_c, mesh=mesh,
+                                    nsteps=n, **common)
+
+    def run(self, w: Workload, *, totals_only: bool = False):
+        g = self._grid(w, totals_only)
+        args, mesh, common = g["args"], g["mesh"], g["common"]
+        if self.chunk is not None:
+            out = _run_chunked(*args, chunk=self.chunk, mesh=mesh, **common)
+        elif mesh is not None:
+            out = _sharded_run(*args, mesh=mesh, **common)
+        else:
+            out = _batched_run(*args, **common)
+        if g["pad"]:
+            out = jax.tree.map(lambda x: x[:g["B"]], out)
+
+        pol, axes, lead = self.policy, g["axes"], g["lead"]
+        out = jax.tree.map(lambda x: x.reshape(lead + x.shape[1:]), out)
+
+        meta = dict(axes=axes, n_jobs=int(len(w.prog)),
                     n_nodes=np.asarray(w.n_nodes), programs=w.programs,
                     systems=w.systems, freq_tiers=pol.freq_tiers)
         if not axes:
             return SimResult(**out, **meta)
         coords = {}
-        if has_fault_axis:
+        if "fault" in axes:
             coords["fault"] = self.faults
-        if has_policy_axis:
-            coords["policy"] = replace(pol, k=k, ucb_scale=u, power_cap=pc,
-                                       freq_weight=fw)
-        if has_seed_axis:
+        if "policy" in axes:
+            coords["policy"] = g["policy_coord"]
+        if "seed" in axes:
             coords["seed"] = self.seeds
         return CampaignResult(**out, **meta, coords=coords)

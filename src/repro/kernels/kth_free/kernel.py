@@ -75,38 +75,46 @@ def radix_select_kth_batched(node_free, n_req):
 
 
 def _kth_free_kernel(free_ref, nreq_ref, out_ref):
-    out_ref[...] = radix_select_kth(free_ref[...], nreq_ref[...][:, 0])
+    out_ref[...] = radix_select_kth(free_ref[...], nreq_ref[:, 0])[:, None]
 
 
 def kth_free_pallas(node_free, n_req, *, interpret: bool = True):
-    """node_free: [S, maxN] f32; n_req: [S] int32.  Returns [S] f32."""
+    """node_free: [S, maxN] f32; n_req: [S] int32.  Returns [S] f32.
+
+    Every block's last two dimensions equal the array's, the one form
+    Mosaic accepts for S and maxN off the (8, 128) tile.  The output is
+    therefore the 2-D column [S, 1], sliced back here: a 1-D (S,) block
+    compiles alone but not under ``vmap`` (the campaign grid, the session
+    pool), where it becomes (Squeezed, S) on an [L, S] array."""
     S, _ = node_free.shape
     return pl.pallas_call(
         _kth_free_kernel,
         in_specs=[pl.BlockSpec(node_free.shape, lambda: (0, 0)),
                   pl.BlockSpec((S, 1), lambda: (0, 0))],
-        out_specs=pl.BlockSpec((S,), lambda: (0,)),
-        out_shape=jax.ShapeDtypeStruct((S,), jnp.float32),
+        out_specs=pl.BlockSpec((S, 1), lambda: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((S, 1), jnp.float32),
         interpret=interpret,
-    )(node_free.astype(jnp.float32), n_req.astype(jnp.int32)[:, None])
+    )(node_free.astype(jnp.float32), n_req.astype(jnp.int32)[:, None])[:, 0]
 
 
 def _kth_free_kernel_batched(free_ref, nreq_ref, out_ref):
-    out_ref[...] = radix_select_kth(free_ref[0], nreq_ref[0, :, 0])[None]
+    out_ref[0] = radix_select_kth(free_ref[0], nreq_ref[0, :, 0])[:, None]
 
 
 def kth_free_pallas_batched(node_free, n_req, *, interpret: bool = True):
     """Pallas twin of ``radix_select_kth_batched``: the grid runs one
     program instance per candidate, each radix-selecting its own [S, maxN]
     block.  node_free: [W, S, maxN] f32; n_req: [W, S] int32.  Returns
-    [W, S] f32."""
+    [W, S] f32.  The output is [W, S, 1] in (1, S, 1) blocks, sliced back
+    here, for the tiling reason ``kth_free_pallas`` gives."""
     W, S, N = node_free.shape
     return pl.pallas_call(
         _kth_free_kernel_batched,
         grid=(W,),
         in_specs=[pl.BlockSpec((1, S, N), lambda w: (w, 0, 0)),
                   pl.BlockSpec((1, S, 1), lambda w: (w, 0, 0))],
-        out_specs=pl.BlockSpec((1, S), lambda w: (w, 0)),
-        out_shape=jax.ShapeDtypeStruct((W, S), jnp.float32),
+        out_specs=pl.BlockSpec((1, S, 1), lambda w: (w, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((W, S, 1), jnp.float32),
         interpret=interpret,
-    )(node_free.astype(jnp.float32), n_req.astype(jnp.int32)[..., None])
+    )(node_free.astype(jnp.float32),
+      n_req.astype(jnp.int32)[..., None])[..., 0]

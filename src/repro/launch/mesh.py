@@ -8,16 +8,7 @@ sets the 512-device XLA flag before jax initializes).
 from __future__ import annotations
 
 import jax
-
-
-def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the AxisType
-    enum) only exist from jax 0.5; older jaxlibs default every axis to Auto
-    already, so omit the argument there."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,7 +18,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_grid_mesh(shards="auto"):
@@ -41,7 +32,7 @@ def make_grid_mesh(shards="auto"):
     if not 1 <= n <= n_local:
         raise ValueError(f"shards={shards!r} not in 1..{n_local} "
                          f"(local devices)")
-    return _make_mesh((n,), ("grid",))
+    return jax.make_mesh((n,), ("grid",), axis_types=(AxisType.Auto,))
 
 
 def make_elastic_mesh(n_devices: int, model_parallel: int = 16):
@@ -49,5 +40,5 @@ def make_elastic_mesh(n_devices: int, model_parallel: int = 16):
     the elastic-restart path (data dim shrinks, model dim is preserved so
     checkpoints reshard without repartitioning logic)."""
     assert n_devices % model_parallel == 0
-    return _make_mesh((n_devices // model_parallel, model_parallel),
-                      ("data", "model"))
+    return jax.make_mesh((n_devices // model_parallel, model_parallel),
+                         ("data", "model"), axis_types=(AxisType.Auto,) * 2)

@@ -94,6 +94,7 @@ from repro.core.cliargs import (add_policy_options, add_scale_options,
 from repro.data.scenarios import (make_stream_workload, maintenance_windows,
                                   load_swf, workload_from_trace,
                                   NPB_SMALL, NPB_LARGE, ARRIVAL_KINDS)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _parse_outages(specs, n_systems):
@@ -164,6 +165,7 @@ def main():
                     help="empty profile tables (exploration phase)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     w = build_workload(args)
     pol = build_policy(args)

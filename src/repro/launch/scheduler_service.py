@@ -54,6 +54,7 @@ import sys
 
 from repro.core import JSCC_SYSTEMS, Scheduler, make_npb_workload
 from repro.core.cliargs import add_policy_options, build_fault, build_policy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.service import Dispatcher, SessionPool, whatif
 
 
@@ -183,6 +184,7 @@ def main(argv=None):
     ap.add_argument("--restore", action="store_true",
                     help="resume the latest checkpoint before reading input")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     w = make_npb_workload(JSCC_SYSTEMS)
     sched = Scheduler(build_policy(args), faults=build_fault(args),

@@ -1,10 +1,9 @@
 """Campaign-grid sharding primitives.
 
 The training side already spreads work over devices (``train/dp.py``);
-this module gives the scheduler's campaign engine the same machinery:
-a version-portable ``shard_map`` entry and the PartitionSpecs for a 1-D
-``("grid",)`` mesh that partitions the flat (fault x policy x seed)
-batch axis.  Importing this module never touches jax device state (the
+this module gives the scheduler's campaign engine the PartitionSpecs
+for a 1-D ``("grid",)`` mesh that partitions the flat (fault x policy x
+seed) batch axis.  Importing this module never touches jax device state (the
 dry-run contract shared with ``launch/mesh.py``).
 
 Per-grid-point simulations are embarrassingly parallel — the scan core
@@ -16,18 +15,7 @@ single-device vmap (asserted in tests/test_sharded_campaign.py).
 
 from __future__ import annotations
 
-from functools import partial
-
-import jax
 from jax.sharding import PartitionSpec
-
-# jax >= 0.5 exposes shard_map at top level with check_vma; older jaxlibs
-# keep the experimental entry with check_rep (same dance as train/dp.py)
-if hasattr(jax, "shard_map"):
-    shard_map = partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-    shard_map = partial(_shard_map_experimental, check_rep=False)
 
 #: the campaign mesh's one axis name (see launch.mesh.make_grid_mesh)
 GRID_AXIS = "grid"

@@ -18,22 +18,12 @@ DCN-connected slices.
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.optim import (AdamWConfig, adamw_update, compressed_psum,
                          init_error_state)
-
-# jax >= 0.5 exposes shard_map at top level with check_vma; older jaxlibs
-# keep it in jax.experimental with the check_rep spelling.
-if hasattr(jax, "shard_map"):
-    _shard_map = partial(jax.shard_map, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-    _shard_map = partial(_shard_map_experimental, check_rep=False)
 
 
 def make_dp_train_step(loss_fn, mesh, ocfg: AdamWConfig,
@@ -59,10 +49,10 @@ def make_dp_train_step(loss_fn, mesh, ocfg: AdamWConfig,
         return new_params, new_opt, err_state, loss, metrics["grad_norm"]
 
     rep = P()            # params/opt/err replicated across the mesh
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(rep, rep, rep, P(("pod", "data"))),
-        out_specs=(rep, rep, rep, rep, rep))
+        out_specs=(rep, rep, rep, rep, rep), check_vma=False)
     return jax.jit(mapped)
 
 

@@ -17,9 +17,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.optim import AdamWConfig
 from repro.train.dp import make_dp_train_step, init_dp_state
-from repro.launch.mesh import _make_mesh
+from jax.sharding import AxisType
 
-mesh = _make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                     axis_types=(AxisType.Auto,) * 2)
 target = jnp.linspace(-1.0, 1.0, 32)
 
 def loss_fn(params, batch):
