@@ -1,0 +1,9 @@
+"""Device nanoseconds per job-lane in the scan step's ``learn`` stage: the
+learned ``C_tab`` / ``T_tab`` / ``runs`` updates.  The join is
+``bench/stage_join.py``."""
+
+from bench.stage_join import ns_per_job_lane
+
+
+def read(run):
+    return ns_per_job_lane(run, "learn")

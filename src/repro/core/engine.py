@@ -67,6 +67,7 @@ from repro.core.result import SimResult, CampaignResult
 from repro.core.workload_model import NPB_PROFILES, npb_tables
 from repro.kernels.kth_free import (kth_free_time, kth_free_time_rows,
                                     kth_free_time_shared)
+from repro.obs import record as _record_programs, stage as _stage
 from repro.sharding.grid import (grid_spec as _grid_spec,
                                  replicated as _replicated)
 
@@ -188,10 +189,11 @@ def make_npb_workload(systems, order=("BT", "EP", "IS", "LU", "SP"),
 
 def _fault_factor(key, j, fvec):
     """fvec: [straggler_prob, straggler_factor, failure_prob, restart_ovh]."""
-    u = jax.random.uniform(jax.random.fold_in(key, j), (2,))
-    slow = jnp.where(u[0] < fvec[0], fvec[1], 1.0)
-    fail = jnp.where(u[1] < fvec[2], 1.0 + fvec[3], 1.0)
-    return slow * fail
+    with _stage("fault"):
+        u = jax.random.uniform(jax.random.fold_in(key, j), (2,))
+        slow = jnp.where(u[0] < fvec[0], fvec[1], 1.0)
+        fail = jnp.where(u[1] < fvec[2], 1.0 + fvec[3], 1.0)
+        return slow * fail
 
 
 def _workload_arrays(w: Workload) -> dict:
@@ -243,11 +245,12 @@ def _earliest(node_free, nreq_row, arr, placer, outage):
     radix select, floored at the arrival and pushed out of any open
     maintenance window.  Shared by the FCFS step, the EASY reservation /
     backfill guard, and the final placement."""
-    kth = kth_free_time(node_free, nreq_row, force=placer)
-    avail = jnp.maximum(arr, kth)
-    if outage is not None:
-        avail = _push_out_of_outage(avail, outage)
-    return kth, avail
+    with _stage("earliest"):
+        kth = kth_free_time(node_free, nreq_row, force=placer)
+        avail = jnp.maximum(arr, kth)
+        if outage is not None:
+            avail = _push_out_of_outage(avail, outage)
+        return kth, avail
 
 
 def _earliest_shared(node_free, nreq_rows, arr_col, placer, outage):
@@ -255,11 +258,12 @@ def _earliest_shared(node_free, nreq_rows, arr_col, placer, outage):
     table: [W, S] requests -> ([W, S] kth, [W, S] earliest start), via the
     shared-table kernel entry (one sort serves every candidate).
     ``arr_col``: [W, 1] per-candidate arrival floors."""
-    kth = kth_free_time_shared(node_free, nreq_rows, force=placer)
-    avail = jnp.maximum(arr_col, kth)
-    if outage is not None:
-        avail = _push_out_of_outage(avail, outage)
-    return kth, avail
+    with _stage("earliest"):
+        kth = kth_free_time_shared(node_free, nreq_rows, force=placer)
+        avail = jnp.maximum(arr_col, kth)
+        if outage is not None:
+            avail = _push_out_of_outage(avail, outage)
+        return kth, avail
 
 
 def _alloc_mask(node_free, sel, kth_sel, need):
@@ -267,18 +271,20 @@ def _alloc_mask(node_free, sel, kth_sel, need):
     below the kth free time, plus first-by-index ties at it (the python
     mirror's stable argsort picks the same nodes).  Exposed separately so
     the event core can mirror an allocation onto its node-power table."""
-    free_sel = node_free[sel]
-    below = free_sel < kth_sel
-    tie = free_sel == kth_sel
-    tie_rank = jnp.cumsum(tie) - 1
-    return below | (tie & (tie_rank < need - jnp.sum(below)))
+    with _stage("alloc"):
+        free_sel = node_free[sel]
+        below = free_sel < kth_sel
+        tie = free_sel == kth_sel
+        tie_rank = jnp.cumsum(tie) - 1
+        return below | (tie & (tie_rank < need - jnp.sum(below)))
 
 
 def _alloc(node_free, sel, kth_sel, need, finish):
     """Allocate the ``need`` earliest-free nodes of system ``sel`` until
     ``finish`` (see ``_alloc_mask`` for the tie-break)."""
-    take = _alloc_mask(node_free, sel, kth_sel, need)
-    return node_free.at[sel].set(jnp.where(take, finish, node_free[sel]))
+    with _stage("alloc"):
+        take = _alloc_mask(node_free, sel, kth_sel, need)
+        return node_free.at[sel].set(jnp.where(take, finish, node_free[sel]))
 
 
 def _idle_energy(arrs, makespan, busy):
@@ -446,68 +452,80 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
     def step(carry, xs):
         node_free, C_tab, T_tab, runs, acc = carry
         j, p, arr, kj = xs
-        # per-job effective K: explicit workload overrides win over the
-        # policy's (resolved at use — the xs carry the raw NaN-padded
-        # column, see _stream_xs)
-        k = jnp.where(jnp.isnan(kj), pol_k, kj)
+        with _stage("select"):
+            # per-job effective K: explicit workload overrides win over
+            # the policy's (resolved at use — the xs carry the raw
+            # NaN-padded column, see _stream_xs)
+            k = jnp.where(jnp.isnan(kj), pol_k, kj)
 
-        nreq_row = n_req[p]                                      # [S]
-        kth, avail = _earliest(node_free, nreq_row, arr, placer, outage)
+        with _stage("earliest"):
+            nreq_row = n_req[p]                                  # [S]
+            kth, avail = _earliest(node_free, nreq_row, arr, placer,
+                                   outage)
 
-        key = jax.random.fold_in(sel_key, j)
-        if tiered:
-            c_x, t_x, r_x, a_x, cp_x, tp_x = _tier_rows(
-                tt, p, C_tab[p], T_tab[p], runs[p], avail, C_pred[p],
-                T_pred[p])
-            sel_x = select(policy, c_row=c_x, t_row=t_x, runs_row=r_x,
-                           avail_row=a_x, k=k, c_pred_row=cp_x,
-                           t_pred_row=tp_x, key=key)
-            f = (sel_x // S).astype(jnp.int32)
-            sel = sel_x % S
-        else:
-            f = jnp.int32(0)
-            sel = select(
-                policy, c_row=C_tab[p], t_row=T_tab[p], runs_row=runs[p],
-                avail_row=avail, k=k, c_pred_row=C_pred[p],
-                t_pred_row=T_pred[p], key=key)
+        with _stage("select"):
+            key = jax.random.fold_in(sel_key, j)
+            if tiered:
+                c_x, t_x, r_x, a_x, cp_x, tp_x = _tier_rows(
+                    tt, p, C_tab[p], T_tab[p], runs[p], avail, C_pred[p],
+                    T_pred[p])
+                sel_x = select(policy, c_row=c_x, t_row=t_x, runs_row=r_x,
+                               avail_row=a_x, k=k, c_pred_row=cp_x,
+                               t_pred_row=tp_x, key=key)
+                f = (sel_x // S).astype(jnp.int32)
+                sel = sel_x % S
+            else:
+                f = jnp.int32(0)
+                sel = select(
+                    policy, c_row=C_tab[p], t_row=T_tab[p],
+                    runs_row=runs[p], avail_row=avail, k=k,
+                    c_pred_row=C_pred[p], t_pred_row=T_pred[p], key=key)
 
-        factor = _fault_factor(fault_key, j, fvec)
-        # tables learn base (tier-0) observations — a tier choice changes
-        # the realized runtime/energy, never the learned profile
-        C_act = C_true[p, sel] * factor
-        T_upd = T_true[p, sel] * factor
-        if tiered:
-            T_act = tt["T"][p, f, sel] * factor
-            E_act = tt["E"][p, f, sel] * factor
-        else:
-            T_act = T_upd
-            E_act = E_true[p, sel] * factor
-        start = avail[sel]
-        finish = start + T_act
+        with _stage("fault"):
+            factor = _fault_factor(fault_key, j, fvec)
+            # tables learn base (tier-0) observations — a tier choice
+            # changes the realized runtime/energy, never the learned
+            # profile
+            C_act = C_true[p, sel] * factor
+            T_upd = T_true[p, sel] * factor
+            if tiered:
+                T_act = tt["T"][p, f, sel] * factor
+                E_act = tt["E"][p, f, sel] * factor
+            else:
+                T_act = T_upd
+                E_act = E_true[p, sel] * factor
 
-        need = nreq_row[sel]
-        node_free = _alloc(node_free, sel, kth[sel], need, finish)
+        with _stage("alloc"):
+            start = avail[sel]
+            finish = start + T_act
+            need = nreq_row[sel]
+            node_free = _alloc(node_free, sel, kth[sel], need, finish)
 
-        n = runs[p, sel].astype(jnp.float32)
-        C_tab = C_tab.at[p, sel].set((C_tab[p, sel] * n + C_act) / (n + 1))
-        T_tab = T_tab.at[p, sel].set((T_tab[p, sel] * n + T_upd) / (n + 1))
-        runs = runs.at[p, sel].add(1)
+        with _stage("learn"):
+            n = runs[p, sel].astype(jnp.float32)
+            C_tab = C_tab.at[p, sel].set((C_tab[p, sel] * n + C_act)
+                                         / (n + 1))
+            T_tab = T_tab.at[p, sel].set((T_tab[p, sel] * n + T_upd)
+                                         / (n + 1))
+            runs = runs.at[p, sel].add(1)
 
-        wait = start - arr
-        if totals_only:
-            sums, comps, fin_max, busy, wait_max = acc
-            # Kahan-compensated f32 sums: 10^5 sequential adds would
-            # otherwise drift ~0.1% vs the full path's array reduction
-            # (x64 is unavailable, so compensation stands in for f64)
-            add = jnp.stack([E_act, wait, (wait + T_act) / T_act])
-            y = add - comps
-            t = sums + y
-            acc = (t, (t - sums) - y, jnp.maximum(fin_max, finish),
-                   busy.at[sel].add(T_act * need),
-                   jnp.maximum(wait_max, wait))
-            out = None
-        else:
-            out = (sel, start, finish, wait, E_act, T_act, f)
+        with _stage("account"):
+            wait = start - arr
+            if totals_only:
+                sums, comps, fin_max, busy, wait_max = acc
+                # Kahan-compensated f32 sums: 10^5 sequential adds would
+                # otherwise drift ~0.1% vs the full path's array
+                # reduction (x64 is unavailable, so compensation stands
+                # in for f64)
+                add = jnp.stack([E_act, wait, (wait + T_act) / T_act])
+                y = add - comps
+                t = sums + y
+                acc = (t, (t - sums) - y, jnp.maximum(fin_max, finish),
+                       busy.at[sel].add(T_act * need),
+                       jnp.maximum(wait_max, wait))
+                out = None
+            else:
+                out = (sel, start, finish, wait, E_act, T_act, f)
         return (node_free, C_tab, T_tab, runs, acc), out
 
     acc0 = ((jnp.zeros(3, jnp.float32), jnp.zeros(3, jnp.float32),
@@ -628,14 +646,16 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
     def sel_for(j, node_free, C_tab, T_tab, runs):
         """Policy selection + earliest start for job id j (sentinel-safe:
         j == J evaluates job J-1; callers mask the result)."""
-        jj = jnp.minimum(j, J - 1)
-        p = prog[jj]
-        kth, avail = _earliest(node_free, n_req[p], arrival[jj], placer,
-                               outage)
-        sel = select(
-            policy, c_row=C_tab[p], t_row=T_tab[p], runs_row=runs[p],
-            avail_row=avail, k=k_of(jj), c_pred_row=C_pred[p],
-            t_pred_row=T_pred[p], key=jax.random.fold_in(sel_key, jj))
+        with _stage("earliest"):
+            jj = jnp.minimum(j, J - 1)
+            p = prog[jj]
+            kth, avail = _earliest(node_free, n_req[p], arrival[jj], placer,
+                                   outage)
+        with _stage("select"):
+            sel = select(
+                policy, c_row=C_tab[p], t_row=T_tab[p], runs_row=runs[p],
+                avail_row=avail, k=k_of(jj), c_pred_row=C_pred[p],
+                t_pred_row=T_pred[p], key=jax.random.fold_in(sel_key, jj))
         return jj, p, kth, avail, sel
 
     def eval_candidates(node_free, C_tab, T_tab, runs, pend):
@@ -644,59 +664,66 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
         Returns per-slot [Wc]-leading arrays: job ids, programs, chosen
         systems, starts, actual runtimes, fault factors, node needs, and
         the [Wc, S, maxN] tentative-allocation stack."""
-        jjs = jnp.minimum(pend, J - 1)                            # [Wc]
-        ps = prog[jjs]                                            # [Wc]
-        kths, avails = _earliest_shared(node_free, n_req[ps],
-                                        arrival[jjs][:, None], placer,
-                                        outage)                   # [Wc, S]
-        keys = jax.vmap(lambda j: jax.random.fold_in(sel_key, j))(jjs)
-        if tiered:
-            c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
-                tt, ps, C_tab[ps], T_tab[ps], runs[ps], avails,
-                C_pred[ps], T_pred[ps])
-            sels_x = select_batched(
-                policy, c_rows=c_x, t_rows=t_x, runs_rows=runs_x,
-                avail_rows=avail_x, k=k_of(jjs), c_pred_rows=cp_x,
-                t_pred_rows=tp_x, keys=keys)                      # [Wc]
-            fs = (sels_x // S).astype(jnp.int32)
-            sels = sels_x % S
-        else:
-            sels = select_batched(
-                policy, c_rows=C_tab[ps], t_rows=T_tab[ps],
-                runs_rows=runs[ps], avail_rows=avails, k=k_of(jjs),
-                c_pred_rows=C_pred[ps], t_pred_rows=T_pred[ps],
-                keys=keys)                                        # [Wc]
-            fs = jnp.zeros(Wc, jnp.int32)
+        with _stage("earliest"):
+            jjs = jnp.minimum(pend, J - 1)                        # [Wc]
+            ps = prog[jjs]                                        # [Wc]
+            kths, avails = _earliest_shared(
+                node_free, n_req[ps], arrival[jjs][:, None], placer,
+                outage)                                           # [Wc, S]
+        with _stage("select"):
+            keys = jax.vmap(lambda j: jax.random.fold_in(sel_key, j))(jjs)
+            if tiered:
+                c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
+                    tt, ps, C_tab[ps], T_tab[ps], runs[ps], avails,
+                    C_pred[ps], T_pred[ps])
+                sels_x = select_batched(
+                    policy, c_rows=c_x, t_rows=t_x, runs_rows=runs_x,
+                    avail_rows=avail_x, k=k_of(jjs), c_pred_rows=cp_x,
+                    t_pred_rows=tp_x, keys=keys)                  # [Wc]
+                fs = (sels_x // S).astype(jnp.int32)
+                sels = sels_x % S
+            else:
+                sels = select_batched(
+                    policy, c_rows=C_tab[ps], t_rows=T_tab[ps],
+                    runs_rows=runs[ps], avail_rows=avails, k=k_of(jjs),
+                    c_pred_rows=C_pred[ps], t_pred_rows=T_pred[ps],
+                    keys=keys)                                    # [Wc]
+                fs = jnp.zeros(Wc, jnp.int32)
         factors = jax.vmap(lambda j: _fault_factor(fault_key, j, fvec))(jjs)
-        idx = jnp.arange(Wc)
-        starts = avails[idx, sels]                                # [Wc]
-        T_acts = (tt["T"][ps, fs, sels] if tiered
-                  else T_true[ps, sels]) * factors
-        needs = n_req[ps, sels]
-        trials = jax.vmap(_alloc, in_axes=(None, 0, 0, 0, 0))(
-            node_free, sels, kths[idx, sels], needs, starts + T_acts)
+        with _stage("alloc"):
+            idx = jnp.arange(Wc)
+            starts = avails[idx, sels]                            # [Wc]
+        with _stage("fault"):
+            T_acts = (tt["T"][ps, fs, sels] if tiered
+                      else T_true[ps, sels]) * factors
+        with _stage("alloc"):
+            needs = n_req[ps, sels]
+            trials = jax.vmap(_alloc, in_axes=(None, 0, 0, 0, 0))(
+                node_free, sels, kths[idx, sels], needs, starts + T_acts)
         return jjs, ps, sels, fs, starts, T_acts, factors, needs, trials
 
     def step(carry, xs):
         node_free, C_tab, T_tab, runs, acc, pend, nbf = carry
         jx, now = xs
 
-        # push the arrival into the first sentinel slot (the invariant
-        # size <= W at step start keeps the index in range; drain steps
-        # push the sentinel J over a sentinel — a no-op)
-        size0 = jnp.sum(pend < J)
-        pend = pend.at[jnp.minimum(size0, Wc - 1)].set(jx)
-        size = size0 + (jx < J)
-        forced = size == Wc                       # window full: FCFS fallback
-        head_valid = pend[0] < J
+        with _stage("push"):
+            # push the arrival into the first sentinel slot (the
+            # invariant size <= W at step start keeps the index in range;
+            # drain steps push the sentinel J over a sentinel — a no-op)
+            size0 = jnp.sum(pend < J)
+            pend = pend.at[jnp.minimum(size0, Wc - 1)].set(jx)
+            size = size0 + (jx < J)
+            forced = size == Wc                   # window full: FCFS fallback
+            head_valid = pend[0] < J
 
         if easy_eval == "batched":
             # one batched evaluation of all Wc slots; slot 0 is the head
             jjs, ps, sels, fs, starts, T_acts, factors, needs, trials = \
                 eval_candidates(node_free, C_tab, T_tab, runs, pend)
-            hj, p_h, sel_h = jjs[0], ps[0], sels[0]
-            r_h = starts[0]                       # head reservation
-            place_head = head_valid & (forced | (r_h <= now))
+            with _stage("select"):
+                hj, p_h, sel_h = jjs[0], ps[0], sels[0]
+                r_h = starts[0]                   # head reservation
+                place_head = head_valid & (forced | (r_h <= now))
 
             # EASY no-delay guard for ALL candidates at once: a trial can
             # only delay the head on the head's RESERVED system, so one
@@ -706,114 +733,140 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
             # (every kth mode is bit-exact, so absent an explicit placer
             # the recheck picks the cheapest: one sort op over [Wc, maxN]
             # beats Wc radix walks inside a scan)
-            kth_h2 = kth_free_time(
-                trials[:, sel_h, :],
-                jnp.broadcast_to(n_req[p_h, sel_h], (Wc,)),
-                force=placer or "sort")
-            avail_h2 = jnp.maximum(arrival[hj], kth_h2)           # [Wc]
-            if outage is not None:
-                # only sel_h's windows apply; [1, W0, 2] broadcasts the
-                # shared push over the [Wc] candidate vector
-                avail_h2 = _push_out_of_outage(avail_h2, outage[sel_h][None])
-            ok = avail_h2 <= r_h                                  # [Wc]
+            with _stage("earliest"):
+                kth_h2 = kth_free_time(
+                    trials[:, sel_h, :],
+                    jnp.broadcast_to(n_req[p_h, sel_h], (Wc,)),
+                    force=placer or "sort")
+                avail_h2 = jnp.maximum(arrival[hj], kth_h2)       # [Wc]
+                if outage is not None:
+                    # only sel_h's windows apply; [1, W0, 2] broadcasts
+                    # the shared push over the [Wc] candidate vector
+                    avail_h2 = _push_out_of_outage(avail_h2,
+                                                   outage[sel_h][None])
+                ok = avail_h2 <= r_h                              # [Wc]
 
-            # first-fit == masked argmin over slot index (Wc = none)
-            idx = jnp.arange(Wc)
-            elig = jnp.where(idx == 0, place_head,
-                             head_valid & ~place_head & (pend < J) & ok)
-            chosen = jnp.min(jnp.where(elig, idx, Wc))
-            placed = chosen < Wc
-            ci = jnp.minimum(chosen, Wc - 1)
+            with _stage("select"):
+                # first-fit == masked argmin over slot index (Wc = none)
+                idx = jnp.arange(Wc)
+                elig = jnp.where(idx == 0, place_head,
+                                 head_valid & ~place_head & (pend < J) & ok)
+                chosen = jnp.min(jnp.where(elig, idx, Wc))
+                placed = chosen < Wc
+                ci = jnp.minimum(chosen, Wc - 1)
 
-            # gather the chosen slot: its trial allocation was computed
-            # against the real starting node_free, so it IS the placement
-            jj, p, sel, f = jjs[ci], ps[ci], sels[ci], fs[ci]
-            factor = factors[ci]
-            T_act = T_acts[ci]
-            start = starts[ci]
-            need = needs[ci]
-            j_pl = jnp.where(placed, pend[ci], J)
-            node_free = jnp.where(placed, trials[ci], node_free)
+            with _stage("alloc"):
+                # gather the chosen slot: its trial allocation was
+                # computed against the real starting node_free, so it IS
+                # the placement
+                jj, p, sel, f = jjs[ci], ps[ci], sels[ci], fs[ci]
+                factor = factors[ci]
+                T_act = T_acts[ci]
+                start = starts[ci]
+                need = needs[ci]
+                j_pl = jnp.where(placed, pend[ci], J)
+                node_free = jnp.where(placed, trials[ci], node_free)
         else:
             # head-of-queue reservation from current node-free times
             h = pend[0]
             hj, p_h, _, avail_h, sel_h = sel_for(h, node_free, C_tab, T_tab,
                                                  runs)
-            r_h = avail_h[sel_h]
-            place_head = head_valid & (forced | (r_h <= now))
+            with _stage("select"):
+                r_h = avail_h[sel_h]
+                place_head = head_valid & (forced | (r_h <= now))
 
-            # EASY backfill: first pending job (arrival order) whose
-            # tentative allocation cannot delay the head's reservation on
-            # its reserved system
-            chosen = jnp.where(place_head, 0, Wc)     # slot index; Wc = none
-            may_backfill = head_valid & ~place_head
+                # EASY backfill: first pending job (arrival order) whose
+                # tentative allocation cannot delay the head's
+                # reservation on its reserved system
+                chosen = jnp.where(place_head, 0, Wc)  # slot; Wc = none
+                may_backfill = head_valid & ~place_head
             for ci in range(1, Wc):
-                b = pend[ci]
-                live = may_backfill & (b < J) & (chosen == Wc)
+                with _stage("select"):
+                    b = pend[ci]
+                    live = may_backfill & (b < J) & (chosen == Wc)
                 bj, p_b, kth_b, avail_b, sel_b = sel_for(b, node_free, C_tab,
                                                          T_tab, runs)
-                s_b = avail_b[sel_b]
-                fin_b = s_b + T_true[p_b, sel_b] * _fault_factor(
-                    fault_key, bj, fvec)
-                trial = _alloc(node_free, sel_b, kth_b[sel_b],
-                               n_req[p_b, sel_b], fin_b)
-                _, avail_h2 = _earliest(trial, n_req[p_h], arrival[hj],
-                                        placer, outage)
-                ok = avail_h2[sel_h] <= r_h
-                chosen = jnp.where(live & ok, ci, chosen)
+                with _stage("alloc"):
+                    s_b = avail_b[sel_b]
+                    fin_b = s_b + T_true[p_b, sel_b] * _fault_factor(
+                        fault_key, bj, fvec)
+                    trial = _alloc(node_free, sel_b, kth_b[sel_b],
+                                   n_req[p_b, sel_b], fin_b)
+                with _stage("earliest"):
+                    _, avail_h2 = _earliest(trial, n_req[p_h], arrival[hj],
+                                            placer, outage)
+                    ok = avail_h2[sel_h] <= r_h
+                with _stage("select"):
+                    chosen = jnp.where(live & ok, ci, chosen)
 
             # place the chosen job (if any): same math as the FCFS step
-            placed = chosen < Wc
-            j_pl = jnp.where(placed, pend[jnp.minimum(chosen, Wc - 1)], J)
+            with _stage("select"):
+                placed = chosen < Wc
+                j_pl = jnp.where(placed, pend[jnp.minimum(chosen, Wc - 1)],
+                                 J)
             jj, p, kth, avail, sel = sel_for(j_pl, node_free, C_tab, T_tab,
                                              runs)
-            f = jnp.int32(0)                      # unrolled path is untier
-            factor = _fault_factor(fault_key, jj, fvec)
-            T_act = T_true[p, sel] * factor
-            start = avail[sel]
-            need = n_req[p, sel]
-            node_free = jnp.where(
-                placed,
-                _alloc(node_free, sel, kth[sel], need, start + T_act),
-                node_free)
+            with _stage("fault"):
+                f = jnp.int32(0)                  # unrolled path is untier
+                factor = _fault_factor(fault_key, jj, fvec)
+                T_act = T_true[p, sel] * factor
+            with _stage("alloc"):
+                start = avail[sel]
+                need = n_req[p, sel]
+                node_free = jnp.where(
+                    placed,
+                    _alloc(node_free, sel, kth[sel], need, start + T_act),
+                    node_free)
 
-        # learned tables always absorb BASE (tier-0) observations; the
-        # recorded energy/runtime use the tier-scaled values
-        C_act = C_true[p, sel] * factor
-        T_upd = T_true[p, sel] * factor
-        E_act = (tt["E"][p, f, sel] if tiered else E_true[p, sel]) * factor
-        finish = start + T_act
+        with _stage("fault"):
+            # learned tables always absorb BASE (tier-0) observations;
+            # the recorded energy/runtime use the tier-scaled values
+            C_act = C_true[p, sel] * factor
+            T_upd = T_true[p, sel] * factor
+            E_act = (tt["E"][p, f, sel] if tiered
+                     else E_true[p, sel]) * factor
+        with _stage("alloc"):
+            finish = start + T_act
 
-        n = runs[p, sel].astype(jnp.float32)
-        C_tab = C_tab.at[p, sel].set(jnp.where(
-            placed, (C_tab[p, sel] * n + C_act) / (n + 1), C_tab[p, sel]))
-        T_tab = T_tab.at[p, sel].set(jnp.where(
-            placed, (T_tab[p, sel] * n + T_upd) / (n + 1), T_tab[p, sel]))
-        runs = runs.at[p, sel].add(jnp.where(placed, 1, 0))
+        with _stage("learn"):
+            n = runs[p, sel].astype(jnp.float32)
+            C_tab = C_tab.at[p, sel].set(jnp.where(
+                placed, (C_tab[p, sel] * n + C_act) / (n + 1),
+                C_tab[p, sel]))
+            T_tab = T_tab.at[p, sel].set(jnp.where(
+                placed, (T_tab[p, sel] * n + T_upd) / (n + 1),
+                T_tab[p, sel]))
+            runs = runs.at[p, sel].add(jnp.where(placed, 1, 0))
 
-        was_backfill = placed & (chosen > 0)
-        nbf = nbf + was_backfill.astype(jnp.int32)
+        with _stage("account"):
+            was_backfill = placed & (chosen > 0)
+            nbf = nbf + was_backfill.astype(jnp.int32)
 
-        # pop the chosen slot (shift the tail left; chosen == Wc: no-op)
-        shifted = jnp.concatenate([pend[1:], jnp.full((1,), J, jnp.int32)])
-        pend = jnp.where(jnp.arange(Wc) < chosen, pend, shifted)
+        with _stage("push"):
+            # pop the chosen slot (shift the tail left; chosen == Wc:
+            # no-op)
+            shifted = jnp.concatenate([pend[1:],
+                                       jnp.full((1,), J, jnp.int32)])
+            pend = jnp.where(jnp.arange(Wc) < chosen, pend, shifted)
 
-        wait = start - arrival[jj]
-        if totals_only:
-            sums, comps, fin_max, busy, wait_max = acc
-            add = jnp.where(placed,
-                            jnp.stack([E_act, wait, (wait + T_act) / T_act]),
-                            0.0)
-            y = add - comps
-            t = sums + y
-            acc = (t, (t - sums) - y,
-                   jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
-                   busy.at[sel].add(jnp.where(placed, T_act * need, 0.0)),
-                   jnp.maximum(wait_max, jnp.where(placed, wait, 0.0)))
-            out = None
-        else:
-            out = (j_pl, sel, start, finish, wait, E_act, T_act,
-                   was_backfill, f)
+        with _stage("account"):
+            wait = start - arrival[jj]
+            if totals_only:
+                sums, comps, fin_max, busy, wait_max = acc
+                add = jnp.where(
+                    placed, jnp.stack([E_act, wait, (wait + T_act) / T_act]),
+                    0.0)
+                y = add - comps
+                t = sums + y
+                acc = (t, (t - sums) - y,
+                       jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
+                       busy.at[sel].add(jnp.where(placed, T_act * need,
+                                                  0.0)),
+                       jnp.maximum(wait_max, jnp.where(placed, wait, 0.0)))
+                out = None
+            else:
+                out = (j_pl, sel, start, finish, wait, E_act, T_act,
+                       was_backfill, f)
         return (node_free, C_tab, T_tab, runs, acc, pend, nbf), out
 
     acc0 = ((jnp.zeros(3, jnp.float32), jnp.zeros(3, jnp.float32),
@@ -1042,256 +1095,290 @@ def make_event_step(policy: Policy, placer: str | None = None,
         k_of = lambda j: jnp.where(jnp.isnan(arrs["k_job"][j]), pol_k,
                                    arrs["k_job"][j])
         J = prog.shape[0]
-        exists = arrs["free0"] < BIG                             # [S, maxN]
-        idle_mat = jnp.where(exists, idle_w[:, None], 0.0)       # [S, maxN]
-        pc = jnp.asarray(policy.power_cap, jnp.float32)
-        capped = pc < UNCAPPED                                   # traced
-        out_ends = (None if outage is None
-                    else outage[..., 1].reshape(-1))             # [S*W0]
+        with _stage("select"):
+            exists = arrs["free0"] < BIG                         # [S, maxN]
+            idle_mat = jnp.where(exists, idle_w[:, None], 0.0)   # [S, maxN]
+            pc = jnp.asarray(policy.power_cap, jnp.float32)
+            capped = pc < UNCAPPED                               # traced
+        with _stage("advance"):
+            out_ends = (None if outage is None
+                        else outage[..., 1].reshape(-1))         # [S*W0]
 
         (node_free, node_pow, C_tab, T_tab, runs, acc, busy,
          pend, t0s, rts, accTs, accFs, accWs, s0s, pblocks,
          a, now, nbf, peak, cdel) = carry
 
         # ---- push: admit the next arrival if due and there is room
-        size0 = jnp.sum(pend < J)
-        arr_a = arrival[jnp.minimum(a, J - 1)]
-        do_push = (a < J) & (size0 < Wc) & (arr_a <= now)
-        slot = jnp.minimum(size0, Wc - 1)
+        with _stage("push"):
+            size0 = jnp.sum(pend < J)
+            arr_a = arrival[jnp.minimum(a, J - 1)]
+            do_push = (a < J) & (size0 < Wc) & (arr_a <= now)
+            slot = jnp.minimum(size0, Wc - 1)
 
-        def pushed(arr, val):
-            return arr.at[slot].set(jnp.where(do_push, val, arr[slot]))
-        pend = pushed(pend, a.astype(jnp.int32))
-        t0s = pushed(t0s, arr_a)
-        rts = pushed(rts, False)
-        accTs = pushed(accTs, 0.0)
-        accFs = pushed(accFs, 0.0)
-        accWs = pushed(accWs, 0.0)
-        s0s = pushed(s0s, 0.0)
-        pblocks = pushed(pblocks, BIG)
-        a = a + do_push
+            def pushed(arr, val):
+                return arr.at[slot].set(jnp.where(do_push, val, arr[slot]))
+            pend = pushed(pend, a.astype(jnp.int32))
+            t0s = pushed(t0s, arr_a)
+            rts = pushed(rts, False)
+            accTs = pushed(accTs, 0.0)
+            accFs = pushed(accFs, 0.0)
+            accWs = pushed(accWs, 0.0)
+            s0s = pushed(s0s, 0.0)
+            pblocks = pushed(pblocks, BIG)
+            a = a + do_push
 
         # ---- next event (pre-placement state; used by advance + the
         # stuck valve).  Completions are node-free times > now.
-        next_evt = jnp.min(jnp.where(node_free > now, node_free, BIG))
-        arr_next = arrival[jnp.minimum(a, J - 1)]
-        next_evt = jnp.minimum(
-            next_evt, jnp.where((a < J) & (arr_next > now), arr_next, BIG))
-        if out_ends is not None:
+        with _stage("advance"):
+            next_evt = jnp.min(jnp.where(node_free > now, node_free, BIG))
+            arr_next = arrival[jnp.minimum(a, J - 1)]
             next_evt = jnp.minimum(
                 next_evt,
-                jnp.min(jnp.where(out_ends > now, out_ends, BIG)))
+                jnp.where((a < J) & (arr_next > now), arr_next, BIG))
+            if out_ends is not None:
+                next_evt = jnp.minimum(
+                    next_evt,
+                    jnp.min(jnp.where(out_ends > now, out_ends, BIG)))
 
         # ---- batched evaluation of every pending slot (sentinel slots
         # evaluate job J-1 behind a BIG arrival floor; never eligible)
-        valid = pend < J
-        jjs = jnp.minimum(pend, J - 1)
-        ps = prog[jjs]
-        t0f = jnp.where(valid, t0s, BIG)
-        kths, avails = _earliest_shared(node_free, n_req[ps],
-                                        t0f[:, None], placer, outage)
-        keys = jax.vmap(lambda j: jax.random.fold_in(sel_key, j))(jjs)
-        if tiered:
-            S = T_true.shape[1]
-            c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
-                tt, ps, C_tab[ps], T_tab[ps], runs[ps], avails,
-                C_pred[ps], T_pred[ps])
-            sels_x = select_batched(
-                policy, c_rows=c_x, t_rows=t_x, runs_rows=runs_x,
-                avail_rows=avail_x, k=k_of(jjs), c_pred_rows=cp_x,
-                t_pred_rows=tp_x, keys=keys)                     # [Wc]
-            fs = (sels_x // S).astype(jnp.int32)
-            sels = sels_x % S
-        else:
-            sels = select_batched(
-                policy, c_rows=C_tab[ps], t_rows=T_tab[ps],
-                runs_rows=runs[ps], avail_rows=avails, k=k_of(jjs),
-                c_pred_rows=C_pred[ps], t_pred_rows=T_pred[ps],
-                keys=keys)                                       # [Wc]
-            fs = jnp.zeros(Wc, jnp.int32)
-        starts_res = avails[idx, sels]                           # [Wc]
+        with _stage("earliest"):
+            valid = pend < J
+            jjs = jnp.minimum(pend, J - 1)
+            ps = prog[jjs]
+            t0f = jnp.where(valid, t0s, BIG)
+            kths, avails = _earliest_shared(node_free, n_req[ps],
+                                            t0f[:, None], placer, outage)
+        with _stage("select"):
+            keys = jax.vmap(lambda j: jax.random.fold_in(sel_key, j))(jjs)
+            if tiered:
+                S = T_true.shape[1]
+                c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
+                    tt, ps, C_tab[ps], T_tab[ps], runs[ps], avails,
+                    C_pred[ps], T_pred[ps])
+                sels_x = select_batched(
+                    policy, c_rows=c_x, t_rows=t_x, runs_rows=runs_x,
+                    avail_rows=avail_x, k=k_of(jjs), c_pred_rows=cp_x,
+                    t_pred_rows=tp_x, keys=keys)                 # [Wc]
+                fs = (sels_x // S).astype(jnp.int32)
+                sels = sels_x % S
+            else:
+                sels = select_batched(
+                    policy, c_rows=C_tab[ps], t_rows=T_tab[ps],
+                    runs_rows=runs[ps], avail_rows=avails, k=k_of(jjs),
+                    c_pred_rows=C_pred[ps], t_pred_rows=T_pred[ps],
+                    keys=keys)                                   # [Wc]
+                fs = jnp.zeros(Wc, jnp.int32)
+        with _stage("alloc"):
+            starts_res = avails[idx, sels]                       # [Wc]
 
         # fault draws (keyed by job id, as _fault_factor does)
-        u = jax.vmap(lambda j: jax.random.uniform(
-            jax.random.fold_in(fault_key, j), (2,)))(jjs)        # [Wc, 2]
-        slows = jnp.where(u[:, 0] < fvec[0], fvec[1], 1.0)
-        fails = u[:, 1] < fvec[2]
-        if retries:
-            first_fail = fails & ~rts        # retries never fail again
-            scale = jnp.where(first_fail, fvec[3], 1.0)
-        else:
-            first_fail = jnp.zeros(Wc, bool)
-            scale = jnp.where(fails, 1.0 + fvec[3], 1.0)
-        factors = slows * scale
-        T_acts = (tt["T"][ps, fs, sels] if tiered
-                  else T_true[ps, sels]) * factors
-        E_acts = (tt["E"][ps, fs, sels] if tiered
-                  else E_true[ps, sels]) * factors
-        needs = n_req[ps, sels]
+        with _stage("fault"):
+            u = jax.vmap(lambda j: jax.random.uniform(
+                jax.random.fold_in(fault_key, j), (2,)))(jjs)    # [Wc, 2]
+            slows = jnp.where(u[:, 0] < fvec[0], fvec[1], 1.0)
+            fails = u[:, 1] < fvec[2]
+            if retries:
+                first_fail = fails & ~rts    # retries never fail again
+                scale = jnp.where(first_fail, fvec[3], 1.0)
+            else:
+                first_fail = jnp.zeros(Wc, bool)
+                scale = jnp.where(fails, 1.0 + fvec[3], 1.0)
+            factors = slows * scale
+            T_acts = (tt["T"][ps, fs, sels] if tiered
+                      else T_true[ps, sels]) * factors
+            E_acts = (tt["E"][ps, fs, sels] if tiered
+                      else E_true[ps, sels]) * factors
 
-        # start rule: capped runs quantize to the current event (exact
-        # power trace); uncapped keep the resource-earliest start (FCFS
-        # bit-identity — the nodes were idle since then)
-        starts = jnp.where(capped, jnp.maximum(starts_res, now), starts_res)
-        finishes = starts + T_acts
-        trials = jax.vmap(_alloc, in_axes=(None, 0, 0, 0, 0))(
-            node_free, sels, kths[idx, sels], needs, finishes)
+        with _stage("alloc"):
+            needs = n_req[ps, sels]
+
+            # start rule: capped runs quantize to the current event
+            # (exact power trace); uncapped keep the resource-earliest
+            # start (FCFS bit-identity — the nodes were idle since then)
+            starts = jnp.where(capped, jnp.maximum(starts_res, now),
+                               starts_res)
+            finishes = starts + T_acts
+            trials = jax.vmap(_alloc, in_axes=(None, 0, 0, 0, 0))(
+                node_free, sels, kths[idx, sels], needs, finishes)
 
         # ---- discipline eligibility (resource side)
-        res_ok = valid & (starts_res <= now)
-        if outage is not None:
-            # a cap-deferred start quantizes to ``now`` — which must
-            # itself respect the start gate: a slot whose system has an
-            # open maintenance window is not placeable until the window
-            # ends (an event the clock advances to).  Uncapped starts are
-            # already outage-pushed inside ``starts_res``.
-            gated = _push_out_of_outage(starts, outage[sels])
-            res_ok = res_ok & (~capped | (gated <= now))
-        if queue == "fcfs":
-            elig_res = res_ok & (idx == 0)
-        else:  # event-driven EASY: only the head's reservation is guarded
-            p_h, sel_h = ps[0], sels[0]
-            r_h = starts_res[0]
-            kth_h2 = kth_free_time(
-                trials[:, sel_h, :],
-                jnp.broadcast_to(n_req[p_h, sel_h], (Wc,)),
-                force=placer or "sort")
-            avail_h2 = jnp.maximum(t0f[0], kth_h2)               # [Wc]
+        with _stage("select"):
+            res_ok = valid & (starts_res <= now)
             if outage is not None:
-                avail_h2 = _push_out_of_outage(avail_h2,
-                                               outage[sel_h][None])
-            elig_res = res_ok & ((idx == 0) | (avail_h2 <= r_h))
+                # a cap-deferred start quantizes to ``now`` — which must
+                # itself respect the start gate: a slot whose system has
+                # an open maintenance window is not placeable until the
+                # window ends (an event the clock advances to).  Uncapped
+                # starts are already outage-pushed inside ``starts_res``.
+                gated = _push_out_of_outage(starts, outage[sels])
+                res_ok = res_ok & (~capped | (gated <= now))
+        if queue == "fcfs":
+            with _stage("select"):
+                elig_res = res_ok & (idx == 0)
+        else:  # event-driven EASY: only the head's reservation is guarded
+            with _stage("earliest"):
+                p_h, sel_h = ps[0], sels[0]
+                r_h = starts_res[0]
+                kth_h2 = kth_free_time(
+                    trials[:, sel_h, :],
+                    jnp.broadcast_to(n_req[p_h, sel_h], (Wc,)),
+                    force=placer or "sort")
+                avail_h2 = jnp.maximum(t0f[0], kth_h2)           # [Wc]
+                if outage is not None:
+                    avail_h2 = _push_out_of_outage(avail_h2,
+                                                   outage[sel_h][None])
+            with _stage("select"):
+                elig_res = res_ok & ((idx == 0) | (avail_h2 <= r_h))
 
         # ---- power feasibility + the stuck valve
-        p_now = jnp.sum(jnp.where(node_free > now, node_pow, idle_mat))
-        w_jobs = (tt["w"][ps, fs, sels] if tiered
-                  else w_pow[ps, sels])                          # [Wc]
-        new_P = p_now - needs * idle_w[sels] + w_jobs            # [Wc]
-        power_ok = ~capped | (new_P <= pc)
-        elig0 = elig_res & power_ok
-        head_valid = valid[0]
-        # no event ahead + nothing placeable can only mean the cap is
-        # below the idle floor: force the head rather than stall forever
-        # (only with an open horizon — under a finite one the session is
-        # simply waiting to be driven further, never stuck)
-        stuck = (head_valid & ~do_push & ~jnp.any(elig0)
-                 & (next_evt >= BIG) & (horizon >= BIG))
-        elig = jnp.where(idx == 0, elig0[0] | stuck, elig0)
+        with _stage("select"):
+            p_now = jnp.sum(jnp.where(node_free > now, node_pow, idle_mat))
+            w_jobs = (tt["w"][ps, fs, sels] if tiered
+                      else w_pow[ps, sels])                      # [Wc]
+            new_P = p_now - needs * idle_w[sels] + w_jobs        # [Wc]
+            power_ok = ~capped | (new_P <= pc)
+            elig0 = elig_res & power_ok
+            head_valid = valid[0]
+            # no event ahead + nothing placeable can only mean the cap is
+            # below the idle floor: force the head rather than stall
+            # forever (only with an open horizon — under a finite one the
+            # session is simply waiting to be driven further, never stuck)
+            stuck = (head_valid & ~do_push & ~jnp.any(elig0)
+                     & (next_evt >= BIG) & (horizon >= BIG))
+            elig = jnp.where(idx == 0, elig0[0] | stuck, elig0)
 
-        chosen = jnp.min(jnp.where(elig, idx, Wc))
-        placed = chosen < Wc
-        ci = jnp.minimum(chosen, Wc - 1)
+            chosen = jnp.min(jnp.where(elig, idx, Wc))
+            placed = chosen < Wc
+            ci = jnp.minimum(chosen, Wc - 1)
 
-        # cap-attributed delay: the next would-be placement, power-blocked
-        chosen_res = jnp.min(jnp.where(elig_res, idx, Wc))
-        cri = jnp.minimum(chosen_res, Wc - 1)
-        blocked = (chosen_res < Wc) & ~power_ok[cri]
-        pblocks = pblocks.at[cri].set(
-            jnp.where(blocked, jnp.minimum(pblocks[cri], now), pblocks[cri]))
+            # cap-attributed delay: the next would-be placement,
+            # power-blocked
+            chosen_res = jnp.min(jnp.where(elig_res, idx, Wc))
+            cri = jnp.minimum(chosen_res, Wc - 1)
+            blocked = (chosen_res < Wc) & ~power_ok[cri]
+            pblocks = pblocks.at[cri].set(
+                jnp.where(blocked, jnp.minimum(pblocks[cri], now),
+                          pblocks[cri]))
 
         # ---- place the chosen slot (its trial IS the allocation)
-        jj, p, sel = jjs[ci], ps[ci], sels[ci]
-        factor, T_act, E_act = factors[ci], T_acts[ci], E_acts[ci]
-        start, finish, need = starts[ci], finishes[ci], needs[ci]
-        failed_now = placed & first_fail[ci]
-        final = placed & ~first_fail[ci]
-        # per-slot accruals, captured before the pop shifts the buffer
-        accT_ci, accF_ci, accW_ci = accTs[ci], accFs[ci], accWs[ci]
-        s0_ci = jnp.where(rts[ci], s0s[ci], start)
-        wait_step = start - t0s[ci]
-        pb_ci = pblocks[ci]
+        with _stage("alloc"):
+            jj, p, sel = jjs[ci], ps[ci], sels[ci]
+            factor, T_act, E_act = factors[ci], T_acts[ci], E_acts[ci]
+            start, finish, need = starts[ci], finishes[ci], needs[ci]
+            failed_now = placed & first_fail[ci]
+            final = placed & ~first_fail[ci]
+            # per-slot accruals, captured before the pop shifts the buffer
+            accT_ci, accF_ci, accW_ci = accTs[ci], accFs[ci], accWs[ci]
+            s0_ci = jnp.where(rts[ci], s0s[ci], start)
+            wait_step = start - t0s[ci]
+            pb_ci = pblocks[ci]
 
-        take = _alloc_mask(node_free, sel, kths[ci, sel], need)
-        node_free = jnp.where(placed, trials[ci], node_free)
-        per_node = w_jobs[ci] / jnp.maximum(need, 1).astype(jnp.float32)
-        node_pow = jnp.where(
-            placed,
-            node_pow.at[sel].set(jnp.where(take, per_node, node_pow[sel])),
-            node_pow)
+            take = _alloc_mask(node_free, sel, kths[ci, sel], need)
+            node_free = jnp.where(placed, trials[ci], node_free)
+            per_node = w_jobs[ci] / jnp.maximum(need, 1).astype(jnp.float32)
+            node_pow = jnp.where(
+                placed,
+                node_pow.at[sel].set(jnp.where(take, per_node,
+                                               node_pow[sel])),
+                node_pow)
 
-        fac_tot = accF_ci + factor
-        C_upd = C_true[p, sel] * fac_tot
-        T_upd = T_true[p, sel] * fac_tot
-        n = runs[p, sel].astype(jnp.float32)
-        C_tab = C_tab.at[p, sel].set(jnp.where(
-            final, (C_tab[p, sel] * n + C_upd) / (n + 1), C_tab[p, sel]))
-        T_tab = T_tab.at[p, sel].set(jnp.where(
-            final, (T_tab[p, sel] * n + T_upd) / (n + 1), T_tab[p, sel]))
-        runs = runs.at[p, sel].add(jnp.where(final, 1, 0))
+        with _stage("learn"):
+            fac_tot = accF_ci + factor
+            C_upd = C_true[p, sel] * fac_tot
+            T_upd = T_true[p, sel] * fac_tot
+            n = runs[p, sel].astype(jnp.float32)
+            C_tab = C_tab.at[p, sel].set(jnp.where(
+                final, (C_tab[p, sel] * n + C_upd) / (n + 1),
+                C_tab[p, sel]))
+            T_tab = T_tab.at[p, sel].set(jnp.where(
+                final, (T_tab[p, sel] * n + T_upd) / (n + 1),
+                T_tab[p, sel]))
+            runs = runs.at[p, sel].add(jnp.where(final, 1, 0))
 
-        busy = busy.at[sel].add(jnp.where(placed, T_act * need, 0.0))
-        nbf = nbf + (final & (chosen > 0)).astype(jnp.int32)
-        peak = jnp.maximum(peak, jnp.where(placed, new_P[ci], 0.0))
-        cdel = cdel + jnp.where(placed & (pb_ci < BIG), now - pb_ci, 0.0)
+        with _stage("account"):
+            busy = busy.at[sel].add(jnp.where(placed, T_act * need, 0.0))
+            nbf = nbf + (final & (chosen > 0)).astype(jnp.int32)
+            peak = jnp.maximum(peak, jnp.where(placed, new_P[ci], 0.0))
+            cdel = cdel + jnp.where(placed & (pb_ci < BIG), now - pb_ci,
+                                    0.0)
 
-        # pop the chosen slot (shift left; chosen == Wc: no-op)
-        def pop(arr, fill):
-            shifted = jnp.concatenate(
-                [arr[1:], jnp.full((1,), fill, arr.dtype)])
-            return jnp.where(idx < chosen, arr, shifted)
-        pend = pop(pend, J)
-        t0s, rts = pop(t0s, 0.0), pop(rts, False)
-        accTs, accFs, accWs = pop(accTs, 0.0), pop(accFs, 0.0), \
-            pop(accWs, 0.0)
-        s0s, pblocks = pop(s0s, 0.0), pop(pblocks, BIG)
+        with _stage("push"):
+            # pop the chosen slot (shift left; chosen == Wc: no-op)
+            def pop(arr, fill):
+                shifted = jnp.concatenate(
+                    [arr[1:], jnp.full((1,), fill, arr.dtype)])
+                return jnp.where(idx < chosen, arr, shifted)
+            pend = pop(pend, J)
+            t0s, rts = pop(t0s, 0.0), pop(rts, False)
+            accTs, accFs, accWs = pop(accTs, 0.0), pop(accFs, 0.0), \
+                pop(accWs, 0.0)
+            s0s, pblocks = pop(s0s, 0.0), pop(pblocks, BIG)
 
-        if retries:
-            # a failed first attempt re-queues at the tail: effective
-            # arrival = the failure time (a completion event)
-            size2 = jnp.sum(pend < J)
-            slot2 = jnp.minimum(size2, Wc - 1)
+            if retries:
+                # a failed first attempt re-queues at the tail: effective
+                # arrival = the failure time (a completion event)
+                size2 = jnp.sum(pend < J)
+                slot2 = jnp.minimum(size2, Wc - 1)
 
-            def requeue(arr, val):
-                return arr.at[slot2].set(
-                    jnp.where(failed_now, val, arr[slot2]))
-            pend = requeue(pend, jj.astype(jnp.int32))
-            t0s = requeue(t0s, finish)
-            rts = requeue(rts, True)
-            accTs = requeue(accTs, accT_ci + T_act)
-            accFs = requeue(accFs, fac_tot)
-            accWs = requeue(accWs, accW_ci + wait_step)
-            s0s = requeue(s0s, s0_ci)
-            pblocks = requeue(pblocks, BIG)
+                def requeue(arr, val):
+                    return arr.at[slot2].set(
+                        jnp.where(failed_now, val, arr[slot2]))
+                pend = requeue(pend, jj.astype(jnp.int32))
+                t0s = requeue(t0s, finish)
+                rts = requeue(rts, True)
+                accTs = requeue(accTs, accT_ci + T_act)
+                accFs = requeue(accFs, fac_tot)
+                accWs = requeue(accWs, accW_ci + wait_step)
+                s0s = requeue(s0s, s0_ci)
+                pblocks = requeue(pblocks, BIG)
 
-        T_tot = accT_ci + T_act
-        wait_tot = accW_ci + wait_step
+        with _stage("account"):
+            T_tot = accT_ci + T_act
+            wait_tot = accW_ci + wait_step
 
         # ---- advance the clock only when nothing else happened (and
         # never past the horizon)
-        advance = (~do_push & ~placed & (next_evt < BIG)
-                   & (next_evt <= horizon))
-        now = jnp.where(advance, next_evt, now)
+        with _stage("advance"):
+            advance = (~do_push & ~placed & (next_evt < BIG)
+                       & (next_evt <= horizon))
+            now = jnp.where(advance, next_evt, now)
 
-        if totals_only:
-            sums, comps, fin_max, wait_max = acc
-            add = jnp.stack([
-                E_act,
-                jnp.where(final, wait_tot, 0.0),
-                jnp.where(final, (wait_tot + T_tot) / T_tot, 0.0)])
-            # Kahan update applied ONLY on placement steps, so the FCFS
-            # op sequence matches the arrival-indexed core bit for bit
-            y = add - comps
-            t = sums + y
-            acc = (jnp.where(placed, t, sums),
-                   jnp.where(placed, (t - sums) - y, comps),
-                   jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
-                   jnp.maximum(wait_max, jnp.where(final, wait_tot, 0.0)))
-            out = None
-        else:
-            out = {
-                # batch-result channels (_event_results scatters these)
-                "j_add": jnp.where(placed, jj, J), "E": E_act,
-                "j_fin": jnp.where(final, jj, J), "sys": sel,
-                "s0": s0_ci, "finish": finish, "wait": wait_tot,
-                "T": T_tot, "bf": final & (chosen > 0),
-                "tier": fs[ci],
-                # live-decision channels (the service dispatcher reads
-                # these; pure additions, the batch channels are untouched)
-                "pushed": do_push, "j_push": jnp.where(do_push, a - 1, J),
-                "placed": placed, "final": final, "advanced": advance,
-                "start": start, "now": now, "qlen": jnp.sum(pend < J),
-                "power": jnp.where(placed, new_P[ci], p_now),
-            }
+        with _stage("account"):
+            if totals_only:
+                sums, comps, fin_max, wait_max = acc
+                add = jnp.stack([
+                    E_act,
+                    jnp.where(final, wait_tot, 0.0),
+                    jnp.where(final, (wait_tot + T_tot) / T_tot, 0.0)])
+                # Kahan update applied ONLY on placement steps, so the
+                # FCFS op sequence matches the arrival-indexed core bit
+                # for bit
+                y = add - comps
+                t = sums + y
+                acc = (jnp.where(placed, t, sums),
+                       jnp.where(placed, (t - sums) - y, comps),
+                       jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
+                       jnp.maximum(wait_max, jnp.where(final, wait_tot,
+                                                       0.0)))
+                out = None
+            else:
+                out = {
+                    # batch-result channels (_event_results scatters these)
+                    "j_add": jnp.where(placed, jj, J), "E": E_act,
+                    "j_fin": jnp.where(final, jj, J), "sys": sel,
+                    "s0": s0_ci, "finish": finish, "wait": wait_tot,
+                    "T": T_tot, "bf": final & (chosen > 0),
+                    "tier": fs[ci],
+                    # live-decision channels (the service dispatcher reads
+                    # these; pure additions, the batch channels are
+                    # untouched)
+                    "pushed": do_push,
+                    "j_push": jnp.where(do_push, a - 1, J),
+                    "placed": placed, "final": final, "advanced": advance,
+                    "start": start, "now": now, "qlen": jnp.sum(pend < J),
+                    "power": jnp.where(placed, new_P[ci], p_now),
+                }
 
         return EventCarry(
             node_free, node_pow, C_tab, T_tab, runs, acc, busy,
@@ -1473,12 +1560,14 @@ def make_cons_step(policy: Policy, placer: str | None = None,
                                    arrs["k_job"][j])
         S = T_true.shape[1]
         J = prog.shape[0]
-        exists = arrs["free0"] < BIG
-        idle_mat = jnp.where(exists, idle_w[:, None], 0.0)
-        pc = jnp.asarray(policy.power_cap, jnp.float32)
-        capped = pc < UNCAPPED
-        out_ends = (None if outage is None
-                    else outage[..., 1].reshape(-1))
+        with _stage("select"):
+            exists = arrs["free0"] < BIG
+            idle_mat = jnp.where(exists, idle_w[:, None], 0.0)
+            pc = jnp.asarray(policy.power_cap, jnp.float32)
+            capped = pc < UNCAPPED
+        with _stage("advance"):
+            out_ends = (None if outage is None
+                        else outage[..., 1].reshape(-1))
         #: per-slot pop fill values (sentinel slot state)
         FILLS = dict(pend=J, t0=0.0, rt=False, accT=0.0, accF=0.0,
                      accW=0.0, s0=0.0, pblock=BIG, sel=0, start=0.0,
@@ -1493,267 +1582,307 @@ def make_cons_step(policy: Policy, placer: str | None = None,
             arrival floor, node free times, reservation finishes (the only
             capacity rises); dips happen only at reservation starts, so each
             candidate is checked against the [W] reservation table."""
-            need = n_req[p]                                          # [S]
-            r_valid = slots["pend"] < J                              # [Wc]
-            r_sel, r_sta = slots["sel"], slots["start"]
-            r_fin, r_need = slots["fin"], slots["need"]
-            cands = jnp.concatenate([
-                jnp.full((S, 1), t0, jnp.float32), node_free,
-                jnp.broadcast_to(r_fin[None], (S, Wc)),
-            ], axis=1)                                               # [S, E]
-            cands = jnp.maximum(cands, t0)
-            if outage is not None:
-                # start gating only (jobs ride through windows, as in the
-                # other cores); outage ends are free-time candidates via the
-                # floored duplicates below
-                for wi in range(outage.shape[1]):
-                    o0 = outage[:, wi, 0][:, None]
-                    o1 = outage[:, wi, 1][:, None]
-                    cands = jnp.where((cands >= o0) & (cands < o1), o1, cands)
-            q = jnp.concatenate(
-                [cands, jnp.broadcast_to(r_sta[None], (S, Wc))], axis=1)
-            cnt = jnp.sum(node_free[:, None, :] <= q[:, :, None], axis=2)
-            on_sys = r_valid[None, None, :] & (r_sel[None, None, :] == sys_col)
-            occ = jnp.sum(jnp.where(
-                on_sys & (r_sta[None, None, :] <= q[:, :, None])
-                & (q[:, :, None] < r_fin[None, None, :]),
-                r_need[None, None, :], 0), axis=2)
-            availn = cnt - occ                                   # [S, E + Wc]
-            E_c = cands.shape[1]
-            cap_ok = availn[:, :E_c] >= need[:, None]                # [S, E]
-            avail_rs = availn[:, E_c:]                               # [S, Wc]
-            dips = (on_sys & (cands[:, :, None] < r_sta[None, None, :])
-                    & (r_sta[None, None, :]
-                       < cands[:, :, None] + Tdur[:, None, None]))
-            dip_ok = jnp.all(
-                ~dips | (avail_rs[:, None, :] >= need[:, None, None]), axis=2)
-            return jnp.min(jnp.where(cap_ok & dip_ok, cands, BIG), axis=1)
+            with _stage("earliest"):
+                need = n_req[p]                                      # [S]
+                r_valid = slots["pend"] < J                          # [Wc]
+                r_sel, r_sta = slots["sel"], slots["start"]
+                r_fin, r_need = slots["fin"], slots["need"]
+                cands = jnp.concatenate([
+                    jnp.full((S, 1), t0, jnp.float32), node_free,
+                    jnp.broadcast_to(r_fin[None], (S, Wc)),
+                ], axis=1)                                           # [S, E]
+                cands = jnp.maximum(cands, t0)
+                if outage is not None:
+                    # start gating only (jobs ride through windows, as in
+                    # the other cores); outage ends are free-time
+                    # candidates via the floored duplicates below
+                    for wi in range(outage.shape[1]):
+                        o0 = outage[:, wi, 0][:, None]
+                        o1 = outage[:, wi, 1][:, None]
+                        cands = jnp.where((cands >= o0) & (cands < o1), o1,
+                                          cands)
+                q = jnp.concatenate(
+                    [cands, jnp.broadcast_to(r_sta[None], (S, Wc))], axis=1)
+                cnt = jnp.sum(node_free[:, None, :] <= q[:, :, None], axis=2)
+                on_sys = (r_valid[None, None, :]
+                          & (r_sel[None, None, :] == sys_col))
+                occ = jnp.sum(jnp.where(
+                    on_sys & (r_sta[None, None, :] <= q[:, :, None])
+                    & (q[:, :, None] < r_fin[None, None, :]),
+                    r_need[None, None, :], 0), axis=2)
+                availn = cnt - occ                               # [S, E + Wc]
+                E_c = cands.shape[1]
+                cap_ok = availn[:, :E_c] >= need[:, None]            # [S, E]
+                avail_rs = availn[:, E_c:]                           # [S, Wc]
+                dips = (on_sys & (cands[:, :, None] < r_sta[None, None, :])
+                        & (r_sta[None, None, :]
+                           < cands[:, :, None] + Tdur[:, None, None]))
+                dip_ok = jnp.all(
+                    ~dips | (avail_rs[:, None, :] >= need[:, None, None]),
+                    axis=2)
+                return jnp.min(jnp.where(cap_ok & dip_ok, cands, BIG),
+                               axis=1)
 
         def reserve(jp, t0, is_retry, node_free, slots, C_tab, T_tab, runs):
             """Admission: fault draw + hole-aware earliest fit + selection —
             the new reservation row for the slot table."""
-            p = prog[jp]
-            u = jax.random.uniform(jax.random.fold_in(fault_key, jp), (2,))
-            slow = jnp.where(u[0] < fvec[0], fvec[1], 1.0)
-            fail = u[1] < fvec[2]
-            if retries:
-                first_fail = fail & ~is_retry
-                scale = jnp.where(first_fail, fvec[3], 1.0)
-            else:
-                first_fail = jnp.zeros((), bool)
-                scale = jnp.where(fail, 1.0 + fvec[3], 1.0)
-            factor = slow * scale
-            key = jax.random.fold_in(sel_key, jp)
+            with _stage("earliest"):
+                p = prog[jp]
+            with _stage("fault"):
+                u = jax.random.uniform(jax.random.fold_in(fault_key, jp),
+                                       (2,))
+                slow = jnp.where(u[0] < fvec[0], fvec[1], 1.0)
+                fail = u[1] < fvec[2]
+                if retries:
+                    first_fail = fail & ~is_retry
+                    scale = jnp.where(first_fail, fvec[3], 1.0)
+                else:
+                    first_fail = jnp.zeros((), bool)
+                    scale = jnp.where(fail, 1.0 + fvec[3], 1.0)
+                factor = slow * scale
+            with _stage("select"):
+                key = jax.random.fold_in(sel_key, jp)
             if tiered:
                 # hole-aware earliest fit per tier: a slower tier's longer
                 # window may fit a different hole, so each tier gets its
                 # own piecewise-capacity evaluation
-                Tdur_f = tt["T"][p] * factor                     # [F, S]
+                with _stage("fault"):
+                    Tdur_f = tt["T"][p] * factor                 # [F, S]
                 avail_f = jax.vmap(
                     lambda td: earliest_fit(p, t0, td, node_free, slots)
                 )(Tdur_f)                                        # [F, S]
-                c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
-                    tt, p, C_tab[p], T_tab[p], runs[p], avail_f,
-                    C_pred[p], T_pred[p])
-                sel_x = select(
-                    policy, c_row=c_x, t_row=t_x, runs_row=runs_x,
-                    avail_row=avail_x, k=k_of(jp), c_pred_row=cp_x,
-                    t_pred_row=tp_x, key=key)
-                f = (sel_x // S).astype(jnp.int32)
-                sel = sel_x % S
-                start = avail_f[f, sel]
-                T_act = Tdur_f[f, sel]
-                E_res = tt["E"][p, f, sel] * factor
-                wjob = tt["w"][p, f, sel]
+                with _stage("select"):
+                    c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
+                        tt, p, C_tab[p], T_tab[p], runs[p], avail_f,
+                        C_pred[p], T_pred[p])
+                    sel_x = select(
+                        policy, c_row=c_x, t_row=t_x, runs_row=runs_x,
+                        avail_row=avail_x, k=k_of(jp), c_pred_row=cp_x,
+                        t_pred_row=tp_x, key=key)
+                    f = (sel_x // S).astype(jnp.int32)
+                    sel = sel_x % S
+                with _stage("push"):
+                    start = avail_f[f, sel]
+                    T_act = Tdur_f[f, sel]
+                    E_res = tt["E"][p, f, sel] * factor
+                    wjob = tt["w"][p, f, sel]
             else:
-                Tdur = T_true[p] * factor                            # [S]
+                with _stage("fault"):
+                    Tdur = T_true[p] * factor                        # [S]
                 avail_p = earliest_fit(p, t0, Tdur, node_free, slots)
-                sel = select(
-                    policy, c_row=C_tab[p], t_row=T_tab[p],
-                    runs_row=runs[p], avail_row=avail_p, k=k_of(jp),
-                    c_pred_row=C_pred[p], t_pred_row=T_pred[p], key=key)
-                f = jnp.int32(0)
-                start = avail_p[sel]
-                T_act = Tdur[sel]
-                E_res = E_true[p, sel] * factor
-                wjob = w_pow[p, sel]
-            return dict(sel=sel.astype(jnp.int32), start=start,
-                        fin=start + T_act, T=T_act,
-                        E=E_res, need=n_req[p, sel],
-                        wjob=wjob, fac=factor, fail=first_fail, tier=f)
+                with _stage("select"):
+                    sel = select(
+                        policy, c_row=C_tab[p], t_row=T_tab[p],
+                        runs_row=runs[p], avail_row=avail_p, k=k_of(jp),
+                        c_pred_row=C_pred[p], t_pred_row=T_pred[p],
+                        key=key)
+                with _stage("push"):
+                    f = jnp.int32(0)
+                    start = avail_p[sel]
+                    T_act = Tdur[sel]
+                    E_res = E_true[p, sel] * factor
+                    wjob = w_pow[p, sel]
+            with _stage("push"):
+                return dict(sel=sel.astype(jnp.int32), start=start,
+                            fin=start + T_act, T=T_act,
+                            E=E_res, need=n_req[p, sel],
+                            wjob=wjob, fac=factor, fail=first_fail, tier=f)
 
         (node_free, node_pow, C_tab, T_tab, runs, acc, busy,
          slots, a, now, nbf, peak, cdel) = carry
 
         # ---- push: admit + reserve the next arrival if due and room
-        size0 = jnp.sum(slots["pend"] < J)
-        jp = jnp.minimum(a, J - 1)
-        arr_a = arrival[jp]
-        do_push = (a < J) & (size0 < Wc) & (arr_a <= now)
-        vals = reserve(jp, arr_a, jnp.zeros((), bool), node_free, slots,
-                       C_tab, T_tab, runs)
-        slot = jnp.minimum(size0, Wc - 1)
-        newv = dict(pend=jp.astype(jnp.int32), t0=arr_a, rt=False,
-                    accT=0.0, accF=0.0, accW=0.0, s0=0.0, pblock=BIG,
-                    **vals)
-        slots = {k: v.at[slot].set(jnp.where(do_push, newv[k], v[slot]))
-                 for k, v in slots.items()}
-        a = a + do_push
+        with _stage("push"):
+            size0 = jnp.sum(slots["pend"] < J)
+            jp = jnp.minimum(a, J - 1)
+            arr_a = arrival[jp]
+            do_push = (a < J) & (size0 < Wc) & (arr_a <= now)
+            vals = reserve(jp, arr_a, jnp.zeros((), bool), node_free, slots,
+                           C_tab, T_tab, runs)
+            slot = jnp.minimum(size0, Wc - 1)
+            newv = dict(pend=jp.astype(jnp.int32), t0=arr_a, rt=False,
+                        accT=0.0, accF=0.0, accW=0.0, s0=0.0, pblock=BIG,
+                        **vals)
+            slots = {k: v.at[slot].set(jnp.where(do_push, newv[k], v[slot]))
+                     for k, v in slots.items()}
+            a = a + do_push
 
-        valid = slots["pend"] < J
-        r_start, r_sel, r_need = slots["start"], slots["sel"], slots["need"]
+        with _stage("earliest"):
+            valid = slots["pend"] < J
+            r_start, r_sel = slots["start"], slots["sel"]
+            r_need = slots["need"]
 
         # ---- next event: arrivals, completions, reservation starts,
         # outage ends (reserved starts need not coincide with node-free
         # times once a cap defers placements)
-        next_evt = jnp.min(jnp.where(node_free > now, node_free, BIG))
-        arr_next = arrival[jnp.minimum(a, J - 1)]
-        next_evt = jnp.minimum(
-            next_evt, jnp.where((a < J) & (arr_next > now), arr_next, BIG))
-        next_evt = jnp.minimum(
-            next_evt,
-            jnp.min(jnp.where(valid & (r_start > now), r_start, BIG)))
-        if out_ends is not None:
+        with _stage("advance"):
+            next_evt = jnp.min(jnp.where(node_free > now, node_free, BIG))
+            arr_next = arrival[jnp.minimum(a, J - 1)]
             next_evt = jnp.minimum(
                 next_evt,
-                jnp.min(jnp.where(out_ends > now, out_ends, BIG)))
+                jnp.where((a < J) & (arr_next > now), arr_next, BIG))
+            next_evt = jnp.minimum(
+                next_evt,
+                jnp.min(jnp.where(valid & (r_start > now), r_start, BIG)))
+            if out_ends is not None:
+                next_evt = jnp.minimum(
+                    next_evt,
+                    jnp.min(jnp.where(out_ends > now, out_ends, BIG)))
 
         # ---- realizability on the REAL table (one shared sort)
-        kth_rows = kth_free_time_rows(node_free, r_sel, r_need,
-                                      force=placer)              # [Wc]
-        avail_real = jnp.maximum(jnp.where(valid, slots["t0"], BIG),
-                                 kth_rows)
-        if outage is not None:
-            avail_real = _push_out_of_outage(avail_real, outage[r_sel])
-        elig_res = valid & (r_start <= now) & (avail_real <= now)
-        if outage is not None:
-            # cap-deferred starts quantize to ``now``: the start gate
-            # must hold there too (reserved starts are already pushed)
-            q = jnp.maximum(r_start, now)
-            gated = _push_out_of_outage(q, outage[r_sel])
-            elig_res = elig_res & (~capped | (gated <= now))
+        with _stage("earliest"):
+            kth_rows = kth_free_time_rows(node_free, r_sel, r_need,
+                                          force=placer)          # [Wc]
+            avail_real = jnp.maximum(jnp.where(valid, slots["t0"], BIG),
+                                     kth_rows)
+            if outage is not None:
+                avail_real = _push_out_of_outage(avail_real, outage[r_sel])
+        with _stage("select"):
+            elig_res = valid & (r_start <= now) & (avail_real <= now)
+            if outage is not None:
+                # cap-deferred starts quantize to ``now``: the start gate
+                # must hold there too (reserved starts are already pushed)
+                q = jnp.maximum(r_start, now)
+                gated = _push_out_of_outage(q, outage[r_sel])
+                elig_res = elig_res & (~capped | (gated <= now))
 
-        # ---- power feasibility + the stuck valve
-        p_now = jnp.sum(jnp.where(node_free > now, node_pow, idle_mat))
-        new_P = p_now - r_need * idle_w[r_sel] + slots["wjob"]
-        power_ok = ~capped | (new_P <= pc)
-        elig0 = elig_res & power_ok
-        stuck = (jnp.any(elig_res) & ~do_push & ~jnp.any(elig0)
-                 & (next_evt >= BIG) & (horizon >= BIG))
-        elig = elig0 | (elig_res & stuck)
+            # ---- power feasibility + the stuck valve
+            p_now = jnp.sum(jnp.where(node_free > now, node_pow, idle_mat))
+            new_P = p_now - r_need * idle_w[r_sel] + slots["wjob"]
+            power_ok = ~capped | (new_P <= pc)
+            elig0 = elig_res & power_ok
+            stuck = (jnp.any(elig_res) & ~do_push & ~jnp.any(elig0)
+                     & (next_evt >= BIG) & (horizon >= BIG))
+            elig = elig0 | (elig_res & stuck)
 
-        chosen = jnp.min(jnp.where(elig, idx, Wc))
-        placed = chosen < Wc
-        ci = jnp.minimum(chosen, Wc - 1)
+            chosen = jnp.min(jnp.where(elig, idx, Wc))
+            placed = chosen < Wc
+            ci = jnp.minimum(chosen, Wc - 1)
 
-        chosen_res = jnp.min(jnp.where(elig_res, idx, Wc))
-        cri = jnp.minimum(chosen_res, Wc - 1)
-        blocked = (chosen_res < Wc) & ~power_ok[cri]
-        slots["pblock"] = slots["pblock"].at[cri].set(
-            jnp.where(blocked, jnp.minimum(slots["pblock"][cri], now),
-                      slots["pblock"][cri]))
+            chosen_res = jnp.min(jnp.where(elig_res, idx, Wc))
+            cri = jnp.minimum(chosen_res, Wc - 1)
+            blocked = (chosen_res < Wc) & ~power_ok[cri]
+            slots["pblock"] = slots["pblock"].at[cri].set(
+                jnp.where(blocked, jnp.minimum(slots["pblock"][cri], now),
+                          slots["pblock"][cri]))
 
         # ---- realize the chosen reservation
-        jj = jnp.minimum(slots["pend"][ci], J - 1)
-        p = prog[jj]
-        sel, need = r_sel[ci], jnp.maximum(r_need[ci], 1)
-        T_act, E_act, fac = slots["T"][ci], slots["E"][ci], slots["fac"][ci]
-        tier_ci = slots["tier"][ci]
-        start = jnp.where(capped, jnp.maximum(r_start[ci], now),
-                          r_start[ci])
-        finish = start + T_act
-        failed_now = placed & slots["fail"][ci]
-        final = placed & ~slots["fail"][ci]
-        accT_ci, accF_ci = slots["accT"][ci], slots["accF"][ci]
-        accW_ci = slots["accW"][ci]
-        s0_ci = jnp.where(slots["rt"][ci], slots["s0"][ci], start)
-        wait_step = start - slots["t0"][ci]
-        pb_ci = slots["pblock"][ci]
+        with _stage("alloc"):
+            jj = jnp.minimum(slots["pend"][ci], J - 1)
+            p = prog[jj]
+            sel, need = r_sel[ci], jnp.maximum(r_need[ci], 1)
+            T_act, E_act = slots["T"][ci], slots["E"][ci]
+            fac = slots["fac"][ci]
+            tier_ci = slots["tier"][ci]
+            start = jnp.where(capped, jnp.maximum(r_start[ci], now),
+                              r_start[ci])
+            finish = start + T_act
+            failed_now = placed & slots["fail"][ci]
+            final = placed & ~slots["fail"][ci]
+            accT_ci, accF_ci = slots["accT"][ci], slots["accF"][ci]
+            accW_ci = slots["accW"][ci]
+            s0_ci = jnp.where(slots["rt"][ci], slots["s0"][ci], start)
+            wait_step = start - slots["t0"][ci]
+            pb_ci = slots["pblock"][ci]
 
-        kth_ci = kth_rows[ci]
-        take = _alloc_mask(node_free, sel, kth_ci, need)
-        node_free = jnp.where(
-            placed, _alloc(node_free, sel, kth_ci, need, finish),
-            node_free)
-        per_node = slots["wjob"][ci] / need.astype(jnp.float32)
-        node_pow = jnp.where(
-            placed,
-            node_pow.at[sel].set(jnp.where(take, per_node, node_pow[sel])),
-            node_pow)
+            kth_ci = kth_rows[ci]
+            take = _alloc_mask(node_free, sel, kth_ci, need)
+            node_free = jnp.where(
+                placed, _alloc(node_free, sel, kth_ci, need, finish),
+                node_free)
+            per_node = slots["wjob"][ci] / need.astype(jnp.float32)
+            node_pow = jnp.where(
+                placed,
+                node_pow.at[sel].set(jnp.where(take, per_node,
+                                               node_pow[sel])),
+                node_pow)
 
-        fac_tot = accF_ci + fac
-        C_upd = C_true[p, sel] * fac_tot
-        T_upd = T_true[p, sel] * fac_tot
-        n = runs[p, sel].astype(jnp.float32)
-        C_tab = C_tab.at[p, sel].set(jnp.where(
-            final, (C_tab[p, sel] * n + C_upd) / (n + 1), C_tab[p, sel]))
-        T_tab = T_tab.at[p, sel].set(jnp.where(
-            final, (T_tab[p, sel] * n + T_upd) / (n + 1), T_tab[p, sel]))
-        runs = runs.at[p, sel].add(jnp.where(final, 1, 0))
+        with _stage("learn"):
+            fac_tot = accF_ci + fac
+            C_upd = C_true[p, sel] * fac_tot
+            T_upd = T_true[p, sel] * fac_tot
+            n = runs[p, sel].astype(jnp.float32)
+            C_tab = C_tab.at[p, sel].set(jnp.where(
+                final, (C_tab[p, sel] * n + C_upd) / (n + 1),
+                C_tab[p, sel]))
+            T_tab = T_tab.at[p, sel].set(jnp.where(
+                final, (T_tab[p, sel] * n + T_upd) / (n + 1),
+                T_tab[p, sel]))
+            runs = runs.at[p, sel].add(jnp.where(final, 1, 0))
 
-        busy = busy.at[sel].add(jnp.where(placed, T_act * need, 0.0))
-        nbf = nbf + (final & (chosen > 0)).astype(jnp.int32)
-        peak = jnp.maximum(peak, jnp.where(placed, new_P[ci], 0.0))
-        cdel = cdel + jnp.where(placed & (pb_ci < BIG), now - pb_ci, 0.0)
+        with _stage("account"):
+            busy = busy.at[sel].add(jnp.where(placed, T_act * need, 0.0))
+            nbf = nbf + (final & (chosen > 0)).astype(jnp.int32)
+            peak = jnp.maximum(peak, jnp.where(placed, new_P[ci], 0.0))
+            cdel = cdel + jnp.where(placed & (pb_ci < BIG), now - pb_ci,
+                                    0.0)
 
-        def pop(arr, fill):
-            shifted = jnp.concatenate(
-                [arr[1:], jnp.full((1,), fill, arr.dtype)])
-            return jnp.where(idx < chosen, arr, shifted)
-        slots = {k: pop(v, FILLS[k]) for k, v in slots.items()}
+        with _stage("push"):
+            def pop(arr, fill):
+                shifted = jnp.concatenate(
+                    [arr[1:], jnp.full((1,), fill, arr.dtype)])
+                return jnp.where(idx < chosen, arr, shifted)
+            slots = {k: pop(v, FILLS[k]) for k, v in slots.items()}
 
-        if retries:
-            # failed first attempt: fresh reservation at the failure time
-            vals2 = reserve(jj, finish, jnp.ones((), bool), node_free,
-                            slots, C_tab, T_tab, runs)
-            size2 = jnp.sum(slots["pend"] < J)
-            slot2 = jnp.minimum(size2, Wc - 1)
-            newv2 = dict(pend=jj.astype(jnp.int32), t0=finish, rt=True,
-                         accT=accT_ci + T_act, accF=fac_tot,
-                         accW=accW_ci + wait_step, s0=s0_ci, pblock=BIG,
-                         **vals2)
-            slots = {k: v.at[slot2].set(
-                jnp.where(failed_now, newv2[k], v[slot2]))
-                for k, v in slots.items()}
+            if retries:
+                # failed first attempt: fresh reservation at the failure
+                # time
+                vals2 = reserve(jj, finish, jnp.ones((), bool), node_free,
+                                slots, C_tab, T_tab, runs)
+                size2 = jnp.sum(slots["pend"] < J)
+                slot2 = jnp.minimum(size2, Wc - 1)
+                newv2 = dict(pend=jj.astype(jnp.int32), t0=finish, rt=True,
+                             accT=accT_ci + T_act, accF=fac_tot,
+                             accW=accW_ci + wait_step, s0=s0_ci, pblock=BIG,
+                             **vals2)
+                slots = {k: v.at[slot2].set(
+                    jnp.where(failed_now, newv2[k], v[slot2]))
+                    for k, v in slots.items()}
 
-        T_tot = accT_ci + T_act
-        wait_tot = accW_ci + wait_step
+        with _stage("account"):
+            T_tot = accT_ci + T_act
+            wait_tot = accW_ci + wait_step
 
         # ---- advance the clock only when nothing else happened (and
         # never past the horizon)
-        advance = (~do_push & ~placed & (next_evt < BIG)
-                   & (next_evt <= horizon))
-        now = jnp.where(advance, next_evt, now)
+        with _stage("advance"):
+            advance = (~do_push & ~placed & (next_evt < BIG)
+                       & (next_evt <= horizon))
+            now = jnp.where(advance, next_evt, now)
 
-        if totals_only:
-            sums, comps, fin_max, wait_max = acc
-            add = jnp.stack([
-                E_act,
-                jnp.where(final, wait_tot, 0.0),
-                jnp.where(final, (wait_tot + T_tot) / T_tot, 0.0)])
-            y = add - comps
-            t = sums + y
-            acc = (jnp.where(placed, t, sums),
-                   jnp.where(placed, (t - sums) - y, comps),
-                   jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
-                   jnp.maximum(wait_max, jnp.where(final, wait_tot, 0.0)))
-            out = None
-        else:
-            out = {
-                # batch-result channels (_event_results scatters these)
-                "j_add": jnp.where(placed, jj, J), "E": E_act,
-                "j_fin": jnp.where(final, jj, J), "sys": sel,
-                "s0": s0_ci, "finish": finish, "wait": wait_tot,
-                "T": T_tot, "bf": final & (chosen > 0),
-                "tier": tier_ci,
-                # live-decision channels (the service dispatcher reads
-                # these; pure additions, the batch channels are untouched)
-                "pushed": do_push, "j_push": jnp.where(do_push, a - 1, J),
-                "placed": placed, "final": final, "advanced": advance,
-                "start": start, "now": now,
-                "qlen": jnp.sum(slots["pend"] < J),
-                "power": jnp.where(placed, new_P[ci], p_now),
-            }
+        with _stage("account"):
+            if totals_only:
+                sums, comps, fin_max, wait_max = acc
+                add = jnp.stack([
+                    E_act,
+                    jnp.where(final, wait_tot, 0.0),
+                    jnp.where(final, (wait_tot + T_tot) / T_tot, 0.0)])
+                y = add - comps
+                t = sums + y
+                acc = (jnp.where(placed, t, sums),
+                       jnp.where(placed, (t - sums) - y, comps),
+                       jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
+                       jnp.maximum(wait_max, jnp.where(final, wait_tot,
+                                                       0.0)))
+                out = None
+            else:
+                out = {
+                    # batch-result channels (_event_results scatters these)
+                    "j_add": jnp.where(placed, jj, J), "E": E_act,
+                    "j_fin": jnp.where(final, jj, J), "sys": sel,
+                    "s0": s0_ci, "finish": finish, "wait": wait_tot,
+                    "T": T_tot, "bf": final & (chosen > 0),
+                    "tier": tier_ci,
+                    # live-decision channels (the service dispatcher reads
+                    # these; pure additions, the batch channels are
+                    # untouched)
+                    "pushed": do_push,
+                    "j_push": jnp.where(do_push, a - 1, J),
+                    "placed": placed, "final": final, "advanced": advance,
+                    "start": start, "now": now,
+                    "qlen": jnp.sum(slots["pend"] < J),
+                    "power": jnp.where(placed, new_P[ci], p_now),
+                }
 
         return ConsCarry(node_free, node_pow, C_tab, T_tab, runs, acc,
                          busy, slots, a, now, nbf, peak, cdel), out
@@ -1889,22 +2018,28 @@ def _run_chunked(arrs, policy, seeds, faults, *, chunk, mesh, warm_start,
               retries=retries)
     xs, length = _stream_xs(arrs, policy, core, retries)
     chunk = max(1, int(chunk))
-    carries = _chunk_init(arrs, policy, seeds, faults, **kw)
+    args = (arrs, policy, seeds, faults)
+    carries = _chunk_init(*args, **kw)
+    programs = [(_chunk_init, kw, args)]
     parts = []
     for lo in range(0, length, chunk):
         n = min(chunk, length - lo)
         xs_c = (None if xs is None
                 else jax.tree.map(lambda x: x[lo:lo + n], xs))
-        carries, ys = _chunk_advance(arrs, policy, seeds, faults, carries,
-                                     xs_c, nsteps=n, **kw)
+        if lo == 0 or n != chunk:          # the full chunk, the remainder
+            programs.append((_chunk_advance, {**kw, "nsteps": n},
+                             (*args, carries, xs_c)))
+        carries, ys = _chunk_advance(*args, carries, xs_c, nsteps=n, **kw)
         if not totals_only:
             parts.append(jax.device_get(ys))
     ys_all = None
     if not totals_only:
         ys_all = jax.tree.map(lambda *cs: np.concatenate(cs, axis=1),
                               *parts)
-    return _chunk_finish(arrs, policy, seeds, faults, carries, ys_all,
-                         **kw)
+    out = _chunk_finish(*args, carries, ys_all, **kw)
+    _record_programs(programs + [(_chunk_finish, kw,
+                                  (*args, carries, ys_all))])
+    return out
 
 
 def _fault_vec(cfg: SimConfig | FaultConfig):
@@ -2174,8 +2309,11 @@ class Scheduler:
             out = _run_chunked(*args, chunk=self.chunk, mesh=mesh, **common)
         elif mesh is not None:
             out = _sharded_run(*args, mesh=mesh, **common)
+            _record_programs([(_sharded_run, {"mesh": mesh, **common},
+                               args)])
         else:
             out = _batched_run(*args, **common)
+            _record_programs([(_batched_run, common, args)])
         if g["pad"]:
             out = jax.tree.map(lambda x: x[:g["B"]], out)
 

@@ -106,3 +106,112 @@ def parse_collective_bytes(hlo_text: str) -> dict:
         link_bytes += rec["bytes"] * _LINK_FACTOR[name]
         total += rec["bytes"]
     return {"per_op": per_op, "link_bytes": link_bytes, "total_output_bytes": total}
+
+
+# --------------------------------------------------------------- stages
+
+# a computation's header: ``%name (params) -> shape {`` (ENTRY marked)
+_COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{\s*$")
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_REF_RE = re.compile(r"%([\w.\-]+)")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_WHILE_RE = re.compile(r"\b(?:condition|body)=%?([\w.\-]+)")
+_BRANCH_RE = re.compile(
+    r"\b(?:true_computation|false_computation)=%?([\w.\-]+)"
+    r"|\bbranch_computations=\{([^}]*)\}")
+_CALL_RE = re.compile(r"\scall\(.*\bto_apply=%?([\w.\-]+)")
+_IN_LOOP_RE = re.compile(r"(?:^|/)while(?:/|$)")
+
+
+def _computations(hlo_text: str):
+    """``{computation: [instruction line, ...]}`` and the entry's name."""
+    comps, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        m = _COMP_RE.match(line)
+        if m:
+            cur = m.group(2)
+            comps[cur] = []
+            if m.group(1):
+                entry = cur
+        elif cur is not None and _INSTR_RE.match(line):
+            comps[cur].append(line)
+    return comps, entry
+
+
+def _callees(line: str):
+    """(computation, reached through a loop) pairs that one instruction
+    runs as a sequence of top-level instructions."""
+    out = [(c, True) for c in _WHILE_RE.findall(line)]
+    for one, many in _BRANCH_RE.findall(line):
+        names = [one] if one else [n.strip().lstrip("%")
+                                   for n in many.split(",")]
+        out += [(n, False) for n in names if n]
+    m = _CALL_RE.search(line)
+    if m:
+        out.append((m.group(1), False))
+    return out
+
+
+def op_stages(hlo_text: str, prefix: str = "step.") -> dict[str, str]:
+    """Each top-level instruction of a compiled module's text, mapped to
+    the stage it was traced under.
+
+    Top-level instructions are those the device runs one by one, and a
+    profiler trace names: the entry computation's, and those of the
+    computations it reaches through ``while`` (body, condition),
+    ``conditional`` branches and ``call`` (not the insides of fusions or
+    reducers).  An instruction's stage is the innermost ``<prefix><stage>``
+    component of its ``op_name`` (a fusion's is its root's).  Failing
+    that, a source path gives ``loop`` where it lies under ``while`` or
+    the instruction runs in a loop (the scan's own slicing, trip count
+    and condition, work hoisted into the loop) and ``outside``
+    elsewhere.  An instruction the compiler made without a source path
+    (a layout copy, a rewritten reduction) takes the stage of its users
+    where they all have the same one, repeated back along chains of such
+    instructions; what is left is ``loop`` inside a loop's computations
+    (carry copies, the loop's tuples) and ``outside`` elsewhere."""
+    comps, entry = _computations(hlo_text)
+    in_loop, todo = {}, ([(entry, False)] if entry in comps else [])
+    while todo:
+        comp, looped = todo.pop()
+        if comp in in_loop and (in_loop[comp] or not looped):
+            continue
+        in_loop[comp] = looped
+        for line in comps[comp]:
+            todo += [(c, looped or lp) for c, lp in _callees(line)
+                     if c in comps]
+    pat = re.compile(re.escape(prefix) + r"([A-Za-z_]\w*)")
+    stages = {}
+    for comp, looped in in_loop.items():
+        names, refs, label = [], {}, {}
+        for line in comps[comp]:
+            name = _INSTR_RE.match(line).group(1)
+            names.append(name)
+            refs[name] = _REF_RE.findall(line.split("=", 1)[1])
+            m = _OP_NAME_RE.search(line)
+            op_name = m.group(1) if m else ""
+            found = pat.findall(op_name)
+            if found:
+                label[name] = found[-1]
+            elif "/" in op_name:
+                in_loop_path = looped or _IN_LOOP_RE.search(op_name)
+                label[name] = "loop" if in_loop_path else "outside"
+        users = {n: [] for n in names}
+        for n in names:
+            for r in set(refs[n]):
+                if r in users:
+                    users[r].append(n)
+        changed = True
+        while changed:
+            changed = False
+            for n in names:
+                seen = {label.get(u) for u in users[n]}
+                if n in label or len(seen) != 1:
+                    continue
+                (st,) = seen
+                if st not in (None, "loop", "outside"):
+                    label[n] = st
+                    changed = True
+        for n in names:
+            stages[n] = label.get(n, "loop" if looped else "outside")
+    return stages
