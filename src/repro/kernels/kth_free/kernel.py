@@ -12,16 +12,38 @@ lanes hold nodes, sublanes hold systems), and bit-exact against the sort
 reference because the selected value is an element of the input, not an
 approximation.
 
-Single-block kernel (no grid): the node matrix of any realistic SCC fits
-VMEM many times over ([S, maxN] is a few KB); the win is replacing the sort
-network with 32 compare-and-count sweeps.
+Layout.  An unvmapped ``kth_free_pallas`` call is one block with no grid:
+the node matrix of any realistic SCC fits VMEM many times over ([S, maxN]
+is a few KB).  Under ``vmap`` (the campaign's lanes, each device's lanes
+inside the grid ``shard_map``, a vmap nested in another) its
+``custom_vmap`` rule folds every lane into one invocation of the
+lane-folded kernel, an [L, S, maxN] block walked once for all L·S rows.
+The 32 passes are bound by the latency of each pass's cross-lane count,
+not by its width, so L lanes in one walk cost about what one lane does,
+where a grid step per lane (Pallas's own batching rule) would run L walks
+one after another.  Where the lanes outgrow ``LANE_BLOCK_BYTES`` the
+kernel grids over groups of as many lanes as fit.
 """
 
 from __future__ import annotations
 
+from functools import cache, partial
+
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 from jax.experimental import pallas as pl
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
+
+from repro import obs
+
+#: node-free bytes of one lane-folded block.  The walk keeps about nine
+#: block-sized arrays in VMEM (the input's two buffers, the keys, the
+#: active mask, a bit plane and their products): a 2 MB block of 12
+#: lanes of [4, 10240] asked v5e's compiler for 18.7 MB of its 16 MiB of
+#: scoped VMEM, so a block stays at 1 MiB.
+LANE_BLOCK_BYTES = 1 << 20
 
 
 def _f32_to_ordered_u32(x):
@@ -40,26 +62,31 @@ def _ordered_u32_to_f32(u):
 
 def radix_select_kth(node_free, n_req):
     """Pure-jnp radix select (the kernel's algorithm, usable on any backend
-    and inside scan/vmap).  node_free: [S, maxN] f32; n_req: [S] int.
-    Returns [S] f32: the n_req-th smallest per row (1-indexed, clipped)."""
-    S, N = node_free.shape
-    u = _f32_to_ordered_u32(node_free)                      # [S, N]
-    k0 = jnp.clip(n_req, 1, N).astype(jnp.int32)            # [S]
+    and inside scan/vmap).  node_free: [..., S, maxN] f32; n_req: [..., S]
+    int, or the column [..., S, 1] (the lane-folded kernel's form, which
+    keeps every count in the layout of its row).  Returns n_req's shape in
+    f32: the n_req-th smallest per row (1-indexed, clipped)."""
+    N = node_free.shape[-1]
+    col = n_req.ndim == node_free.ndim
+    u = _f32_to_ordered_u32(node_free)                      # [..., S, N]
+    k0 = jnp.clip(n_req, 1, N).astype(jnp.int32)            # [..., S(, 1)]
 
     def bit_step(i, carry):
         active, k, val = carry
         shift = jnp.uint32(31) - i.astype(jnp.uint32)
-        bit = ((u >> shift) & jnp.uint32(1)).astype(jnp.int32)   # [S, N]
-        zeros = jnp.sum(active * (1 - bit), axis=1)              # [S]
-        go_one = k > zeros                                       # [S]
+        bit = ((u >> shift) & jnp.uint32(1)).astype(jnp.int32)   # [.., N]
+        zeros = jnp.sum(active * (1 - bit), axis=-1, keepdims=col)
+        go_one = k > zeros                                       # as k
         val = val | jnp.where(go_one, jnp.uint32(1) << shift, jnp.uint32(0))
-        keep_bit = go_one.astype(jnp.int32)[:, None]             # [S, 1]
+        keep_bit = go_one.astype(jnp.int32)
+        if not col:
+            keep_bit = keep_bit[..., None]                       # [.., 1]
         active = active * (bit == keep_bit).astype(jnp.int32)
         k = jnp.where(go_one, k - zeros, k)
         return active, k, val
 
-    active0 = jnp.ones((S, N), jnp.int32)
-    val0 = jnp.zeros((S,), jnp.uint32)
+    active0 = jnp.ones(node_free.shape, jnp.int32)
+    val0 = jnp.zeros(k0.shape, jnp.uint32)
     _, _, val = jax.lax.fori_loop(0, 32, bit_step, (active0, k0, val0))
     return _ordered_u32_to_f32(val)
 
@@ -78,14 +105,11 @@ def _kth_free_kernel(free_ref, nreq_ref, out_ref):
     out_ref[...] = radix_select_kth(free_ref[...], nreq_ref[:, 0])[:, None]
 
 
-def kth_free_pallas(node_free, n_req, *, interpret: bool = True):
-    """node_free: [S, maxN] f32; n_req: [S] int32.  Returns [S] f32.
-
-    Every block's last two dimensions equal the array's, the one form
-    Mosaic accepts for S and maxN off the (8, 128) tile.  The output is
-    therefore the 2-D column [S, 1], sliced back here: a 1-D (S,) block
-    compiles alone but not under ``vmap`` (the campaign grid, the session
-    pool), where it becomes (Squeezed, S) on an [L, S] array."""
+def _kth_free_block(node_free, n_req, interpret):
+    """One [S, maxN] table in one block, no grid.  Every block's last two
+    dimensions equal the array's, the one form Mosaic accepts for S and
+    maxN off the (8, 128) tile.  The output is therefore the 2-D column
+    [S, 1], sliced back here."""
     S, _ = node_free.shape
     return pl.pallas_call(
         _kth_free_kernel,
@@ -94,7 +118,92 @@ def kth_free_pallas(node_free, n_req, *, interpret: bool = True):
         out_specs=pl.BlockSpec((S, 1), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((S, 1), jnp.float32),
         interpret=interpret,
-    )(node_free.astype(jnp.float32), n_req.astype(jnp.int32)[:, None])[:, 0]
+    )(node_free, n_req[:, None])[:, 0]
+
+
+def _kth_free_kernel_lanes(free_ref, nreq_ref, out_ref):
+    out_ref[...] = radix_select_kth(free_ref[...], nreq_ref[...])
+
+
+def _lane_groups(L, S, N):
+    """(lanes G of one block, grid steps) of the lane-folded kernel over
+    L lanes of [S, N]: G is the most lanes that fit ``LANE_BLOCK_BYTES``
+    (at least one), and the grid has one step per group."""
+    G = min(L, max(1, LANE_BLOCK_BYTES // (S * N * 4)))
+    return G, pl.cdiv(L, G)
+
+
+# The lane-folded kernel's result passes through ``_noted_p``, which is
+# nothing in the compiled program: lowering it notes the fold in
+# ``repro.obs.kth_free_calls()``.  A note at trace time would also count
+# the folds an outer vmap's rule replaces before anything is lowered.
+_noted_p = Primitive("kth_free_noted")
+_noted_p.def_abstract_eval(lambda x, **_: x)
+_noted_p.def_impl(lambda x, **fold: jax.jit(partial(_noted_p.bind, **fold))(x))
+
+
+def _noted_lowering(ctx, x, **fold):
+    obs.note_kth_free(**fold)
+    return [x]
+
+
+mlir.register_lowering(_noted_p, _noted_lowering)
+
+
+def _kth_free_lanes(node_free, n_req, interpret):
+    """The lane-folded kernel: node_free [L, S, maxN] f32, n_req [L, S]
+    int32 -> [L, S] f32, one walk over the L·S rows of a block.  A last
+    group that runs past L reads lanes it never writes back."""
+    L, S, N = node_free.shape
+    G, steps = _lane_groups(L, S, N)
+    out = pl.pallas_call(
+        _kth_free_kernel_lanes,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((G, S, N), lambda g: (g, 0, 0)),
+                  pl.BlockSpec((G, S, 1), lambda g: (g, 0, 0))],
+        out_specs=pl.BlockSpec((G, S, 1), lambda g: (g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((L, S, 1), jnp.float32),
+        interpret=interpret,
+        name="kth_free_time",
+    )(node_free, n_req[..., None])[..., 0]
+    return _noted_p.bind(out, lanes=L, grid_steps=steps,
+                         block_bytes=G * S * N * 4)
+
+
+@cache
+def _kth_free_op(interpret: bool):
+    """``kth_free_pallas``'s operation, [..., S, maxN] -> [..., S]: one
+    table goes to ``_kth_free_block``, a stack of them to
+    ``_kth_free_lanes``.  Its vmap rule adds the mapped axis to the stack
+    (broadcasting an argument that is not mapped), so a vmap at any depth
+    folds into the same single invocation."""
+    @custom_vmap
+    def op(node_free, n_req):
+        if node_free.ndim == 2:
+            return _kth_free_block(node_free, n_req, interpret)
+        lead, (S, N) = node_free.shape[:-2], node_free.shape[-2:]
+        out = _kth_free_lanes(node_free.reshape(-1, S, N),
+                              n_req.reshape(-1, S), interpret)
+        return out.reshape(*lead, S)
+
+    @op.def_vmap
+    def _(axis_size, in_batched, node_free, n_req):
+        node_free, n_req = (
+            x if b else jnp.broadcast_to(x, (axis_size, *x.shape))
+            for x, b in zip((node_free, n_req), in_batched))
+        return op(node_free, n_req), True
+
+    return op
+
+
+def kth_free_pallas(node_free, n_req, *, interpret: bool = True):
+    """node_free: [S, maxN] f32; n_req: [S] int32.  Returns [S] f32.
+
+    Alone: one block, no grid.  Under ``vmap`` at any depth: the
+    lane-folded kernel, one invocation for all the mapped lanes (see the
+    module docstring)."""
+    return _kth_free_op(interpret)(node_free.astype(jnp.float32),
+                                   n_req.astype(jnp.int32))
 
 
 def _kth_free_kernel_batched(free_ref, nreq_ref, out_ref):

@@ -34,9 +34,17 @@ Stages (``STAGES``):
 An instruction outside every stage but inside the scan's loop is
 ``"loop"`` (xs slicing, the trip counter, carry copies, the condition);
 anything else is ``"outside"`` (set-up and the result epilogue).
+
+How the kth-free kernel is invoked: each lowering of its lane-folded form
+(``repro.kernels.kth_free``, a vmapped ``kth_free_pallas``) notes the
+lanes one invocation covers, its grid steps and the node-free bytes of
+one block, readable as ``kth_free_calls()``.  An unvmapped call notes
+nothing.
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import numpy as np
@@ -49,6 +57,13 @@ PREFIX = "step."
 
 _programs: tuple = ()      # ((fn, static kwargs, shapes), ...) of the last run
 _tables: dict = {}         # record key -> stage table
+_kth_free: list = []       # KthFreeCall per lowering of the folded kernel
+
+
+class KthFreeCall(NamedTuple):
+    lanes: int             # lanes one invocation covers
+    grid_steps: int        # groups of lanes it walks one after another
+    block_bytes: int       # node-free bytes of one block
 
 
 def stage(name: str):
@@ -106,3 +121,15 @@ def stage_table() -> dict[str, str]:
                     clash.add(name)
         _tables[key] = {n: s for n, s in table.items() if n not in clash}
     return _tables[key]
+
+
+def note_kth_free(lanes: int, grid_steps: int, block_bytes: int) -> None:
+    """Note one lowering of the lane-folded kth-free kernel."""
+    _kth_free.append(KthFreeCall(lanes, grid_steps, block_bytes))
+
+
+def kth_free_calls() -> tuple[KthFreeCall, ...]:
+    """The lane-folded kth-free kernel's lowerings since the process
+    started, oldest first.  A program that JAX has lowered before and
+    serves from its caches notes nothing again."""
+    return tuple(_kth_free)

@@ -107,6 +107,7 @@ from repro.kernels.kth_free import (kth_free_ref, kth_free_pallas,  # noqa: E402
                                     kth_free_time_shared,
                                     radix_select_kth,
                                     radix_select_kth_batched)
+from repro.kernels.kth_free.kernel import LANE_BLOCK_BYTES  # noqa: E402
 
 
 @pytest.mark.parametrize("s,n,seed", [
@@ -255,6 +256,109 @@ def test_kth_free_rows_clips_out_of_range_requests():
     nreq = jnp.asarray(np.array([0, 99, 3], np.int32))
     out = np.asarray(kth_free_time_rows(table, sels, nreq))
     np.testing.assert_array_equal(out, [0.0, 11.0, 2.0])
+
+
+# ------------------------------------------------- kth free, lanes folded
+
+from repro import obs  # noqa: E402
+
+
+def _lanes_case(b, n, seed, s=4):
+    """[b, s, n] node-free tables with idle ties and BIG sentinels, and
+    [b, s] requests that run past both ends of [1, n] (clipped)."""
+    rng = np.random.default_rng(seed)
+    free = rng.uniform(0, 1e6, (b, s, n)).astype(np.float32)
+    free[rng.random((b, s, n)) < 0.3] = 1e30
+    free[rng.random((b, s, n)) < 0.3] = 0.0
+    nreq = rng.integers(-2, n + 3, (b, s)).astype(np.int32)
+    return jnp.asarray(free), jnp.asarray(nreq)
+
+
+def _pallas_interpret(free, nreq):
+    return kth_free_time(free, nreq, force="pallas_interpret")
+
+
+@pytest.mark.parametrize("n", [136, 10240])
+@pytest.mark.parametrize("b", [1, 3, 12, 13])
+@pytest.mark.parametrize("batched", [(True, False), (True, True),
+                                     (False, True), (False, False)])
+def test_kth_free_folded_bit_exact(batched, b, n):
+    """vmap over lanes of the Pallas kth-free (interpret mode) vs the sort
+    oracle, bit for bit, for each pattern of mapped arguments: the
+    campaign's (tables mapped, the job's request shared), both mapped,
+    one table asked for many requests, and neither (a vmap over another
+    argument, which leaves the call unvmapped).  maxN 10240 takes more
+    than one group of lanes from 12 lanes up."""
+    free, nreq = _lanes_case(b, n, seed=100 * b + n % 97)
+    tf, tn = batched
+    f_ax, n_ax = (0 if tf else None), (0 if tn else None)
+    free_in, nreq_in = (free if tf else free[0]), (nreq if tn else nreq[0])
+
+    def lane(i, f, r):
+        return _pallas_interpret(f, r) + 0.0 * i
+    out = jax.jit(jax.vmap(lane, in_axes=(0, f_ax, n_ax)))(
+        jnp.arange(b, dtype=jnp.float32), free_in, nreq_in)
+    if tf or tn:
+        ref = jax.vmap(kth_free_ref, in_axes=(f_ax, n_ax))(free_in, nreq_in)
+    else:
+        ref = jnp.broadcast_to(kth_free_ref(free_in, nreq_in), (b, 4))
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+
+
+def test_kth_free_folded_inside_scan_matches_sort():
+    """The campaign's shape of call, vmap over lanes of a lax.scan whose
+    step feeds the kth-free times back into the tables: every step's
+    result and the final tables equal the sort placer's."""
+    free, nreq = _lanes_case(12, 136, seed=7)
+
+    def grid(force):
+        def lane(c, r):
+            def body(c, _):
+                k = kth_free_time(c, r, force=force)
+                return jnp.where(c <= k[:, None], k[:, None] + 1.0, c), k
+            return jax.lax.scan(body, c, None, length=6)
+        return jax.jit(jax.vmap(lane, in_axes=(0, None)))(free, nreq[0])
+    for got, want in zip(jax.tree.leaves(grid("pallas_interpret")),
+                         jax.tree.leaves(grid("sort"))):
+        np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("inner_batched", [(True, True), (True, False)])
+def test_kth_free_folded_nested_vmap(inner_batched):
+    """Sessions x lanes: a vmap around the lanes' vmap folds both axes
+    into one invocation of 5 x 3 lanes."""
+    free, nreq = _lanes_case(15, 136, seed=11)
+    free, nreq = free.reshape(5, 3, 4, 136), nreq.reshape(5, 3, 4)
+    n_ax = 0 if inner_batched[1] else None
+    nreq_in = nreq if inner_batched[1] else nreq[:, 0]
+    jax.clear_caches()
+    seen = len(obs.kth_free_calls())
+    out = jax.vmap(jax.vmap(_pallas_interpret, in_axes=(0, n_ax)))(
+        free, nreq_in)
+    ref = jax.vmap(jax.vmap(kth_free_ref, in_axes=(0, n_ax)))(free, nreq_in)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(out))
+    assert [c.lanes for c in obs.kth_free_calls()[seen:]] == [15]
+
+
+def test_kth_free_calls_record_folded_lowerings_only():
+    """An unvmapped call notes nothing; a vmapped one notes its lanes,
+    its grid steps and its block's bytes."""
+    jax.clear_caches()
+    seen = len(obs.kth_free_calls())
+    free, nreq = _lanes_case(7, 136, seed=3)
+    np.testing.assert_array_equal(
+        np.asarray(kth_free_ref(free[0], nreq[0])),
+        np.asarray(_pallas_interpret(free[0], nreq[0])))
+    assert obs.kth_free_calls()[seen:] == ()
+    jax.vmap(_pallas_interpret)(free, nreq)
+    assert obs.kth_free_calls()[seen:] == (
+        obs.KthFreeCall(lanes=7, grid_steps=1, block_bytes=7 * 4 * 136 * 4),)
+    big, nbig = _lanes_case(13, 10240, seed=4)
+    jax.vmap(_pallas_interpret, in_axes=(0, None))(big, nbig[0])
+    lanes = LANE_BLOCK_BYTES // (4 * 10240 * 4)
+    assert obs.kth_free_calls()[seen + 1:] == (obs.KthFreeCall(
+        lanes=13, grid_steps=-(-13 // lanes),
+        block_bytes=lanes * 4 * 10240 * 4),)
 
 
 # ---------------------------------------------------------------- SSD scan

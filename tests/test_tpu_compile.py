@@ -8,6 +8,10 @@ kernels and the campaign's scan step for a ``v5e:2x2`` topology at the
 JSCC facility's shapes, where they are used.  Nothing runs, so nothing
 here says anything about results or times.
 
+Under ``vmap`` the kth-free kernel folds every lane into one invocation
+(``repro.kernels.kth_free``); ``repro.obs.kth_free_calls()`` says how many
+lanes and grid steps each lowering of the folded kernel took.
+
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
 file.
@@ -19,13 +23,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import AxisType, NamedSharding, SingleDeviceSharding
 
+from repro import obs
 from repro.core import JSCC_SYSTEMS, Scheduler, make_npb_workload, make_policy
 from repro.core.engine import (_batched_run, _chunk_advance, _chunk_init,
                                _sharded_run, _stream_xs)
 from repro.data.scenarios import synthetic_swf_arrays, workload_from_arrays
 from repro.kernels.kth_free import kth_free_pallas, kth_free_pallas_batched
+from repro.kernels.kth_free.kernel import LANE_BLOCK_BYTES
 from repro.service import SessionPool
 from repro.sharding.grid import grid_spec, replicated
+from repro.utils.hlo import op_stages
 
 S, MAXN = 4, 136            # JSCC: 4 systems, the largest has 136 nodes
 
@@ -96,12 +103,28 @@ def test_kth_free_alone(one_chip, shape):
     assert "tpu_custom_call" in hlo
 
 
+def _folds(fn, *args, **kw):
+    """(compiled text, ``obs.kth_free_calls()`` of that one lowering)."""
+    jax.clear_caches()
+    seen = len(obs.kth_free_calls())
+    hlo = fn.lower(*args, **kw).compile().as_text()
+    return hlo, obs.kth_free_calls()[seen:]
+
+
 @pytest.mark.parametrize("shape", [(8, S, MAXN), (8, S, 10240)])
 def test_kth_free_under_vmap_scan(one_chip, shape):
-    hlo = _compiled_text(_vmap_scan(_pallas),
-                         _sds(shape, jnp.float32, one_chip),
-                         _sds(shape[:2], jnp.int32, one_chip))
+    """The lanes fold into one invocation: one block where they fit
+    ``LANE_BLOCK_BYTES``, else a grid over groups of as many as fit
+    (six lanes of [4, 10240])."""
+    hlo, folds = _folds(jax.jit(_vmap_scan(_pallas)),
+                        _sds(shape, jnp.float32, one_chip),
+                        _sds(shape[:2], jnp.int32, one_chip))
     assert "tpu_custom_call" in hlo
+    per_lane = S * shape[-1] * 4
+    G = min(8, LANE_BLOCK_BYTES // per_lane)
+    assert folds == (obs.KthFreeCall(lanes=8, grid_steps=-(-8 // G),
+                                     block_bytes=G * per_lane),)
+    assert (folds[0].grid_steps > 1) == (shape[-1] == 10240)
 
 
 @pytest.mark.parametrize("W", [17, 33])
@@ -120,12 +143,12 @@ def test_kth_free_batched_under_vmap_scan(one_chip):
 
 
 def _campaign_inputs(J=512):
-    """Flat-batch inputs of an 8-lane (4 K x 2 seeds) paper campaign on
+    """Flat-batch inputs of a 12-lane (6 K x 2 seeds) paper campaign on
     the JSCC systems, as ``Scheduler.run`` builds them."""
     w = workload_from_arrays(*synthetic_swf_arrays(J, seed=11),
                              JSCC_SYSTEMS)
     pol = make_policy("paper").with_params(
-        k=np.asarray([0.0, 0.1, 0.2, 0.3], np.float32))
+        k=np.asarray([0.0, 0.05, 0.1, 0.2, 0.5, 0.85], np.float32))
     g = Scheduler(pol, warm_start=True, seeds=[0, 1],
                   placer="pallas")._grid(w, False)
     assert g["common"]["core"] == "arrival"
@@ -134,11 +157,20 @@ def _campaign_inputs(J=512):
 
 def test_arrival_core_campaign_step(one_chip):
     """The jitted vmapped arrival-core scan (the paper policy's campaign
-    path) with the compiled Pallas placer, at the JSCC shapes."""
+    path) with the compiled Pallas placer, at the JSCC shapes: the scan's
+    step runs exactly one kth-free custom call, named after
+    ``kth_free_time`` and under ``step.earliest``, which walks all 12
+    lanes in one block."""
     args, common = _campaign_inputs()
     sds = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), args)
-    hlo = _batched_run.lower(*sds, **common).compile().as_text()
-    assert "tpu_custom_call" in hlo
+    hlo, folds = _folds(_batched_run, *sds, **common)
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%")
+             for line in hlo.splitlines() if "custom-call(" in line]
+    kth = [c for c in calls if "kth_free" in c]
+    assert len(kth) == 1 and kth[0].startswith("kth_free_time"), calls
+    assert op_stages(hlo)[kth[0]] == "earliest"
+    assert folds == (obs.KthFreeCall(lanes=12, grid_steps=1,
+                                     block_bytes=12 * S * MAXN * 4),)
 
 
 def test_arrival_core_in_grid_shard_map(topo):
