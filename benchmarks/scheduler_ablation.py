@@ -323,7 +323,7 @@ def run_service():
     against the batch ``Scheduler.run`` is asserted (the service
     acceptance criterion); the row records the warm per-decision latency
     (the one compile-paying step is excluded as the latency maximum)."""
-    from repro.service import Dispatcher
+    from repro.service import Dispatcher, own_columns
 
     w = queue_streams()["swf"]
     pol = make_policy("paper", k=0.10)
@@ -332,7 +332,8 @@ def run_service():
     disp = Dispatcher(w, pol, warm_start=True, queue=qs)
     for j in range(len(w.prog)):
         disp.drive(until=float(w.arrival[j]))
-        disp.submit(int(w.prog[j]), float(w.arrival[j]))
+        disp.submit(int(w.prog[j]), float(w.arrival[j]),
+                    **own_columns(w, j))
     disp.drain()
     res = disp.result()
     for f in ("total_energy", "makespan", "total_wait", "max_wait",
@@ -355,7 +356,7 @@ def run_pool():
     bit-identical to the batch run, and the per-decision cost (warm
     pool-step wall / N) must scale SUB-linearly in N — the vmapped step
     amortizes dispatch and device traffic across the whole pool."""
-    from repro.service import SessionPool
+    from repro.service import SessionPool, own_columns
 
     w = queue_streams()["swf"]
     pol = make_policy("paper", k=0.10)
@@ -369,7 +370,7 @@ def run_pool():
             t = float(w.arrival[j])
             pool.drive(t)
             for i in range(n):
-                pool.submit(i, int(w.prog[j]), t)
+                pool.submit(i, int(w.prog[j]), t, **own_columns(w, j))
         pool.drain()
         for i in range(n):
             res = pool.result(i)

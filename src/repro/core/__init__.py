@@ -1,6 +1,7 @@
 from repro.core.systems import (
     ComputeSystem, JSCC_SYSTEMS, JSCC_BY_NAME, TPU_SYSTEMS, ALL_SYSTEMS,
-    KNL, BROADWELL, SKYLAKE, CASCADE_LAKE,
+    KNL, BROADWELL, SKYLAKE, CASCADE_LAKE, CORI_SYSTEMS, CORI_HASWELL,
+    CORI_KNL,
 )
 from repro.core.workload_model import (
     JobProfile, NPB_PROFILES, NPB_NODES, NPB_CORES, npb_tables,

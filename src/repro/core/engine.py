@@ -153,6 +153,12 @@ class Workload:
     # compute-phase and every non-idle joule is dynamic.
     T_comp: np.ndarray | None = None
     E_comp: np.ndarray | None = None
+    # per-job columns (a trace replay's own runtimes and widths; None = each
+    # job runs as its class, see ``_job_scales``):
+    # [J] f32 the job's realised runtime over its class's reference runtime
+    t_scale: np.ndarray | None = None
+    # [J, S] i32 the job's node count on each system
+    n_req_job: np.ndarray | None = None
 
 
 def make_npb_workload(systems, order=("BT", "EP", "IS", "LU", "SP"),
@@ -226,7 +232,99 @@ def _workload_arrays(w: Workload) -> dict:
     arrs["E_comp"] = jnp.asarray(E_comp, jnp.float32)
     if w.outage is not None and w.outage.size:
         arrs["outage"] = jnp.asarray(w.outage, jnp.float32)
+    J, S = len(w.prog), len(w.n_nodes)
+    if w.t_scale is not None:
+        ts = _column(w.t_scale, (J,), "t_scale")
+        if not (np.isfinite(ts) & (ts > 0)).all():
+            raise ValueError("t_scale must be finite and positive")
+        arrs["t_scale"] = jnp.asarray(ts, jnp.float32)
+    if w.n_req_job is not None:
+        nj = _column(w.n_req_job, (J, S), "n_req_job")
+        if (nj < 1).any() or (nj > np.asarray(w.n_nodes)[None, :]).any():
+            raise ValueError("n_req_job must lie in [1, n_nodes[s]] on "
+                             "every system")
+        arrs["n_req_job"] = jnp.asarray(nj, jnp.int32)
     return arrs
+
+
+def _column(x, shape, name):
+    x = np.asarray(x)
+    if x.shape != shape:
+        raise ValueError(f"{name} has shape {x.shape}, the workload needs "
+                         f"{shape}")
+    return x
+
+
+def _job_need(arrs, j, p, sel=None):
+    """Nodes job ``j`` of class ``p`` asks of each system ([..., S]), or of
+    system ``sel``: its own width where the workload has per-job widths,
+    else its class's."""
+    if "n_req_job" not in arrs:
+        return arrs["n_req"][p] if sel is None else arrs["n_req"][p, sel]
+    with _stage("job"):
+        return arrs["n_req_job"][j] if sel is None \
+            else arrs["n_req_job"][j, sel]
+
+
+def _job_t_scale(arrs, j):
+    """Job ``j``'s realised runtime over its class's; None where the
+    workload has no per-job runtimes."""
+    if "t_scale" not in arrs:
+        return None
+    with _stage("job"):
+        return arrs["t_scale"][j]
+
+
+def _job_scales(arrs, j, p, sel):
+    """What turns job ``j``'s class values on system ``sel`` into its own:
+    ``(runtime, energy, draw)`` multipliers ``(t_scale_j, t_scale_j * w,
+    w)`` with ``w = n_req_job[j, sel] / n_req[p, sel]`` its width over its
+    class's.
+    Work (``C``) scales with node-seconds and is left as the class's; the
+    learned tables keep learning per class.  None where the workload has
+    no per-job columns: its jobs run as their class, and the step
+    compiles as it does without this helper."""
+    if "t_scale" not in arrs and "n_req_job" not in arrs:
+        return None
+    ts = _job_t_scale(arrs, j)
+    with _stage("job"):
+        if ts is None:
+            ts = jnp.float32(1.0)
+        if "n_req_job" not in arrs:
+            return ts, ts, jnp.float32(1.0)
+        wr = (arrs["n_req_job"][j, sel].astype(jnp.float32)
+              / arrs["n_req"][p, sel].astype(jnp.float32))
+        return ts, ts * wr, wr
+
+
+def _running_mean(mean, n, x, scales):
+    """A learned mean after its ``n + 1``-th observation ``x``.  With
+    per-job columns (``scales``) both operands of the running sum's add
+    take a trip through the integer domain first, so that no compiler can
+    fuse a product into that add as one fused multiply-add: one program
+    built from a step (the batch scan) might contract where another (a
+    live session's step) does not, and per-job observations, themselves
+    products, would leave the two programs' tables an ulp apart.  An
+    ``optimization_barrier`` does not hold here: XLA:CPU drops it before
+    fusing."""
+    total = mean * n
+    if scales is not None:
+        with _stage("job"):
+            # n is a run count, never negative: the or adds no bit, and
+            # the compiler cannot know it
+            sign = (n < 0.0).astype(jnp.int32)
+            total, x = (jax.lax.bitcast_convert_type(
+                jax.lax.bitcast_convert_type(v, jnp.int32) | sign,
+                jnp.float32) for v in (total, x))
+    return (total + x) / (n + 1)
+
+
+def _job_nodes(arrs, prog, sel):
+    """[J] nodes each job held on its system ``sel``."""
+    if "n_req_job" in arrs:
+        return jnp.take_along_axis(arrs["n_req_job"], sel[:, None],
+                                   axis=1)[:, 0]
+    return arrs["n_req"][prog, sel]
 
 
 def _push_out_of_outage(avail, outage):
@@ -459,7 +557,7 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
             k = jnp.where(jnp.isnan(kj), pol_k, kj)
 
         with _stage("earliest"):
-            nreq_row = n_req[p]                                  # [S]
+            nreq_row = _job_need(arrs, j, p)                     # [S]
             kth, avail = _earliest(node_free, nreq_row, arr, placer,
                                    outage)
 
@@ -494,6 +592,11 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
             else:
                 T_act = T_upd
                 E_act = E_true[p, sel] * factor
+        scales = _job_scales(arrs, j, p, sel)
+        if scales is not None:
+            with _stage("job"):
+                T_upd, T_act = T_upd * scales[0], T_act * scales[0]
+                E_act = E_act * scales[1]
 
         with _stage("alloc"):
             start = avail[sel]
@@ -503,10 +606,10 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
 
         with _stage("learn"):
             n = runs[p, sel].astype(jnp.float32)
-            C_tab = C_tab.at[p, sel].set((C_tab[p, sel] * n + C_act)
-                                         / (n + 1))
-            T_tab = T_tab.at[p, sel].set((T_tab[p, sel] * n + T_upd)
-                                         / (n + 1))
+            C_tab = C_tab.at[p, sel].set(
+                _running_mean(C_tab[p, sel], n, C_act, scales))
+            T_tab = T_tab.at[p, sel].set(
+                _running_mean(T_tab[p, sel], n, T_upd, scales))
             runs = runs.at[p, sel].add(1)
 
         with _stage("account"):
@@ -545,7 +648,7 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
                     "max_wait": wait_max, "busy": busy,
                     **_power_totals(arrs, fin_max, busy), **tabs}
         sel, start, fin, wait, E, T_act, tier = ys
-        nodes = n_req[prog, sel]                                 # [J]
+        nodes = _job_nodes(arrs, prog, sel)                      # [J]
         busy = jnp.zeros(S, jnp.float32).at[sel].add(T_act * nodes)
         makespan = fin.max()
         return {
@@ -649,8 +752,8 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
         with _stage("earliest"):
             jj = jnp.minimum(j, J - 1)
             p = prog[jj]
-            kth, avail = _earliest(node_free, n_req[p], arrival[jj], placer,
-                                   outage)
+            kth, avail = _earliest(node_free, _job_need(arrs, jj, p),
+                                   arrival[jj], placer, outage)
         with _stage("select"):
             sel = select(
                 policy, c_row=C_tab[p], t_row=T_tab[p], runs_row=runs[p],
@@ -668,8 +771,8 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
             jjs = jnp.minimum(pend, J - 1)                        # [Wc]
             ps = prog[jjs]                                        # [Wc]
             kths, avails = _earliest_shared(
-                node_free, n_req[ps], arrival[jjs][:, None], placer,
-                outage)                                           # [Wc, S]
+                node_free, _job_need(arrs, jjs, ps), arrival[jjs][:, None],
+                placer, outage)                                   # [Wc, S]
         with _stage("select"):
             keys = jax.vmap(lambda j: jax.random.fold_in(sel_key, j))(jjs)
             if tiered:
@@ -696,8 +799,12 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
         with _stage("fault"):
             T_acts = (tt["T"][ps, fs, sels] if tiered
                       else T_true[ps, sels]) * factors
+        scales = _job_scales(arrs, jjs, ps, sels)
+        if scales is not None:
+            with _stage("job"):
+                T_acts = T_acts * scales[0]
         with _stage("alloc"):
-            needs = n_req[ps, sels]
+            needs = _job_need(arrs, jjs, ps, sels)
             trials = jax.vmap(_alloc, in_axes=(None, 0, 0, 0, 0))(
                 node_free, sels, kths[idx, sels], needs, starts + T_acts)
         return jjs, ps, sels, fs, starts, T_acts, factors, needs, trials
@@ -736,7 +843,7 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
             with _stage("earliest"):
                 kth_h2 = kth_free_time(
                     trials[:, sel_h, :],
-                    jnp.broadcast_to(n_req[p_h, sel_h], (Wc,)),
+                    jnp.broadcast_to(_job_need(arrs, hj, p_h, sel_h), (Wc,)),
                     force=placer or "sort")
                 avail_h2 = jnp.maximum(arrival[hj], kth_h2)       # [Wc]
                 if outage is not None:
@@ -788,13 +895,19 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
                                                          T_tab, runs)
                 with _stage("alloc"):
                     s_b = avail_b[sel_b]
-                    fin_b = s_b + T_true[p_b, sel_b] * _fault_factor(
+                    T_b = T_true[p_b, sel_b] * _fault_factor(
                         fault_key, bj, fvec)
+                scales_b = _job_scales(arrs, bj, p_b, sel_b)
+                if scales_b is not None:
+                    with _stage("job"):
+                        T_b = T_b * scales_b[0]
+                with _stage("alloc"):
+                    fin_b = s_b + T_b
                     trial = _alloc(node_free, sel_b, kth_b[sel_b],
-                                   n_req[p_b, sel_b], fin_b)
+                                   _job_need(arrs, bj, p_b, sel_b), fin_b)
                 with _stage("earliest"):
-                    _, avail_h2 = _earliest(trial, n_req[p_h], arrival[hj],
-                                            placer, outage)
+                    _, avail_h2 = _earliest(trial, _job_need(arrs, hj, p_h),
+                                            arrival[hj], placer, outage)
                     ok = avail_h2[sel_h] <= r_h
                 with _stage("select"):
                     chosen = jnp.where(live & ok, ci, chosen)
@@ -810,9 +923,13 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
                 f = jnp.int32(0)                  # unrolled path is untier
                 factor = _fault_factor(fault_key, jj, fvec)
                 T_act = T_true[p, sel] * factor
+            scales = _job_scales(arrs, jj, p, sel)
+            if scales is not None:
+                with _stage("job"):
+                    T_act = T_act * scales[0]
             with _stage("alloc"):
                 start = avail[sel]
-                need = n_req[p, sel]
+                need = _job_need(arrs, jj, p, sel)
                 node_free = jnp.where(
                     placed,
                     _alloc(node_free, sel, kth[sel], need, start + T_act),
@@ -825,16 +942,20 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
             T_upd = T_true[p, sel] * factor
             E_act = (tt["E"][p, f, sel] if tiered
                      else E_true[p, sel]) * factor
+        scales = _job_scales(arrs, jj, p, sel)
+        if scales is not None:
+            with _stage("job"):
+                T_upd, E_act = T_upd * scales[0], E_act * scales[1]
         with _stage("alloc"):
             finish = start + T_act
 
         with _stage("learn"):
             n = runs[p, sel].astype(jnp.float32)
             C_tab = C_tab.at[p, sel].set(jnp.where(
-                placed, (C_tab[p, sel] * n + C_act) / (n + 1),
+                placed, _running_mean(C_tab[p, sel], n, C_act, scales),
                 C_tab[p, sel]))
             T_tab = T_tab.at[p, sel].set(jnp.where(
-                placed, (T_tab[p, sel] * n + T_upd) / (n + 1),
+                placed, _running_mean(T_tab[p, sel], n, T_upd, scales),
                 T_tab[p, sel]))
             runs = runs.at[p, sel].add(jnp.where(placed, 1, 0))
 
@@ -899,7 +1020,7 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
         T_act = scat(T_s, jnp.float32)
         backfilled = scat(bf_s, bool)
         tier = scat(f_s, jnp.int32)
-        nodes = n_req[prog, sel]                                 # [J]
+        nodes = _job_nodes(arrs, prog, sel)                      # [J]
         busy = jnp.zeros(S, jnp.float32).at[sel].add(T_act * nodes)
         makespan = fin.max()
         return {
@@ -1147,7 +1268,8 @@ def make_event_step(policy: Policy, placer: str | None = None,
             jjs = jnp.minimum(pend, J - 1)
             ps = prog[jjs]
             t0f = jnp.where(valid, t0s, BIG)
-            kths, avails = _earliest_shared(node_free, n_req[ps],
+            kths, avails = _earliest_shared(node_free,
+                                            _job_need(arrs, jjs, ps),
                                             t0f[:, None], placer, outage)
         with _stage("select"):
             keys = jax.vmap(lambda j: jax.random.fold_in(sel_key, j))(jjs)
@@ -1189,9 +1311,14 @@ def make_event_step(policy: Policy, placer: str | None = None,
                       else T_true[ps, sels]) * factors
             E_acts = (tt["E"][ps, fs, sels] if tiered
                       else E_true[ps, sels]) * factors
+        scales = _job_scales(arrs, jjs, ps, sels)
+        if scales is not None:
+            with _stage("job"):
+                T_acts = T_acts * scales[0]
+                E_acts = E_acts * scales[1]
 
         with _stage("alloc"):
-            needs = n_req[ps, sels]
+            needs = _job_need(arrs, jjs, ps, sels)
 
             # start rule: capped runs quantize to the current event
             # (exact power trace); uncapped keep the resource-earliest
@@ -1219,10 +1346,13 @@ def make_event_step(policy: Policy, placer: str | None = None,
         else:  # event-driven EASY: only the head's reservation is guarded
             with _stage("earliest"):
                 p_h, sel_h = ps[0], sels[0]
+                # (the head's job id is read only for a per-job width)
+                j_h = jjs[0] if "n_req_job" in arrs else None
                 r_h = starts_res[0]
                 kth_h2 = kth_free_time(
                     trials[:, sel_h, :],
-                    jnp.broadcast_to(n_req[p_h, sel_h], (Wc,)),
+                    jnp.broadcast_to(_job_need(arrs, j_h, p_h, sel_h),
+                                     (Wc,)),
                     force=placer or "sort")
                 avail_h2 = jnp.maximum(t0f[0], kth_h2)           # [Wc]
                 if outage is not None:
@@ -1236,6 +1366,10 @@ def make_event_step(policy: Policy, placer: str | None = None,
             p_now = jnp.sum(jnp.where(node_free > now, node_pow, idle_mat))
             w_jobs = (tt["w"][ps, fs, sels] if tiered
                       else w_pow[ps, sels])                      # [Wc]
+        if scales is not None:
+            with _stage("job"):
+                w_jobs = w_jobs * scales[2]
+        with _stage("select"):
             new_P = p_now - needs * idle_w[sels] + w_jobs        # [Wc]
             power_ok = ~capped | (new_P <= pc)
             elig0 = elig_res & power_ok
@@ -1287,12 +1421,16 @@ def make_event_step(policy: Policy, placer: str | None = None,
             fac_tot = accF_ci + factor
             C_upd = C_true[p, sel] * fac_tot
             T_upd = T_true[p, sel] * fac_tot
+        if scales is not None:
+            with _stage("job"):
+                T_upd = T_upd * scales[0][ci]
+        with _stage("learn"):
             n = runs[p, sel].astype(jnp.float32)
             C_tab = C_tab.at[p, sel].set(jnp.where(
-                final, (C_tab[p, sel] * n + C_upd) / (n + 1),
+                final, _running_mean(C_tab[p, sel], n, C_upd, scales),
                 C_tab[p, sel]))
             T_tab = T_tab.at[p, sel].set(jnp.where(
-                final, (T_tab[p, sel] * n + T_upd) / (n + 1),
+                final, _running_mean(T_tab[p, sel], n, T_upd, scales),
                 T_tab[p, sel]))
             runs = runs.at[p, sel].add(jnp.where(final, 1, 0))
 
@@ -1418,7 +1556,7 @@ def _event_results(arrs, totals_only, ys, carry):
     T_act = scat(T_s, jnp.float32)
     backfilled = scat(bf_s, bool)
     tier = scat(ys["tier"], jnp.int32)
-    nodes = n_req[prog, sel]                                     # [J]
+    nodes = _job_nodes(arrs, prog, sel)                          # [J]
     makespan = finish.max()
     return {
         "system": sel, "start": start, "finish": finish, "wait": wait,
@@ -1575,15 +1713,16 @@ def make_cons_step(policy: Policy, placer: str | None = None,
                      fail=False, tier=0)
         sys_col = jnp.arange(S)[:, None, None]                   # [S, 1, 1]
 
-        def earliest_fit(p, t0, Tdur, node_free, slots):
+        def earliest_fit(jp, p, t0, Tdur, node_free, slots):
             """Per-system earliest start where free capacity (really-free
-            node count minus reservation occupancy) covers ``n_req[p]``
-            nodes for the whole [t, t + Tdur) window.  Candidates: the
-            arrival floor, node free times, reservation finishes (the only
-            capacity rises); dips happen only at reservation starts, so each
-            candidate is checked against the [W] reservation table."""
+            node count minus reservation occupancy) covers job ``jp``'s
+            nodes (``_job_need``) for the whole [t, t + Tdur) window.
+            Candidates: the arrival floor, node free times, reservation
+            finishes (the only capacity rises); dips happen only at
+            reservation starts, so each candidate is checked against the
+            [W] reservation table."""
             with _stage("earliest"):
-                need = n_req[p]                                      # [S]
+                need = _job_need(arrs, jp, p)                        # [S]
                 r_valid = slots["pend"] < J                          # [Wc]
                 r_sel, r_sta = slots["sel"], slots["start"]
                 r_fin, r_need = slots["fin"], slots["need"]
@@ -1642,14 +1781,23 @@ def make_cons_step(policy: Policy, placer: str | None = None,
                 factor = slow * scale
             with _stage("select"):
                 key = jax.random.fold_in(sel_key, jp)
+            # this job's own runtime (and width) where the workload has
+            # per-job columns: reservations span the realised runtime, as
+            # they do for a fault-scaled one
+            ts = _job_t_scale(arrs, jp)
+            if ts is not None:
+                with _stage("job"):
+                    factor_t = factor * ts
+            else:
+                factor_t = factor
             if tiered:
                 # hole-aware earliest fit per tier: a slower tier's longer
                 # window may fit a different hole, so each tier gets its
                 # own piecewise-capacity evaluation
                 with _stage("fault"):
-                    Tdur_f = tt["T"][p] * factor                 # [F, S]
+                    Tdur_f = tt["T"][p] * factor_t               # [F, S]
                 avail_f = jax.vmap(
-                    lambda td: earliest_fit(p, t0, td, node_free, slots)
+                    lambda td: earliest_fit(jp, p, t0, td, node_free, slots)
                 )(Tdur_f)                                        # [F, S]
                 with _stage("select"):
                     c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
@@ -1668,8 +1816,8 @@ def make_cons_step(policy: Policy, placer: str | None = None,
                     wjob = tt["w"][p, f, sel]
             else:
                 with _stage("fault"):
-                    Tdur = T_true[p] * factor                        # [S]
-                avail_p = earliest_fit(p, t0, Tdur, node_free, slots)
+                    Tdur = T_true[p] * factor_t                      # [S]
+                avail_p = earliest_fit(jp, p, t0, Tdur, node_free, slots)
                 with _stage("select"):
                     sel = select(
                         policy, c_row=C_tab[p], t_row=T_tab[p],
@@ -1682,10 +1830,14 @@ def make_cons_step(policy: Policy, placer: str | None = None,
                     T_act = Tdur[sel]
                     E_res = E_true[p, sel] * factor
                     wjob = w_pow[p, sel]
+            scales = _job_scales(arrs, jp, p, sel)
+            if scales is not None:
+                with _stage("job"):
+                    E_res, wjob = E_res * scales[1], wjob * scales[2]
             with _stage("push"):
                 return dict(sel=sel.astype(jnp.int32), start=start,
                             fin=start + T_act, T=T_act,
-                            E=E_res, need=n_req[p, sel],
+                            E=E_res, need=_job_need(arrs, jp, p, sel),
                             wjob=wjob, fac=factor, fail=first_fail, tier=f)
 
         (node_free, node_pow, C_tab, T_tab, runs, acc, busy,
@@ -1801,12 +1953,17 @@ def make_cons_step(policy: Policy, placer: str | None = None,
             fac_tot = accF_ci + fac
             C_upd = C_true[p, sel] * fac_tot
             T_upd = T_true[p, sel] * fac_tot
+        scales = _job_scales(arrs, jj, p, sel)
+        if scales is not None:
+            with _stage("job"):
+                T_upd = T_upd * scales[0]
+        with _stage("learn"):
             n = runs[p, sel].astype(jnp.float32)
             C_tab = C_tab.at[p, sel].set(jnp.where(
-                final, (C_tab[p, sel] * n + C_upd) / (n + 1),
+                final, _running_mean(C_tab[p, sel], n, C_upd, scales),
                 C_tab[p, sel]))
             T_tab = T_tab.at[p, sel].set(jnp.where(
-                final, (T_tab[p, sel] * n + T_upd) / (n + 1),
+                final, _running_mean(T_tab[p, sel], n, T_upd, scales),
                 T_tab[p, sel]))
             runs = runs.at[p, sel].add(jnp.where(final, 1, 0))
 

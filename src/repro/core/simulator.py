@@ -144,13 +144,45 @@ class _PySim:
             return float(self.tt["w"][p, f, s])
         return float(self.w_pow[p, s])
 
-    def avail_for(self, p: int, arr: float, node_free=None) -> np.ndarray:
-        """Earliest start per system (float64 kth-free + outage push),
-        vectorized over systems: sort the free table, gather the kth free
-        time per system, then push through maintenance windows in order."""
+    # per-job twins (``engine._job_need`` / ``_job_scales``): a job of a
+    # workload with per-job columns runs ``t_scale[j]`` times its class's
+    # runtime on ``n_req_job[j, s]`` nodes, and draws energy and power in
+    # proportion to its width over its class's
+    def need(self, j, p, s=None):
+        nj = self.w.n_req_job
+        row = (np.asarray(self.w.n_req[p]) if nj is None
+               else np.asarray(nj[j]))
+        return np.asarray(row, np.int64) if s is None else int(row[s])
+
+    def scales(self, j, p, s):
+        """(runtime, energy, draw) multipliers of job j on system s."""
+        w = self.w
+        ts = 1.0 if w.t_scale is None else float(w.t_scale[j])
+        wr = (1.0 if w.n_req_job is None
+              else float(w.n_req_job[j, s]) / float(w.n_req[p, s]))
+        return ts, ts * wr, wr
+
+    def T_job(self, j, p, f, s):
+        return self.T_of(p, f, s) * self.scales(j, p, s)[0]
+
+    def E_job(self, j, p, f, s):
+        return self.E_of(p, f, s) * self.scales(j, p, s)[1]
+
+    def w_job(self, j, p, f, s):
+        return self.w_of(p, f, s) * self.scales(j, p, s)[2]
+
+    def T_learn(self, j, p, s):
+        """The (base-tier) runtime the learned table absorbs."""
+        return float(self.w.T_true[p, s]) * self.scales(j, p, s)[0]
+
+    def avail_for(self, j: int, arr: float, node_free=None) -> np.ndarray:
+        """Earliest start per system of job ``j`` (float64 kth-free +
+        outage push), vectorized over systems: sort the free table,
+        gather the kth free time per system, then push through
+        maintenance windows in order."""
         w = self.w
         nf = self.node_free if node_free is None else node_free
-        need = np.asarray(w.n_req[p], np.int64)                      # [S]
+        need = self.need(j, int(w.prog[j]))                          # [S]
         kidx = np.maximum(np.minimum(need, self.counts) - 1, 0)
         kth = np.sort(nf, axis=1)[np.arange(self.S), kidx]
         avail = np.where(need <= self.counts, np.maximum(arr, kth), BIG)
@@ -174,7 +206,7 @@ class _PySim:
         kj = float(w.k_job[j])
         k = self.scfg.k if np.isnan(kj) else kj
         if avail is None:
-            avail = self.avail_for(p, arr, node_free)
+            avail = self.avail_for(j, arr, node_free)
         if self.tt is None:
             rand_sel = None
             if self.pol.objective == "random":
@@ -221,14 +253,14 @@ class _PySim:
         return the per-job record."""
         w = self.w
         p, arr, avail, sel, f = self.choose(j)
-        T_act = self.T_of(p, f, sel)
-        E_act = self.E_of(p, f, sel)
+        T_act = self.T_job(j, p, f, sel)
+        E_act = self.E_job(j, p, f, sel)
         # learned tables absorb BASE (tier-0) observations
-        T_upd = float(w.T_true[p, sel])
+        T_upd = self.T_learn(j, p, sel)
         C_act = float(w.C_true[p, sel])
         start = float(avail[sel])
         finish = start + T_act
-        self.alloc(self.node_free, sel, int(w.n_req[p, sel]), finish)
+        self.alloc(self.node_free, sel, self.need(j, p, sel), finish)
         n = self.runs[p, sel]
         self.C_tab[p, sel] = (self.C_tab[p, sel] * n + C_act) / (n + 1)
         self.T_tab[p, sel] = (self.T_tab[p, sel] * n + T_upd) / (n + 1)
@@ -313,13 +345,13 @@ class _PySim:
         (``w.T_true[p, sel]`` — identical for untier policies)."""
         w = self.w
         finish = start + T_act
-        need = int(w.n_req[p, sel])
+        need = self.need(j, p, sel)
         idx = np.argsort(self.node_free[sel], kind="stable")[:need]
         self.node_free[sel, idx] = finish
         self.node_pow[sel, idx] = wjob / max(need, 1)
         n = self.runs[p, sel]
         C_act = float(w.C_true[p, sel])
-        T_upd = float(w.T_true[p, sel])
+        T_upd = self.T_learn(j, p, sel)
         self.C_tab[p, sel] = (self.C_tab[p, sel] * n + C_act) / (n + 1)
         self.T_tab[p, sel] = (self.T_tab[p, sel] * n + T_upd) / (n + 1)
         self.runs[p, sel] += 1
@@ -363,9 +395,9 @@ def _easy_order_py(sim: _PySim, J: int, window: int):
                 p_b, _, avail_b, sel_b, f_b = sim.choose(b)
                 s_b = float(avail_b[sel_b])
                 trial = sim.node_free.copy()
-                sim.alloc(trial, sel_b, int(w.n_req[p_b, sel_b]),
-                          s_b + sim.T_of(p_b, f_b, sel_b))
-                if sim.avail_for(p_h, arr_h, trial)[sel_h] <= r_h:
+                sim.alloc(trial, sel_b, sim.need(b, p_b, sel_b),
+                          s_b + sim.T_job(b, p_b, f_b, sel_b))
+                if sim.avail_for(h, arr_h, trial)[sel_h] <= r_h:
                     chosen = ci
                     break
         if chosen is not None:
@@ -408,8 +440,8 @@ def _events_py(sim: _PySim, pol):
             p_b, _, avail_b, sel_b, f_b = evals[ci]
             s_b = max(starts_res[ci], now) if capped else starts_res[ci]
             trial = sim.node_free.copy()
-            sim.alloc(trial, sel_b, int(w.n_req[p_b, sel_b]),
-                      s_b + sim.T_of(p_b, f_b, sel_b))
+            sim.alloc(trial, sel_b, sim.need(pend[ci], p_b, sel_b),
+                      s_b + sim.T_job(pend[ci], p_b, f_b, sel_b))
             return trial
 
         def guard_ok(ci):
@@ -419,7 +451,8 @@ def _events_py(sim: _PySim, pol):
                 return False
             trial = trial_of(ci)        # EASY: only the head is guarded
             p_h, arr_h, _, sel_h, _ = evals[0]
-            return sim.avail_for(p_h, arr_h, trial)[sel_h] <= starts_res[0]
+            return (sim.avail_for(pend[0], arr_h, trial)[sel_h]
+                    <= starts_res[0])
 
         blocked_recorded = False
         for ci in range(len(pend)):
@@ -429,8 +462,8 @@ def _events_py(sim: _PySim, pol):
             if sim.outage_gated(sel_b, max(starts_res[ci], now)):
                 continue
             new_P = (p_now
-                     - int(w.n_req[p_b, sel_b]) * sim.idle_pw[sel_b]
-                     + sim.w_of(p_b, f_b, sel_b))
+                     - sim.need(pend[ci], p_b, sel_b) * sim.idle_pw[sel_b]
+                     + sim.w_job(pend[ci], p_b, f_b, sel_b))
             if capped and new_P > sim.ev_cap:
                 if not blocked_recorded:
                     # the next would-be placement is power-blocked
@@ -455,8 +488,8 @@ def _events_py(sim: _PySim, pol):
         p, arr, avail, sel, f = evals[chosen]
         start = (max(starts_res[chosen], now) if capped
                  else starts_res[chosen])
-        sim.realize(j, chosen, p, sel, start, sim.T_of(p, f, sel),
-                    sim.E_of(p, f, sel), sim.w_of(p, f, sel), arr,
+        sim.realize(j, chosen, p, sel, start, sim.T_job(j, p, f, sel),
+                    sim.E_job(j, p, f, sel), sim.w_job(j, p, f, sel), arr,
                     p_now, tier=f)
     assert sim.placed_n == J, \
         f"event mirror stalled: {sim.placed_n}/{J} placed"
@@ -484,7 +517,7 @@ def _cons_py(sim: _PySim, pol, check_reservations: bool = False):
     pend: list[dict] = []
     max_iters = 16 * J + 64
 
-    def earliest_fit(p, t0, Trow=None):
+    def earliest_fit(j, p, t0, Trow=None):
         """Float64 twin of the engine's hole-aware earliest fit,
         vectorized over the candidate set: per system, the first
         candidate start whose capacity (free nodes minus reservation
@@ -499,8 +532,9 @@ def _cons_py(sim: _PySim, pol, check_reservations: bool = False):
         r_need = np.asarray([r["need"] for r in pend], np.float64)
         fin_c = np.maximum(r_fin, t0)       # candidates shared across S
         for s in range(S):
-            n = int(w.n_req[p, s])
-            Td = float(w.T_true[p, s] if Trow is None else Trow[s])
+            n = sim.need(j, p, s)
+            Td = (float(w.T_true[p, s]) * sim.scales(j, p, s)[0]
+                  if Trow is None else float(Trow[s]))
             free = sim.node_free[s, :int(sim.counts[s])]
             mine = r_sel == s
             rs, rf, rn = r_start[mine], r_fin[mine], r_need[mine]
@@ -539,21 +573,22 @@ def _cons_py(sim: _PySim, pol, check_reservations: bool = False):
         longer window may land in a different hole)."""
         pp = int(w.prog[j])
         if sim.tt is not None:
+            ts = sim.scales(j, pp, 0)[0]
             avail = np.stack([
-                earliest_fit(pp, t0, np.asarray(sim.tt["T"][pp, fi],
-                                                np.float64))
+                earliest_fit(j, pp, t0, np.asarray(sim.tt["T"][pp, fi],
+                                                   np.float64) * ts)
                 for fi in range(sim.F)])                         # [F, S]
             p, _, _, sel, f = sim.choose(j, arr=t0, avail=avail)
             start = float(avail[f, sel])
         else:
-            avail = earliest_fit(pp, t0)
+            avail = earliest_fit(j, pp, t0)
             p, _, _, sel, f = sim.choose(j, arr=t0, avail=avail)
             start = float(avail[sel])
-        T_act = sim.T_of(p, f, sel)
+        T_act = sim.T_job(j, p, f, sel)
         return dict(j=j, p=p, t0=t0, sel=sel, start=start, T=T_act,
-                    fin=start + T_act, E=sim.E_of(p, f, sel),
-                    need=int(w.n_req[p, sel]),
-                    wjob=sim.w_of(p, f, sel), tier=f)
+                    fin=start + T_act, E=sim.E_job(j, p, f, sel),
+                    need=sim.need(j, p, sel),
+                    wjob=sim.w_job(j, p, f, sel), tier=f)
 
     for _ in range(max_iters):
         if sim.placed_n == J:
@@ -571,7 +606,7 @@ def _cons_py(sim: _PySim, pol, check_reservations: bool = False):
         blocked_recorded = False
         elig_res = []
         for ci, rec in enumerate(pend):
-            avail_real = sim.avail_for(rec["p"], rec["t0"])[rec["sel"]]
+            avail_real = sim.avail_for(rec["j"], rec["t0"])[rec["sel"]]
             ok = rec["start"] <= now and avail_real <= now
             if ok:
                 # the engine's cap-deferred start gate: now must not sit
@@ -605,7 +640,7 @@ def _cons_py(sim: _PySim, pol, check_reservations: bool = False):
         j, p, sel = rec["j"], rec["p"], rec["sel"]
         start = max(rec["start"], now) if capped else rec["start"]
         if check_reservations and not capped:
-            avail_real = sim.avail_for(p, rec["t0"])[sel]
+            avail_real = sim.avail_for(j, rec["t0"])[sel]
             assert avail_real <= rec["start"] + 1e-6, (
                 f"reservation of job {j} not realizable: {avail_real} > "
                 f"{rec['start']} (a backfill delayed it)")
@@ -660,7 +695,10 @@ def simulate_py(w: Workload, scfg: SimConfig, *,
     sel, start, finish, wait, E, T_act, tier = map(np.array, zip(*out))
     makespan = finish.max()
     busy = np.zeros(sim.S)
-    np.add.at(busy, sel, T_act * np.asarray(w.n_req)[np.asarray(w.prog), sel])
+    nodes = (np.asarray(w.n_req)[np.asarray(w.prog), sel]
+             if w.n_req_job is None
+             else np.asarray(w.n_req_job)[np.arange(J), sel])
+    np.add.at(busy, sel, T_act * nodes)
     idle_energy = (float(np.sum(idle_w * np.asarray(w.n_nodes))) * makespan
                    - float(np.sum(idle_w * busy)))
     return {

@@ -77,6 +77,35 @@ JSCC_SYSTEMS = (KNL, BROADWELL, SKYLAKE, CASCADE_LAKE)
 JSCC_BY_NAME = {s.name: s for s in JSCC_SYSTEMS}
 
 
+# --- NERSC Cori (Cray XC40): a shared facility at real size ---------------
+# Node counts, cores and peaks as documented for Cori's two partitions:
+# Haswell, 2 x Xeon E5-2698 v3 (2 x 16 cores at 2.3 GHz, 16 DP flops a
+# cycle a core: 1.178 TF, 136 GB/s of DDR4); KNL, one Xeon Phi 7250 (68
+# cores at 1.4 GHz, 32 DP flops a cycle a core: 3.05 TF, MCDRAM as KNL's
+# above).  The power split and efficiencies are estimates: Haswell's from
+# its public TDP (2 x 135 W) as BROADWELL's are, KNL's those of ``KNL``
+# above (the same architecture, 7250 against 7290); the Aries injection
+# and file-system bandwidths are assumed.
+
+CORI_HASWELL = ComputeSystem(
+    name="CoriHaswell", n_nodes=2388, cores_per_node=32,
+    peak_flops_node=1.178e12, mem_bw_node=136e9,
+    net_bw_node=10e9, disk_bw_node=2e9,
+    idle_w=110.0, cpu_w=270.0, net_w=15.0, disk_w=12.0,
+    efficiency=0.14, scalar_eff=0.60,
+)
+
+CORI_KNL = ComputeSystem(
+    name="CoriKNL", n_nodes=9688, cores_per_node=68,
+    peak_flops_node=3.05e12, mem_bw_node=400e9,
+    net_bw_node=10e9, disk_bw_node=2e9,
+    idle_w=120.0, cpu_w=230.0, net_w=18.0, disk_w=12.0,
+    efficiency=0.16, scalar_eff=0.20,
+)
+
+CORI_SYSTEMS = (CORI_HASWELL, CORI_KNL)
+
+
 # --- heterogeneous TPU pod tiers (production half) ------------------------
 # One "node" = one chip; a pod tier exposes n_nodes chips to the scheduler.
 
@@ -107,4 +136,5 @@ TPU_V5P_POD = ComputeSystem(
 TPU_SYSTEMS = (TPU_V5E_POD, TPU_V4_POD, TPU_V5P_POD)
 TPU_BY_NAME = {s.name: s for s in TPU_SYSTEMS}
 
-ALL_SYSTEMS = {**JSCC_BY_NAME, **TPU_BY_NAME}
+ALL_SYSTEMS = {**JSCC_BY_NAME, **{s.name: s for s in CORI_SYSTEMS},
+               **TPU_BY_NAME}

@@ -235,7 +235,14 @@ def workload_from_arrays(submit, runtime, procs, systems,
     ``E_comp``) come from ``workload_model.predict_phases`` /
     ``predict_energy`` — so replayed jobs scale across systems with the
     same net/disk behaviour the NPB workloads carry, instead of pure
-    flops throughput."""
+    flops throughput.
+
+    Each job keeps its own runtime and width (``Workload.t_scale``,
+    ``n_req_job``): it runs ``runtime_j / T_true[class, ref]`` times its
+    class's runtime on every system (so exactly its trace runtime on the
+    reference system), on ``ceil(procs_j / cores_per_node)`` nodes of
+    each system (at least 1, at most the system).  The class tables stay
+    what the scheduler predicts and learns from."""
     submit = np.asarray(submit, np.float64)
     runt = np.asarray(runtime, np.float64)
     procs = np.asarray(procs, np.float64)
@@ -279,6 +286,9 @@ def workload_from_arrays(submit, runtime, procs, systems,
         C_true = E_true / mops
 
     J = len(submit)
+    n_req_job = np.minimum(np.maximum(np.ceil(procs[:, None] / cores[None, :]),
+                                      1.0), nn[None, :])          # [J, S]
+    t_scale = runt / np.asarray(T_true)[prog, ref]
     return Workload(
         prog=prog.astype(np.int32),
         arrival=submit.astype(np.float32),
@@ -291,6 +301,8 @@ def workload_from_arrays(submit, runtime, procs, systems,
         systems=tuple(s.name for s in systems),
         idle_w=np.asarray([s.idle_w for s in systems], np.float32),
         T_comp=T_comp, E_comp=E_comp,
+        t_scale=t_scale.astype(np.float32),
+        n_req_job=n_req_job.astype(np.int32),
     )
 
 

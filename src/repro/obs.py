@@ -29,7 +29,11 @@ Stages (``STAGES``):
 - ``account``: the Kahan totals, busy time and per-step outputs;
 - ``push``: admission into the pending buffer, its pop and re-queue;
 - ``advance``: the next event and the clock (event and conservative
-  cores).
+  cores);
+- ``job``: the reads of a workload's per-job columns (``t_scale``,
+  ``n_req_job``) and the products that turn a class's runtime, energy
+  and draw into the job's own; no operation where the workload has no
+  such columns.
 
 An instruction outside every stage but inside the scan's loop is
 ``"loop"`` (xs slicing, the trip counter, carry copies, the condition);
@@ -52,7 +56,7 @@ import numpy as np
 from repro.utils.hlo import op_stages
 
 STAGES = ("earliest", "select", "fault", "alloc", "learn", "account",
-          "push", "advance")
+          "push", "advance", "job")
 PREFIX = "step."
 
 _programs: tuple = ()      # ((fn, static kwargs, shapes), ...) of the last run

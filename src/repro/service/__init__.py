@@ -14,11 +14,11 @@ the live carry into a jitted rollout for operator queries;
 session or ``--pool N``).  See docs/SERVICE.md.
 """
 
-from repro.service.dispatcher import Dispatcher
+from repro.service.dispatcher import Dispatcher, own_columns
 from repro.service.metrics import ServiceMetrics
 from repro.service.pool import SessionPool
 from repro.service.whatif import whatif
 from repro.service.writer import AsyncWriter
 
 __all__ = ["AsyncWriter", "Dispatcher", "ServiceMetrics", "SessionPool",
-           "whatif"]
+           "own_columns", "whatif"]

@@ -7,7 +7,9 @@ session:
 
   submit(prog, arrival)   register a job (fills the next slot of the
                           capacity-padded job arrays; fixed shapes, so
-                          the jitted step never retraces);
+                          the jitted step never retraces; a session over
+                          a workload with per-job columns also takes the
+                          job's ``t_scale`` and ``nodes``);
   drive(until)            advance the clock through pushes / placements
                           / event hops, never past ``until`` (the step's
                           horizon gate) — returns the decisions emitted;
@@ -33,14 +35,27 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import (
-    BIG, FaultConfig, Scheduler, Workload, _fault_vec, _power_totals,
-    _workload_arrays, cons_carry0, event_carry0, event_context,
-    make_cons_step, make_event_step,
+    BIG, FaultConfig, Scheduler, Workload, _fault_vec, _job_nodes,
+    _power_totals, _workload_arrays, cons_carry0, event_carry0,
+    event_context, make_cons_step, make_event_step,
 )
 from repro.core.policy import Policy
 from repro.core.result import SimResult
 from repro.checkpoint.manager import CheckpointManager
 from repro.service.metrics import ServiceMetrics
+
+
+def own_columns(w: Workload, j: int) -> dict:
+    """Job ``j``'s per-job columns of ``w`` (``Workload.t_scale``,
+    ``n_req_job``) as ``Dispatcher.submit`` takes them: what a live replay
+    of a trace passes so that each job runs as it does in the batch run.
+    Empty for a workload without them."""
+    out = {}
+    if w.t_scale is not None:
+        out["t_scale"] = float(w.t_scale[j])
+    if w.n_req_job is not None:
+        out["nodes"] = w.n_req_job[j]
+    return out
 
 
 class Dispatcher:
@@ -49,7 +64,9 @@ class Dispatcher:
     ``w`` supplies the program x system tables (runtimes, energies, node
     counts, idle watts, outages); its job stream is only a catalog — the
     session's jobs are whatever ``submit`` registers, up to ``capacity``
-    (default: the catalog's length).
+    (default: the catalog's length).  Where ``w`` has per-job columns
+    (``t_scale``, ``n_req_job``), so does the session: each submission
+    then carries its job's own runtime scale and node counts.
 
     Construction: ``Dispatcher.from_scheduler(sched, w, ...)`` is the
     one path — a configured batch ``Scheduler`` IS the session spec
@@ -124,6 +141,14 @@ class Dispatcher:
         arrs["prog"] = jnp.zeros(C, jnp.int32)
         arrs["arrival"] = jnp.full(C, BIG, jnp.float32)
         arrs["k_job"] = jnp.full(C, jnp.nan, jnp.float32)
+        S = len(w.n_nodes)
+        if "t_scale" in arrs:
+            arrs["t_scale"] = jnp.ones(C, jnp.float32)
+        if "n_req_job" in arrs:
+            arrs["n_req_job"] = jnp.ones((C, S), jnp.int32)
+        #: the per-job channels a submission fills
+        self.job_keys = ("prog", "arrival", "k_job") + tuple(
+            k for k in ("t_scale", "n_req_job") if k in arrs)
         self._arrs = arrs
         self._n_out = (arrs["outage"][..., 1].size
                        if "outage" in arrs else 0)
@@ -193,20 +218,50 @@ class Dispatcher:
         if last is not None and t < last:
             raise ValueError("submissions must be arrival-ordered")
 
+    def job_row(self, prog: int, t: float, k: float | None = None,
+                t_scale: float | None = None, nodes=None) -> dict:
+        """One submission's values of ``job_keys``.  ``t_scale`` (its
+        runtime over its class's, default 1) and ``nodes`` (its node
+        count on each system, default its class's) are taken only by a
+        session over a workload with per-job columns."""
+        row = {"prog": int(prog), "arrival": t,
+               "k_job": np.nan if k is None else float(k)}
+        if "t_scale" in self.job_keys:
+            row["t_scale"] = 1.0 if t_scale is None else float(t_scale)
+            if not (np.isfinite(row["t_scale"]) and row["t_scale"] > 0):
+                raise ValueError(f"t_scale {t_scale!r}: finite and positive")
+        elif t_scale is not None:
+            raise ValueError("t_scale needs a session over a workload with "
+                             "per-job runtimes (Workload.t_scale)")
+        if "n_req_job" in self.job_keys:
+            n = np.asarray(self.w.n_req[int(prog)] if nodes is None
+                           else nodes, np.int64)
+            if n.shape != (len(self.w.n_nodes),) or (n < 1).any() or (
+                    n > np.asarray(self.w.n_nodes)).any():
+                raise ValueError(f"nodes {nodes!r}: one count in "
+                                 f"[1, n_nodes[s]] per system")
+            row["n_req_job"] = n
+        elif nodes is not None:
+            raise ValueError("nodes needs a session over a workload with "
+                             "per-job widths (Workload.n_req_job)")
+        return row
+
     def submit(self, prog: int, arrival: float | None = None,
-               k: float | None = None) -> int:
+               k: float | None = None, *, t_scale: float | None = None,
+               nodes=None) -> int:
         """Register a job: program index, submit time (default: the
-        current clock), optional per-job K override.  Returns the job id.
-        Submitting an arrival earlier than the clock is an error — the
-        past is already decided."""
+        current clock), optional per-job K override, and in a session
+        with per-job columns the job's ``t_scale`` and ``nodes``
+        (``job_row``).  Returns the job id.  Submitting an arrival
+        earlier than the clock is an error — the past is already
+        decided."""
         t = float(self.now if arrival is None else arrival)
         self._validate_intake(prog, t)
+        row = self.job_row(prog, t, k, t_scale, nodes)
         j = self.n_submitted
         a = self._arrs
-        a["prog"] = a["prog"].at[j].set(int(prog))
-        a["arrival"] = a["arrival"].at[j].set(t)
-        a["k_job"] = a["k_job"].at[j].set(
-            np.nan if k is None else float(k))
+        for key, v in row.items():
+            a[key] = a[key].at[j].set(v)
         self._ctx = event_context(a, self.policy, self.seed, self._fvec)
         self.n_submitted += 1
         self.metrics.observe_submit()
@@ -312,7 +367,8 @@ class Dispatcher:
             n_backfilled=carry.nbf,
             system=sel, start=jnp.asarray(self._s0[:n]), finish=finish,
             wait=wait, energy=E, runtime=T_act,
-            nodes=arrs["n_req"][prog, sel],
+            nodes=_job_nodes({k: v[:n] if k == "n_req_job" else v
+                              for k, v in arrs.items()}, prog, sel),
             backfilled=jnp.asarray(self._bf[:n]),
             tier=jnp.asarray(self._tier[:n]),
             axes=(), n_jobs=n, n_nodes=np.asarray(self.w.n_nodes),
@@ -327,8 +383,7 @@ class Dispatcher:
     def _tree(self):
         return {
             "carry": self._carry,
-            "jobs": {k: self._arrs[k]
-                     for k in ("prog", "arrival", "k_job")},
+            "jobs": {k: self._arrs[k] for k in self.job_keys},
             "perjob": {"E": self._E, "sys": self._sys, "s0": self._s0,
                        "fin": self._fin, "wait": self._wait, "T": self._T,
                        "bf": self._bf, "tier": self._tier},
@@ -359,7 +414,7 @@ class Dispatcher:
         if tree is None:
             return False
         self._carry = jax.tree.map(jnp.asarray, tree["carry"])
-        for k in ("prog", "arrival", "k_job"):
+        for k in self.job_keys:
             self._arrs[k] = jnp.asarray(tree["jobs"][k])
         self._ctx = event_context(self._arrs, self.policy, self.seed,
                                   self._fvec)

@@ -126,40 +126,36 @@ class SessionPool:
         how many jobs or sessions flush — then sync those members.
         Un-flushed lanes' arrays are untouched, so their steps stay
         no-ops."""
-        si, ji, progv, tv, kv, touched = [], [], [], [], [], []
+        si, ji, rows, touched = [], [], [], []
         for i in idxs:
             buf = self._buffers[i]
             if not buf:
                 continue
             touched.append(i)
             base = self.sessions[i].n_submitted
-            for off, (p, t, k) in enumerate(buf):
+            for off, row in enumerate(buf):
                 si.append(i)
                 ji.append(base + off)
-                progv.append(p)
-                tv.append(t)
-                kv.append(np.nan if k is None else float(k))
+                rows.append(row)
         if not touched:
             return 0
         si = np.asarray(si, np.int32)
         ji = np.asarray(ji, np.int32)
         arrs = self._ctx["arrs"]
-        prog = arrs["prog"].at[si, ji].set(np.asarray(progv, np.int32))
-        arrival = arrs["arrival"].at[si, ji].set(np.asarray(tv, np.float32))
-        k_job = arrs["k_job"].at[si, ji].set(np.asarray(kv, np.float32))
+        new = {key: arrs[key].at[si, ji].set(
+                   np.asarray([r[key] for r in rows], arrs[key].dtype))
+               for key in self.sessions[0].job_keys}
+        k_job = new["k_job"]
         # the stacked twin of event_context's kvec (same elementwise
         # where, so each lane matches its member's own rebuild bitwise)
         kvec = jnp.where(jnp.isnan(k_job),
                          jnp.asarray(self._pol.k, jnp.float32)[:, None],
                          k_job)
-        self._ctx = {**self._ctx, "kvec": kvec,
-                     "arrs": {**arrs, "prog": prog, "arrival": arrival,
-                              "k_job": k_job}}
+        self._ctx = {**self._ctx, "kvec": kvec, "arrs": {**arrs, **new}}
         for i in touched:
             d = self.sessions[i]
-            d._arrs["prog"] = prog[i]
-            d._arrs["arrival"] = arrival[i]
-            d._arrs["k_job"] = k_job[i]
+            for key, v in new.items():
+                d._arrs[key] = v[i]
             d._ctx = event_context(d._arrs, d.policy, d.seed, d._fvec)
             d.n_submitted += len(self._buffers[i])
             self._buffers[i].clear()
@@ -167,18 +163,21 @@ class SessionPool:
 
     # ------------------------------------------------------------ intake
     def submit(self, session: int, prog: int, arrival: float | None = None,
-               k: float | None = None) -> int:
+               k: float | None = None, *, t_scale: float | None = None,
+               nodes=None) -> int:
         """Buffer one submission for ``session`` (validated now, flushed
-        in one scatter at that session's next drive/read).  Returns the
-        job id — assigned immediately, intake never waits on the pool."""
+        in one scatter at that session's next drive/read; ``t_scale`` and
+        ``nodes`` as ``Dispatcher.submit``).  Returns the job id —
+        assigned immediately, intake never waits on the pool."""
         i = int(session)
         d = self.sessions[i]
         buf = self._buffers[i]
         t = float(d.now if arrival is None else arrival)
-        last = float(buf[-1][1]) if buf else None
+        last = float(buf[-1]["arrival"]) if buf else None
         d._validate_intake(prog, t, queued=len(buf), last=last)
+        row = d.job_row(prog, t, k, t_scale, nodes)
         j = d.n_submitted + len(buf)
-        buf.append((int(prog), t, k))
+        buf.append(row)
         d.metrics.observe_submit()
         return j
 
@@ -264,12 +263,13 @@ class SessionPool:
         return self.sessions[i].result()
 
     def whatif(self, session: int, prog: int, arrival: float | None = None,
-               k: float | None = None) -> dict:
+               k: float | None = None, **job) -> dict:
         """Project a hypothetical submission into one session — served
-        from that member's cached jitted fork, the pool never stalls."""
+        from that member's cached jitted fork, the pool never stalls
+        (``job``: ``t_scale``/``nodes`` as ``Dispatcher.submit``)."""
         i = int(session)
         self._flush([i])
-        return _whatif(self.sessions[i], prog, arrival, k)
+        return _whatif(self.sessions[i], prog, arrival, k, **job)
 
     @property
     def mean_step_us(self) -> float:

@@ -45,13 +45,15 @@ def _rollout_fn(disp):
 
 
 def whatif(disp, prog: int, arrival: float | None = None,
-           k: float | None = None) -> dict:
+           k: float | None = None, *, t_scale: float | None = None,
+           nodes=None) -> dict:
     """Project submitting ``prog`` at ``arrival`` (default: now) into the
     live session, without mutating it.  Returns the hypothetical job's
     projected placement (system, start, wait, finish), the session-level
     projections (mean wait over all submitted + hypothetical jobs,
     makespan, peak power), and the cap headroom at the projected peak
-    (``inf`` when uncapped)."""
+    (``inf`` when uncapped).  ``t_scale``/``nodes``: the job's own
+    runtime scale and node counts, as ``Dispatcher.submit`` takes them."""
     if disp.n_submitted >= disp.capacity:
         raise RuntimeError("session full: no free slot for a what-if job")
     if not 0 <= int(prog) < disp.w.T_true.shape[0]:
@@ -62,10 +64,8 @@ def whatif(disp, prog: int, arrival: float | None = None,
 
     j = disp.n_submitted
     arrs = dict(disp._arrs)
-    arrs["prog"] = arrs["prog"].at[j].set(int(prog))
-    arrs["arrival"] = arrs["arrival"].at[j].set(t)
-    arrs["k_job"] = arrs["k_job"].at[j].set(
-        np.nan if k is None else float(k))
+    for key, v in disp.job_row(prog, t, k, t_scale, nodes).items():
+        arrs[key] = arrs[key].at[j].set(v)
     ctx = event_context(arrs, disp.policy, disp.seed, disp._fvec)
 
     carry_f, ys = _rollout_fn(disp)(ctx, disp._carry)

@@ -162,14 +162,18 @@ def op_stages(hlo_text: str, prefix: str = "step.") -> dict[str, str]:
     ``conditional`` branches and ``call`` (not the insides of fusions or
     reducers).  An instruction's stage is the innermost ``<prefix><stage>``
     component of its ``op_name`` (a fusion's is its root's).  Failing
-    that, a source path gives ``loop`` where it lies under ``while`` or
-    the instruction runs in a loop (the scan's own slicing, trip count
-    and condition, work hoisted into the loop) and ``outside``
-    elsewhere.  An instruction the compiler made without a source path
-    (a layout copy, a rewritten reduction) takes the stage of its users
-    where they all have the same one, repeated back along chains of such
-    instructions; what is left is ``loop`` inside a loop's computations
-    (carry copies, the loop's tuples) and ``outside`` elsewhere."""
+    that, a source path gives ``loop`` where it lies under ``while`` (the
+    scan's own slicing, trip count and condition) and ``outside`` where
+    the instruction runs in no loop.  A path outside ``while`` on an
+    instruction that runs in a loop was not traced where it runs (under
+    ``shard_map``, a cached lowering's ``.../shard_map/reduce_window_sum``,
+    or ``.../shard_map/reduce-window.7``, which the partitioner names
+    after the instruction) and counts as none.  An instruction without a
+    source path (a layout copy, a rewritten reduction) takes the stage of
+    its users where they all have the same one, repeated back along
+    chains of such instructions; what is left is ``loop`` inside a loop's
+    computations (carry copies, the loop's tuples, work hoisted into the
+    loop) and ``outside`` elsewhere."""
     comps, entry = _computations(hlo_text)
     in_loop, todo = {}, ([(entry, False)] if entry in comps else [])
     while todo:
@@ -194,8 +198,10 @@ def op_stages(hlo_text: str, prefix: str = "step.") -> dict[str, str]:
             if found:
                 label[name] = found[-1]
             elif "/" in op_name:
-                in_loop_path = looped or _IN_LOOP_RE.search(op_name)
-                label[name] = "loop" if in_loop_path else "outside"
+                if _IN_LOOP_RE.search(op_name):
+                    label[name] = "loop"
+                elif not looped:
+                    label[name] = "outside"
         users = {n: [] for n in names}
         for n in names:
             for r in set(refs[n]):

@@ -6,7 +6,9 @@ Each core's step runs its stages under ``jax.named_scope("step.<stage>")``;
 facility's shape: every top-level instruction inside the scan's loop
 lands in one of the core's stages or in ``loop``, each stage owns some
 instruction, the kth-free placement lies in ``earliest``, the run keeps
-shapes and not arrays, and the table is built only when asked for.
+shapes and not arrays, and the table is built only when asked for.  The
+workload is a trace replay, so its jobs carry per-job columns and every
+core also runs the ``job`` stage.
 """
 
 import re
@@ -21,6 +23,8 @@ from repro.data.scenarios import synthetic_swf_arrays, workload_from_arrays
 from repro.utils.hlo import _callees, _computations, op_stages
 
 SIX = ("earliest", "select", "fault", "alloc", "learn", "account")
+#: the per-job columns' reads and products (a trace replay's own jobs)
+JOB = ("job",)
 FAULTS = (FaultConfig(),
           FaultConfig(straggler_prob=0.05, straggler_factor=2.0))
 INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
@@ -104,20 +108,20 @@ def _check_core(stages, kth_needle="kth_free"):
 @pytest.mark.parametrize("placer", ["pallas_interpret", "jnp"])
 def test_arrival_core_stages(w, placer):
     _sched(placer=placer).run(w, totals_only=True)
-    _check_core(SIX)
+    _check_core(SIX + JOB)
 
 
 def test_easy_core_stages(w):
     _sched(placer="jnp", queue="easy_backfill:window=4").run(
         w, totals_only=True)
-    _check_core(SIX + ("push",))
+    _check_core(SIX + ("push",) + JOB)
 
 
 @pytest.mark.parametrize("spec", [dict(engine="events"),
                                   dict(queue="conservative:window=4")])
 def test_event_cores_stages(w, spec):
     _sched(placer="jnp", **spec).run(w, totals_only=True)
-    _check_core(SIX + ("push", "advance"))
+    _check_core(SIX + ("push", "advance") + JOB)
 
 
 def test_chunked_run_records_its_three_programs(w):
@@ -127,7 +131,7 @@ def test_chunked_run_records_its_three_programs(w):
     assert names == ["_chunk_init", "_chunk_advance", "_chunk_advance",
                      "_chunk_finish"]
     assert [kw["nsteps"] for fn, kw, _ in obs.programs()[1:3]] == [16, 8]
-    _check_core(SIX)
+    _check_core(SIX + JOB)
 
 
 def test_run_keeps_shapes_and_the_table_is_memoised(w, monkeypatch):

@@ -25,7 +25,7 @@ import jax
 
 from repro.core import (JSCC_SYSTEMS, Scheduler, make_npb_workload,
                         make_policy)
-from repro.service import Dispatcher, ServiceMetrics, whatif
+from repro.service import Dispatcher, ServiceMetrics, own_columns, whatif
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "benchmarks"))
@@ -48,7 +48,8 @@ def replay(w, disp):
     arrival, then drain."""
     for j in range(len(w.prog)):
         disp.drive(until=float(w.arrival[j]))
-        disp.submit(int(w.prog[j]), float(w.arrival[j]))
+        disp.submit(int(w.prog[j]), float(w.arrival[j]),
+                    **own_columns(w, j))
     disp.drain()
     return disp
 

@@ -17,6 +17,8 @@ process may load the TPU library, and every test worker imports this
 file.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -142,11 +144,14 @@ def test_kth_free_batched_under_vmap_scan(one_chip):
     assert "tpu_custom_call" in hlo
 
 
-def _campaign_inputs(J=512):
+def _campaign_inputs(J=512, per_job=True):
     """Flat-batch inputs of a 12-lane (6 K x 2 seeds) paper campaign on
-    the JSCC systems, as ``Scheduler.run`` builds them."""
+    the JSCC systems, as ``Scheduler.run`` builds them (``per_job``
+    False: each job runs as its class)."""
     w = workload_from_arrays(*synthetic_swf_arrays(J, seed=11),
                              JSCC_SYSTEMS)
+    if not per_job:
+        w = dataclasses.replace(w, t_scale=None, n_req_job=None)
     pol = make_policy("paper").with_params(
         k=np.asarray([0.0, 0.05, 0.1, 0.2, 0.5, 0.85], np.float32))
     g = Scheduler(pol, warm_start=True, seeds=[0, 1],
@@ -173,20 +178,45 @@ def test_arrival_core_campaign_step(one_chip):
                                      block_bytes=12 * S * MAXN * 4),)
 
 
-def test_arrival_core_in_grid_shard_map(topo):
-    """The same campaign partitioned over the four chips' ``("grid",)``
-    mesh: the kernel compiles inside the shard_map body."""
+def _grid_shard_map_text(topo, args, common):
     mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("grid",),
                              axis_types=(AxisType.Auto,))
-    args, common = _campaign_inputs()
     arrs, pol, seeds, faults = args
 
     def put(spec):
         return lambda x: _sds(x.shape, x.dtype, NamedSharding(mesh, spec))
     sds = (jax.tree.map(put(replicated), arrs),
            *(jax.tree.map(put(grid_spec), a) for a in (pol, seeds, faults)))
-    hlo = _sharded_run.lower(*sds, mesh=mesh, **common).compile().as_text()
+    return _sharded_run.lower(*sds, mesh=mesh, **common).compile().as_text()
+
+
+def test_arrival_core_in_grid_shard_map(topo):
+    """The same campaign partitioned over the four chips' ``("grid",)``
+    mesh: the kernel compiles inside the shard_map body."""
+    hlo = _grid_shard_map_text(topo, *_campaign_inputs())
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("per_job", [False, True])
+def test_grid_shard_map_keeps_the_step_stages(topo, one_chip, per_job):
+    """Under ``shard_map`` the cumsum of ``_alloc_mask`` lowers to
+    instructions whose paths lost the step's scopes (the partitioner
+    names them after themselves, ``.../shard_map/reduce-window.7``): the
+    stage table still gives them ``alloc``, as on one chip, so that a
+    sharded campaign's stages read as a one-chip campaign's."""
+    args, common = _campaign_inputs(per_job=per_job)
+    sds = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), args)
+    texts = {"one chip": _batched_run.lower(*sds, **common).compile()
+             .as_text(),
+             "grid": _grid_shard_map_text(topo, args, common)}
+    for where, hlo in texts.items():
+        stages = op_stages(hlo)
+        windows = {n: st for n, st in stages.items()
+                   if n.startswith("reduce-window")}
+        assert windows and set(windows.values()) == {"alloc"}, (where,
+                                                                 windows)
+    one, grid = (set(op_stages(t).values()) for t in texts.values())
+    assert one == grid
 
 
 def test_chunk_advance_step(one_chip):
@@ -217,3 +247,27 @@ def test_session_pool_event_step(one_chip):
     finally:
         pool.close()
     assert "tpu_custom_call" in hlo
+
+
+def test_cori_width_campaign_step(one_chip):
+    """The arrival-core campaign at NERSC Cori's width, `[S, maxN] =
+    [2, 9688]`, with per-job runtimes and widths (a trace replay's own
+    jobs): the folded kernel still walks all 12 lanes in one block of
+    930 KB, and the per-job reads compile under ``step.job``."""
+    from repro.core import CORI_SYSTEMS
+    rng = np.random.default_rng(5)
+    J = 512
+    cores = rng.integers(1, 76_416, J).astype(np.float64)
+    w = workload_from_arrays(np.sort(rng.uniform(0, 1e5, J)),
+                             np.exp(rng.uniform(1.0, 11.0, J)), cores,
+                             CORI_SYSTEMS)
+    pol = make_policy("paper").with_params(
+        k=np.asarray([0.0, 0.05, 0.1, 0.2, 0.5, 0.85], np.float32))
+    g = Scheduler(pol, warm_start=True, seeds=[0, 1],
+                  placer="pallas")._grid(w, False)
+    sds = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                       g["args"])
+    hlo, folds = _folds(_batched_run, *sds, **g["common"])
+    assert folds == (obs.KthFreeCall(lanes=12, grid_steps=1,
+                                     block_bytes=12 * 2 * 9688 * 4),)
+    assert "job" in set(op_stages(hlo).values())
