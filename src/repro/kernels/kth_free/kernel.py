@@ -4,10 +4,12 @@ The scheduler's inner loop asks, for every system s, for the time at which
 n_req[s] nodes are simultaneously free: the n_req[s]-th smallest entry of
 the node-free row.  A full ``jnp.sort`` per simulation step is
 O(S·maxN·log maxN) and serializes badly; instead we radix-select the kth
-smallest directly: map f32 free-times to order-preserving uint32 keys and
-walk the 32 bits MSB->LSB, at each bit counting candidates whose bit is 0
-and descending into the half that contains rank k.  32 counting passes over
-the [S, maxN] tile — O(S·maxN) work, fully vectorized over both axes (VPU
+smallest directly: map f32 free-times to order-preserving int32 keys and
+build the answer's 32 bits MSB->LSB, a threshold walk: at each bit, count
+the keys below the decided prefix with that bit set, and keep the bit
+while fewer than k are below.  32 counting passes over the [S, maxN]
+tile, each a compare and a sum per node with no per-node state carried
+between passes — O(S·maxN) work, fully vectorized over both axes (VPU
 lanes hold nodes, sublanes hold systems), and bit-exact against the sort
 reference because the selected value is an element of the input, not an
 approximation.
@@ -18,11 +20,13 @@ is a few KB).  Under ``vmap`` (the campaign's lanes, each device's lanes
 inside the grid ``shard_map``, a vmap nested in another) its
 ``custom_vmap`` rule folds every lane into one invocation of the
 lane-folded kernel, an [L, S, maxN] block walked once for all L·S rows.
-The 32 passes are bound by the latency of each pass's cross-lane count,
-not by its width, so L lanes in one walk cost about what one lane does,
-where a grid step per lane (Pallas's own batching rule) would run L walks
-one after another.  Where the lanes outgrow ``LANE_BLOCK_BYTES`` the
-kernel grids over groups of as many lanes as fit.
+At the JSCC facility's width the 32 passes are bound by the latency of
+each pass's cross-lane count, not by its width, so L lanes in one walk
+cost about what one lane does, where a grid step per lane (Pallas's own
+batching rule) would run L walks one after another; at ten thousand
+nodes a row each pass is bound by its per-node work.  Where the lanes
+outgrow ``LANE_BLOCK_BYTES`` the kernel grids over groups of as many
+lanes as fit.
 """
 
 from __future__ import annotations
@@ -38,26 +42,29 @@ from jax.interpreters import mlir
 
 from repro import obs
 
-#: node-free bytes of one lane-folded block.  The walk keeps about nine
-#: block-sized arrays in VMEM (the input's two buffers, the keys, the
-#: active mask, a bit plane and their products): a 2 MB block of 12
-#: lanes of [4, 10240] asked v5e's compiler for 18.7 MB of its 16 MiB of
-#: scoped VMEM, so a block stays at 1 MiB.
+#: node-free bytes of one lane-folded block.  The walk keeps about five
+#: block-sized arrays in VMEM (the input's two buffers, the keys and a
+#: pass's compare plane): a 3.9 MB block of 24 lanes of [4, 10240] asks
+#: v5e's compiler for 18.9 MB of its 16 MiB of scoped VMEM, so a block
+#: stays at 1 MiB, with room to spare.
 LANE_BLOCK_BYTES = 1 << 20
 
 
-def _f32_to_ordered_u32(x):
-    """Order-preserving bijection f32 -> uint32 (IEEE-754 trick: flip sign
-    bit for positives, flip all bits for negatives)."""
-    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    sign = (b >> 31).astype(jnp.bool_)
-    return jnp.where(sign, ~b, b | jnp.uint32(0x80000000))
+def _flip_negatives(b):
+    """int32 bits -> the same bits with a negative's magnitude bits flipped.
+    Its own inverse: the sign bit, which decides the flip, is kept."""
+    return b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
 
 
-def _ordered_u32_to_f32(u):
-    sign = (u >> 31).astype(jnp.bool_)
-    b = jnp.where(sign, u & jnp.uint32(0x7FFFFFFF), ~u)
-    return jax.lax.bitcast_convert_type(b, jnp.float32)
+def _f32_to_ordered_i32(x):
+    """Order-preserving bijection f32 -> int32: the signed integer order of
+    the keys is the float order (-0.0 below +0.0, a NaN beyond the
+    infinity of its sign)."""
+    return _flip_negatives(jax.lax.bitcast_convert_type(x, jnp.int32))
+
+
+def _ordered_i32_to_f32(key):
+    return jax.lax.bitcast_convert_type(_flip_negatives(key), jnp.float32)
 
 
 def radix_select_kth(node_free, n_req):
@@ -65,30 +72,31 @@ def radix_select_kth(node_free, n_req):
     and inside scan/vmap).  node_free: [..., S, maxN] f32; n_req: [..., S]
     int, or the column [..., S, 1] (the lane-folded kernel's form, which
     keeps every count in the layout of its row).  Returns n_req's shape in
-    f32: the n_req-th smallest per row (1-indexed, clipped)."""
+    f32: the n_req-th smallest per row (1-indexed, clipped).
+
+    A threshold walk: the k-th smallest key is the largest v with fewer
+    than k keys below it, built bit by bit from the most significant.
+    ``val`` is the decided prefix with the bits below it zero, read as an
+    unsigned number and held in signed form (so ``INT_MIN`` is the empty
+    prefix and setting a bit is an XOR; the top bit clears the sign).
+    Each pass tries the next bit, ``t = val ^ bit``, counts the keys below
+    t and keeps t where that count stays under k.  Only the prefix is
+    carried through the 32 passes; nothing is stored per node.  The result
+    is the k-th smallest input element itself, so it is bit-exact."""
     N = node_free.shape[-1]
     col = n_req.ndim == node_free.ndim
-    u = _f32_to_ordered_u32(node_free)                      # [..., S, N]
-    k0 = jnp.clip(n_req, 1, N).astype(jnp.int32)            # [..., S(, 1)]
+    key = _f32_to_ordered_i32(node_free)                    # [..., S, N]
+    k = jnp.clip(n_req, 1, N).astype(jnp.int32)             # [..., S(, 1)]
 
-    def bit_step(i, carry):
-        active, k, val = carry
-        shift = jnp.uint32(31) - i.astype(jnp.uint32)
-        bit = ((u >> shift) & jnp.uint32(1)).astype(jnp.int32)   # [.., N]
-        zeros = jnp.sum(active * (1 - bit), axis=-1, keepdims=col)
-        go_one = k > zeros                                       # as k
-        val = val | jnp.where(go_one, jnp.uint32(1) << shift, jnp.uint32(0))
-        keep_bit = go_one.astype(jnp.int32)
-        if not col:
-            keep_bit = keep_bit[..., None]                       # [.., 1]
-        active = active * (bit == keep_bit).astype(jnp.int32)
-        k = jnp.where(go_one, k - zeros, k)
-        return active, k, val
+    def bit_step(i, val):
+        t = val ^ (jnp.int32(1) << (31 - i))               # as k
+        below = key < (t if col else t[..., None])           # [..., S, N]
+        c = jnp.sum(below.astype(jnp.int32), axis=-1, keepdims=col)
+        return jnp.where(c < k, t, val)
 
-    active0 = jnp.ones(node_free.shape, jnp.int32)
-    val0 = jnp.zeros(k0.shape, jnp.uint32)
-    _, _, val = jax.lax.fori_loop(0, 32, bit_step, (active0, k0, val0))
-    return _ordered_u32_to_f32(val)
+    val0 = jnp.full(k.shape, jnp.iinfo(jnp.int32).min, jnp.int32)
+    val = jax.lax.fori_loop(0, 32, bit_step, val0)
+    return _ordered_i32_to_f32(val)
 
 
 def radix_select_kth_batched(node_free, n_req):

@@ -1,6 +1,8 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle
 (deliverable c: every kernel sweeps shapes/dtypes against ref.py)."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -115,6 +117,7 @@ from repro.kernels.kth_free.kernel import LANE_BLOCK_BYTES  # noqa: E402
     (2, 8, 1),
     (7, 200, 2),
     (3, 129, 3),      # non-multiple-of-lane width
+    (2, 9688, 4),     # NERSC Cori's two partitions
 ])
 def test_kth_free_sweep(s, n, seed):
     rng = np.random.default_rng(seed)
@@ -359,6 +362,99 @@ def test_kth_free_calls_record_folded_lowerings_only():
     assert obs.kth_free_calls()[seen + 1:] == (obs.KthFreeCall(
         lanes=13, grid_steps=-(-13 // lanes),
         block_bytes=lanes * 4 * 10240 * 4),)
+
+
+# ------------------------------------------------ kth free, edge values
+
+def _active_mask_walk(node_free, n_req):
+    """A second, independent radix walk that compares bits where the sort
+    oracle compares values: ordered uint32 keys, and at each bit from the
+    most significant a count of the zeros among a row's active nodes, a
+    descent into the half that holds rank k and a per-node active mask
+    narrowed to it.  node_free [..., S, N] f32, n_req [..., S] int."""
+    N = node_free.shape[-1]
+    b = jax.lax.bitcast_convert_type(node_free, jnp.uint32)
+    neg = (b >> 31).astype(jnp.bool_)
+    u = jnp.where(neg, ~b, b | jnp.uint32(0x80000000))
+
+    def bit_step(i, carry):
+        active, k, val = carry
+        shift = jnp.uint32(31) - i.astype(jnp.uint32)
+        bit = ((u >> shift) & jnp.uint32(1)).astype(jnp.int32)
+        zeros = jnp.sum(active * (1 - bit), axis=-1)
+        go_one = k > zeros
+        val = val | jnp.where(go_one, jnp.uint32(1) << shift, jnp.uint32(0))
+        active = active * (bit == go_one[..., None]).astype(jnp.int32)
+        return active, jnp.where(go_one, k - zeros, k), val
+
+    k0 = jnp.clip(n_req, 1, N).astype(jnp.int32)
+    _, _, val = jax.lax.fori_loop(
+        0, 32, bit_step, (jnp.ones(node_free.shape, jnp.int32), k0,
+                          jnp.zeros(k0.shape, jnp.uint32)))
+    neg = (val >> 31).astype(jnp.bool_)
+    b = jnp.where(neg, val & jnp.uint32(0x7FFFFFFF), ~val)
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
+
+
+def _edge_rows(kind, shape, seed):
+    """[L, S, N] node-free tables of one kind of awkward value, and [L, S]
+    requests that take 0, 1, N and N + 5 (clipped) besides values inside
+    [1, N]."""
+    rng = np.random.default_rng(seed)
+    L, S, N = shape
+    tiny = np.finfo(np.float32).tiny
+    if kind == "ties":                       # few distinct values
+        free = rng.integers(0, 5, shape).astype(np.float32) * 3600.0
+    elif kind == "signed_zeros":
+        free = rng.choice(np.float32([-0.0, 0.0, 1.0, -1.0]), shape)
+    elif kind == "negatives":
+        free = rng.uniform(-1e6, 1e5, shape).astype(np.float32)
+    elif kind == "infinities":
+        free = rng.choice(np.float32([-np.inf, np.inf, -1e30, 1e30, 0.0,
+                                      7.5]), shape)
+    elif kind == "denormals":
+        free = rng.choice(np.float32([-0.0, 0.0, 1e-45, -1e-45, 1e-40,
+                                      -1e-40, tiny, -tiny]), shape)
+    elif kind == "all_equal":
+        free = np.broadcast_to(rng.choice(np.float32(
+            [-0.0, 0.0, 86400.0, np.inf, -2.5]), (L, S, 1)), shape).copy()
+    else:                                    # "last_bit": ulp neighbours
+        bits = np.float32(12345.678).view(np.int32) + rng.integers(
+            -3, 4, shape)
+        free = bits.astype(np.int32).view(np.float32)
+        free[:, -1] = -free[:, -1]
+    nreq = rng.integers(1, N + 1, (L, S))
+    nreq = np.where(rng.random((L, S)) < 0.5,
+                    rng.choice([0, 1, N, N + 5], (L, S)), nreq)
+    nreq.reshape(-1)[:4] = [0, 1, N, N + 5]
+    return jnp.asarray(free), jnp.asarray(nreq.astype(np.int32))
+
+
+def _flushed(x):
+    """Values with denormals as zeros: jnp.sort on XLA:CPU orders them so."""
+    x = np.asarray(x)
+    return np.where(np.abs(x) < np.finfo(np.float32).tiny, 0.0, x)
+
+
+_EDGE_KINDS = ("ties", "signed_zeros", "negatives", "infinities",
+               "denormals", "all_equal", "last_bit")
+
+
+@pytest.mark.parametrize("kind", _EDGE_KINDS)
+@pytest.mark.parametrize("force", ["pallas_interpret", "jnp"])
+@pytest.mark.parametrize("shape", [(12, 4, 136), (12, 2, 9688)],
+                         ids=["jscc4", "cori2"])
+def test_kth_free_walk_edge_values_bit_exact(shape, force, kind):
+    """The campaign's folded shapes under vmap, rows of awkward values:
+    equal to the sort oracle by value and to the active-mask walk bit for
+    bit (so -0.0 and +0.0, and denormals, are told apart)."""
+    free, nreq = _edge_rows(kind, shape, seed=10 * shape[-1] + _EDGE_KINDS.index(kind))
+    out = jax.jit(jax.vmap(partial(kth_free_time, force=force)))(free, nreq)
+    out = np.asarray(out)
+    ref = jax.vmap(kth_free_ref)(free, nreq)
+    np.testing.assert_array_equal(_flushed(ref), _flushed(out))
+    want = np.asarray(_active_mask_walk(free, nreq))
+    np.testing.assert_array_equal(want.view(np.int32), out.view(np.int32))
 
 
 # ---------------------------------------------------------------- SSD scan

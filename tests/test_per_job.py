@@ -275,17 +275,18 @@ def test_trace_replay_keeps_each_jobs_runtime_and_width(calibrate):
 
 #: sha256 prefixes of each program's StableHLO text (no source
 #: locations) and of its stage table, as the engine lowered them before
-#: per-job columns existed (CPU, ``placer="jnp"`` unless named).  A
-#: workload without per-job columns must lower to exactly these.
+#: per-job columns existed, with today's kth-free walk (CPU,
+#: ``placer="jnp"`` unless named).  A workload without per-job columns
+#: must lower to exactly these.
 PROGRAMS = {
-    "arrival": ("01053aea5d9dd784", "b0e738d66acbeee5"),
-    "arrival_pallas": ("d38ef32dd07f7b3b", "2a179914c6aa15a6"),
-    "easy": ("1544fd0377c3b581", "996ad8e390b73972"),
-    "easy_unrolled": ("906bdaca8471f2ae", "2642540afd641676"),
-    "events": ("ea14d64f31b9cad0", "7c966ac838f1282a"),
-    "cons": ("d5aa9c244c8667b9", "c5a40515cc299cdd"),
-    "chunk": ("6a817c20e46e5ea8", "2e835e815ef160a2"),
-    "full": ("4a3df2588f7bd1bb", "9ae972bfc30c66ae"),
+    "arrival": ("b7ecccb64ee9e38b", "936ebd643483a13b"),
+    "arrival_pallas": ("069ef7ea7a266118", "e74609028bfaca64"),
+    "easy": ("c1e112851129743a", "83a7ba0304366fb9"),
+    "easy_unrolled": ("758b59f5cd03c509", "a84b792efefa9af5"),
+    "events": ("59bed6a1e1e8c8ea", "31a598e871275057"),
+    "cons": ("832ddfbff5b0487b", "7a29f567105c816f"),
+    "chunk": ("a83dfd6375c5d3f5", "935045f5d3dfcee0"),
+    "full": ("de9af62e8648a378", "6e9a503b0ec8ddb0"),
 }
 SPECS = {"arrival": {}, "arrival_pallas": dict(placer="pallas_interpret"),
          "easy": dict(queue="easy_backfill:window=4"),

@@ -113,20 +113,23 @@ def _folds(fn, *args, **kw):
     return hlo, obs.kth_free_calls()[seen:]
 
 
-@pytest.mark.parametrize("shape", [(8, S, MAXN), (8, S, 10240)])
+@pytest.mark.parametrize("shape", [(8, S, MAXN), (8, S, 10240),
+                                   (12, 2, 9688)])
 def test_kth_free_under_vmap_scan(one_chip, shape):
     """The lanes fold into one invocation: one block where they fit
     ``LANE_BLOCK_BYTES``, else a grid over groups of as many as fit
-    (six lanes of [4, 10240])."""
+    (six lanes of [4, 10240]).  NERSC Cori's 12 lanes of [2, 9688] take
+    one block of 930,048 bytes, within the walk's scoped VMEM."""
+    L, s, n = shape
     hlo, folds = _folds(jax.jit(_vmap_scan(_pallas)),
                         _sds(shape, jnp.float32, one_chip),
                         _sds(shape[:2], jnp.int32, one_chip))
     assert "tpu_custom_call" in hlo
-    per_lane = S * shape[-1] * 4
-    G = min(8, LANE_BLOCK_BYTES // per_lane)
-    assert folds == (obs.KthFreeCall(lanes=8, grid_steps=-(-8 // G),
+    per_lane = s * n * 4
+    G = min(L, LANE_BLOCK_BYTES // per_lane)
+    assert folds == (obs.KthFreeCall(lanes=L, grid_steps=-(-L // G),
                                      block_bytes=G * per_lane),)
-    assert (folds[0].grid_steps > 1) == (shape[-1] == 10240)
+    assert (folds[0].grid_steps > 1) == (n == 10240)
 
 
 @pytest.mark.parametrize("W", [17, 33])
