@@ -1,4 +1,5 @@
-"""Campaign-scale scheduling engine: one jitted core, one ``Scheduler`` facade.
+"""Campaign-scale scheduling engine: four jitted scan cores, one ``Scheduler``
+facade.
 
 Models the paper's SCC: several computing systems (CC_1..CC_S), each a pool
 of interchangeable nodes with per-node free-times; a global job queue routed
@@ -12,8 +13,10 @@ The facade::
                     faults=fault_list).run(workload)    # fault x policy grid
 
 ``Scheduler.run`` flattens the (fault x policy x seed) grid to one batch
-axis, vmaps the lax.scan core over it inside a single jit, and reshapes
-back into a structured ``SimResult``/``CampaignResult`` with named axes.
+axis, vmaps the routed lax.scan core over it inside a single jit, and
+reshapes back into a structured ``SimResult``/``CampaignResult`` with
+named axes.
+
 Because Policy hyperparameters (K, ucb_scale) are PyTree *leaves*, a whole
 policy-hyperparameter grid shares one compilation — the static policy
 metadata (exploration/feasibility/objective) is the only thing that
@@ -22,6 +25,30 @@ retraces.
 ``totals_only=True`` keeps the per-job accounting in the scan carry instead
 of materializing [*grid, J] placement arrays — a 10^5-job x large-grid
 campaign returns [*grid] aggregates in O(grid) memory.
+
+The cores (routed by ``_sim_pieces``):
+
+- arrival FCFS (``_arrival_pieces``): one step per job, in arrival order;
+- arrival EASY (``_easy_pieces``): one step per arrival over a bounded
+  pending window, the head's reservation guarded;
+- event-granular (``make_event_step``): the clock walks arrivals and
+  completions — power caps, mid-job failure re-queue, and the online
+  service's step;
+- conservative (``make_cons_step``): event-granular, every pending job
+  holding a reservation.
+
+Each placement stage is written once and shared by every core that has
+it: ``_earliest``/``_earliest_shared`` (kth-free time and earliest start),
+``_select_tiered`` (the policy's choice over system and DVFS tier),
+``_alloc``/``_alloc_mask`` (the node-free update), ``_learn`` (the
+learned tables), ``_kahan`` (the ``totals_only`` sums) and
+``_totals_results``/``_job_results`` (the result epilogue).
+``_earliest``/``_earliest_shared``, ``_alloc``/``_alloc_mask``,
+``_select_tiered`` and ``_learn`` open their ``repro.obs`` stage scope
+themselves, so those stages read alike in every core; ``_kahan`` runs in
+its caller's ``step.account`` scope.  The realised-value step (fault
+factor, tier tables, per-job scales) and the event cores' push/advance
+blocks keep one copy per core: no two copies agree op for op.
 
 Placement hot path: the per-step question "when are n_req[s] nodes of
 system s free?" is the n_req-th smallest entry of the node-free row,
@@ -319,6 +346,40 @@ def _running_mean(mean, n, x, scales):
     return (total + x) / (n + 1)
 
 
+def _learn(C_tab, T_tab, runs, p, sel, C_obs, T_obs, scales, mask=None):
+    """The learned tables after class ``p`` ran on system ``sel`` and was
+    observed at ``(C_obs, T_obs)``: both running means and the run count
+    move by one observation (``_running_mean``).  ``mask``: where given,
+    a step where it is false leaves the tables as they were (the queued
+    cores, whose steps need not place)."""
+    with _stage("learn"):
+        n = runs[p, sel].astype(jnp.float32)
+
+        def update(tab, obs):
+            new = _running_mean(tab[p, sel], n, obs, scales)
+            return tab.at[p, sel].set(
+                new if mask is None else jnp.where(mask, new, tab[p, sel]))
+        C_tab, T_tab = update(C_tab, C_obs), update(T_tab, T_obs)
+        runs = runs.at[p, sel].add(1 if mask is None
+                                   else jnp.where(mask, 1, 0))
+        return C_tab, T_tab, runs
+
+
+def _kahan(sums, comps, add, mask=None):
+    """One Kahan-compensated step of the f32 totals: ``(sums, comps)``
+    after adding ``add``.  10^5 sequential adds would otherwise drift
+    ~0.1% from the full path's array reduction (x64 is unavailable, so
+    compensation stands in for f64).  ``mask``: where given, a step where
+    it is false keeps both as they were (the event cores, which mask the
+    step; the EASY scan masks the addend instead)."""
+    y = add - comps
+    t = sums + y
+    if mask is None:
+        return t, (t - sums) - y
+    return (jnp.where(mask, t, sums),
+            jnp.where(mask, (t - sums) - y, comps))
+
+
 def _job_nodes(arrs, prog, sel):
     """[J] nodes each job held on its system ``sel``."""
     if "n_req_job" in arrs:
@@ -423,6 +484,39 @@ def _tier_rows(tt, p, C_row, T_row, runs_row, avail_row, C_pred_row,
             flat(T_pred_row[..., None, :] * rt))
 
 
+def _select_tiered(policy, tt, p, C_tab, T_tab, runs, avail, k, C_pred,
+                   T_pred, key):
+    """The policy's choice for one job of class ``p`` (``key`` its
+    selection key), or for a batch of them (``p`` [W], ``key`` a [W] key
+    array): ``(f, sel)``, the frequency tier and the system.  With the
+    tier tables ``tt`` the choice runs over the (tier x system) candidate
+    axis (``_tier_rows``); without them ``f`` is tier 0.  ``avail`` is
+    the earliest start per system ([..., S]), or per (tier, system).
+    ``k`` is a function giving the effective K, called where the
+    selection reads it, so each core keeps its own K lookup in place."""
+    S = C_tab.shape[-1]
+    batched = jnp.ndim(p) > 0
+
+    def pick(c, t, r, a, kk, cp, tp):
+        if batched:
+            return select_batched(policy, c_rows=c, t_rows=t, runs_rows=r,
+                                  avail_rows=a, k=kk, c_pred_rows=cp,
+                                  t_pred_rows=tp, keys=key)
+        return select(policy, c_row=c, t_row=t, runs_row=r, avail_row=a,
+                      k=kk, c_pred_row=cp, t_pred_row=tp, key=key)
+
+    with _stage("select"):
+        if tt is None:
+            sel = pick(C_tab[p], T_tab[p], runs[p], avail, k(), C_pred[p],
+                       T_pred[p])
+            return (jnp.zeros(jnp.shape(p), jnp.int32) if batched
+                    else jnp.int32(0)), sel
+        c_x, t_x, r_x, a_x, cp_x, tp_x = _tier_rows(
+            tt, p, C_tab[p], T_tab[p], runs[p], avail, C_pred[p], T_pred[p])
+        sel_x = pick(c_x, t_x, r_x, a_x, k(), cp_x, tp_x)
+        return (sel_x // S).astype(jnp.int32), sel_x % S
+
+
 class _SimPieces(NamedTuple):
     """One simulation, disassembled for streamed execution:
     ``lax.scan(step, carry0, xs, length=length)`` followed by
@@ -468,11 +562,10 @@ def _stream_xs(arrs: dict, policy: Policy, core: str = "arrival",
 
 def _sim_pieces(arrs: dict, policy: Policy, warm_start: bool,
                 placer: str | None, totals_only: bool, seed, fvec,
-                easy_eval: str = "batched", core: str = "arrival",
-                retries: bool = False) -> _SimPieces:
+                core: str = "arrival", retries: bool = False) -> _SimPieces:
     """Build one simulation's pieces; every argument traced except the
-    static (policy metadata, warm_start, placer, totals_only, easy_eval,
-    core, retries).  Dispatch:
+    static (policy metadata, warm_start, placer, totals_only, core,
+    retries).  Dispatch:
 
     - ``core="arrival"`` (default): the historical arrival-indexed scans —
       the FCFS path bit-identical to the pre-queue-axis engine, EASY via
@@ -512,8 +605,7 @@ def _sim_pieces(arrs: dict, policy: Policy, warm_start: bool,
             lambda carry, ys: _event_results(arrs, totals_only, ys, carry))
     if policy.queue == "easy_backfill":
         step, carry0, fin = _easy_pieces(arrs, policy, placer, totals_only,
-                                         sel_key, fault_key, fvec, tabs0,
-                                         easy_eval)
+                                         sel_key, fault_key, fvec, tabs0)
     else:
         step, carry0, fin = _arrival_pieces(arrs, policy, placer,
                                             totals_only, sel_key,
@@ -523,12 +615,11 @@ def _sim_pieces(arrs: dict, policy: Policy, warm_start: bool,
 
 def _scan_sim(arrs: dict, policy: Policy, warm_start: bool,
               placer: str | None, totals_only: bool, seed, fvec,
-              easy_eval: str = "batched", core: str = "arrival",
-              retries: bool = False):
+              core: str = "arrival", retries: bool = False):
     """One full simulation: fold the routed core's pieces through a
     single lax.scan (see ``_sim_pieces`` for dispatch and staticness)."""
     pieces = _sim_pieces(arrs, policy, warm_start, placer, totals_only,
-                         seed, fvec, easy_eval, core, retries)
+                         seed, fvec, core, retries)
     carry, ys = jax.lax.scan(pieces.step, pieces.carry0, pieces.xs,
                              length=pieces.length)
     return pieces.finish(carry, ys)
@@ -539,10 +630,8 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
     """Pieces of the arrival-indexed FCFS scan (the historical core)."""
     T_true, C_true, E_true = arrs["T_true"], arrs["C_true"], arrs["E_true"]
     T_pred, C_pred = arrs["T_pred"], arrs["C_pred"]
-    n_req, prog, arrival = arrs["n_req"], arrs["prog"], arrs["arrival"]
     outage = arrs.get("outage")
-    P, S = T_true.shape
-    J = prog.shape[0]
+    S = T_true.shape[1]
     tiered = policy.tiered
     tt = tier_tables(arrs, policy.freq_tiers) if tiered else None
     pol_k = jnp.asarray(policy.k, jnp.float32)
@@ -563,21 +652,8 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
 
         with _stage("select"):
             key = jax.random.fold_in(sel_key, j)
-            if tiered:
-                c_x, t_x, r_x, a_x, cp_x, tp_x = _tier_rows(
-                    tt, p, C_tab[p], T_tab[p], runs[p], avail, C_pred[p],
-                    T_pred[p])
-                sel_x = select(policy, c_row=c_x, t_row=t_x, runs_row=r_x,
-                               avail_row=a_x, k=k, c_pred_row=cp_x,
-                               t_pred_row=tp_x, key=key)
-                f = (sel_x // S).astype(jnp.int32)
-                sel = sel_x % S
-            else:
-                f = jnp.int32(0)
-                sel = select(
-                    policy, c_row=C_tab[p], t_row=T_tab[p],
-                    runs_row=runs[p], avail_row=avail, k=k,
-                    c_pred_row=C_pred[p], t_pred_row=T_pred[p], key=key)
+        f, sel = _select_tiered(policy, tt, p, C_tab, T_tab, runs, avail,
+                                lambda: k, C_pred, T_pred, key)
 
         with _stage("fault"):
             factor = _fault_factor(fault_key, j, fvec)
@@ -604,26 +680,16 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
             need = nreq_row[sel]
             node_free = _alloc(node_free, sel, kth[sel], need, finish)
 
-        with _stage("learn"):
-            n = runs[p, sel].astype(jnp.float32)
-            C_tab = C_tab.at[p, sel].set(
-                _running_mean(C_tab[p, sel], n, C_act, scales))
-            T_tab = T_tab.at[p, sel].set(
-                _running_mean(T_tab[p, sel], n, T_upd, scales))
-            runs = runs.at[p, sel].add(1)
+        C_tab, T_tab, runs = _learn(C_tab, T_tab, runs, p, sel, C_act,
+                                    T_upd, scales)
 
         with _stage("account"):
             wait = start - arr
             if totals_only:
                 sums, comps, fin_max, busy, wait_max = acc
-                # Kahan-compensated f32 sums: 10^5 sequential adds would
-                # otherwise drift ~0.1% vs the full path's array
-                # reduction (x64 is unavailable, so compensation stands
-                # in for f64)
                 add = jnp.stack([E_act, wait, (wait + T_act) / T_act])
-                y = add - comps
-                t = sums + y
-                acc = (t, (t - sums) - y, jnp.maximum(fin_max, finish),
+                acc = (*_kahan(sums, comps, add),
+                       jnp.maximum(fin_max, finish),
                        busy.at[sel].add(T_act * need),
                        jnp.maximum(wait_max, wait))
                 out = None
@@ -638,28 +704,13 @@ def _arrival_pieces(arrs: dict, policy: Policy, placer: str | None,
     carry0 = (arrs["free0"], *tabs0, acc0)
 
     def finish(carry, ys):
-        node_free, C_tab, T_tab, runs, acc = carry
+        _, C_tab, T_tab, runs, acc = carry
         tabs = {"C_tab": C_tab, "T_tab": T_tab, "runs": runs,
                 "n_backfilled": jnp.zeros((), jnp.int32)}
         if totals_only:
             sums, _, fin_max, busy, wait_max = acc
-            return {"total_energy": sums[0], "makespan": fin_max,
-                    "total_wait": sums[1], "slowdown_sum": sums[2],
-                    "max_wait": wait_max, "busy": busy,
-                    **_power_totals(arrs, fin_max, busy), **tabs}
-        sel, start, fin, wait, E, T_act, tier = ys
-        nodes = _job_nodes(arrs, prog, sel)                      # [J]
-        busy = jnp.zeros(S, jnp.float32).at[sel].add(T_act * nodes)
-        makespan = fin.max()
-        return {
-            "system": sel, "start": start, "finish": fin, "wait": wait,
-            "energy": E, "runtime": T_act, "nodes": nodes, "tier": tier,
-            "backfilled": jnp.zeros(J, bool),
-            "total_energy": E.sum(), "makespan": makespan,
-            "total_wait": wait.sum(), "max_wait": wait.max(),
-            "slowdown_sum": ((wait + T_act) / T_act).sum(), "busy": busy,
-            **_power_totals(arrs, makespan, busy), **tabs,
-        }
+            return _totals_results(arrs, tabs, sums, fin_max, wait_max, busy)
+        return _job_results(arrs, tabs, *ys)
 
     return step, carry0, finish
 
@@ -679,9 +730,49 @@ def _power_totals(arrs, makespan, busy, peak_power=None, capped_delay=None):
     }
 
 
+def _totals_results(arrs, tabs, sums, makespan, max_wait, busy, peak=None,
+                    cdel=None):
+    """The result epilogue of a ``totals_only`` scan: the Kahan sums
+    ``[energy, wait, slowdown]``, the running maxima, busy node-seconds,
+    the power fields and the learned tables ``tabs``."""
+    return {"total_energy": sums[0], "makespan": makespan,
+            "total_wait": sums[1], "slowdown_sum": sums[2],
+            "max_wait": max_wait, "busy": busy,
+            **_power_totals(arrs, makespan, busy, peak, cdel), **tabs}
+
+
+def _job_totals(arrs, E, wait, T_act, makespan, busy, peak=None, cdel=None):
+    """The totals over per-job arrays (a full scan's, or a live session's
+    placed jobs; none placed: no wait)."""
+    return {"total_energy": E.sum(), "makespan": makespan,
+            "total_wait": wait.sum(),
+            "max_wait": wait.max() if wait.size else jnp.float32(0.0),
+            "slowdown_sum": ((wait + T_act) / T_act).sum(),
+            **_power_totals(arrs, makespan, busy, peak, cdel)}
+
+
+def _job_results(arrs, tabs, sel, start, fin, wait, E, T_act, tier,
+                 backfilled=None, busy=None, peak=None, cdel=None):
+    """The result epilogue of a full scan, from its per-job arrays in
+    arrival order: the arrays, each job's nodes, the totals and the
+    learned tables ``tabs``.  ``busy`` None: summed here from the jobs;
+    ``backfilled`` None: no job backfilled (the FCFS scan)."""
+    nodes = _job_nodes(arrs, arrs["prog"], sel)                  # [J]
+    if busy is None:
+        busy = jnp.zeros(arrs["T_true"].shape[1], jnp.float32).at[sel].add(
+            T_act * nodes)
+    makespan = fin.max()
+    if backfilled is None:
+        backfilled = jnp.zeros(sel.shape[0], bool)
+    return {"system": sel, "start": start, "finish": fin, "wait": wait,
+            "energy": E, "runtime": T_act, "nodes": nodes,
+            "backfilled": backfilled, "tier": tier, "busy": busy,
+            **_job_totals(arrs, E, wait, T_act, makespan, busy, peak, cdel),
+            **tabs}
+
+
 def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
-                 totals_only: bool, sel_key, fault_key, fvec, tabs0,
-                 easy_eval: str = "batched"):
+                 totals_only: bool, sel_key, fault_key, fvec, tabs0):
     """EASY-backfilling scan: J + W steps over a bounded pending window.
 
     The carry grows a pending buffer of W + 1 job-id slots (ascending,
@@ -709,33 +800,28 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
     semantics.  Per-step outputs carry (job id | sentinel); the full path
     scatters them back into arrival-indexed [J] arrays after the scan.
 
-    Candidate evaluation (``easy_eval``, static): every trial allocation
-    in a step is computed against the SAME starting node-free table, so
-    the W + 1 slots are independent and the first-fit choice is a masked
-    argmin over slot index.  ``"batched"`` (default) scores all slots in
-    one shared-table [W+1, S] kth-free call (``kth_free_time_shared`` —
-    one sort serves every candidate) + one vmapped ``select`` + one
-    vmapped tentative allocation; the no-delay guard then needs only the
-    head's RESERVED system, so one per-row kth query over the trials'
-    ``sel_h`` rows ([W+1, maxN]) rechecks every candidate at once — two
-    batched kernel calls per step instead of ~2W sequential radix walks.
-    ``"unrolled"`` is the historical python-unrolled loop, kept as the
-    bit-identity reference (``tests/test_easy_batched.py`` asserts the
-    two agree exactly across the whole policy registry).
+    Candidate evaluation: every trial allocation in a step is computed
+    against the SAME starting node-free table, so the W + 1 slots are
+    independent and the first-fit choice is a masked argmin over slot
+    index.  All slots are scored in one shared-table [W+1, S] kth-free
+    call (``kth_free_time_shared`` — one sort serves every candidate) +
+    one vmapped ``select`` + one vmapped tentative allocation; the
+    no-delay guard then needs only the head's RESERVED system, so one
+    per-row kth query over the trials' ``sel_h`` rows ([W+1, maxN])
+    rechecks every candidate at once — two batched kernel calls per step
+    instead of ~2W sequential radix walks.  The float64 mirror
+    (``simulate_py``'s ``_easy_order_py``) replays the same decisions
+    slot by slot (``tests/test_easy_batched.py``).
     """
     T_true, C_true, E_true = arrs["T_true"], arrs["C_true"], arrs["E_true"]
     T_pred, C_pred = arrs["T_pred"], arrs["C_pred"]
-    n_req, prog, arrival = arrs["n_req"], arrs["prog"], arrs["arrival"]
+    prog, arrival = arrs["prog"], arrs["arrival"]
     outage = arrs.get("outage")
-    P, S = T_true.shape
+    S = T_true.shape[1]
     J = prog.shape[0]
     W = int(policy.window)
     Wc = W + 1                           # buffer capacity (push-then-place)
     tiered = policy.tiered
-    if tiered and easy_eval != "batched":
-        raise ValueError("freq_tiers requires easy_eval='batched' (the "
-                         "unrolled loop predates the tier axis and exists "
-                         "only as the single-tier bit-identity reference)")
     tt = tier_tables(arrs, policy.freq_tiers) if tiered else None
     k_job = arrs["k_job"]
     pol_k = jnp.asarray(policy.k, jnp.float32)
@@ -745,21 +831,6 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
         K vector materializes per batch lane."""
         kj = k_job[j]
         return jnp.where(jnp.isnan(kj), pol_k, kj)
-
-    def sel_for(j, node_free, C_tab, T_tab, runs):
-        """Policy selection + earliest start for job id j (sentinel-safe:
-        j == J evaluates job J-1; callers mask the result)."""
-        with _stage("earliest"):
-            jj = jnp.minimum(j, J - 1)
-            p = prog[jj]
-            kth, avail = _earliest(node_free, _job_need(arrs, jj, p),
-                                   arrival[jj], placer, outage)
-        with _stage("select"):
-            sel = select(
-                policy, c_row=C_tab[p], t_row=T_tab[p], runs_row=runs[p],
-                avail_row=avail, k=k_of(jj), c_pred_row=C_pred[p],
-                t_pred_row=T_pred[p], key=jax.random.fold_in(sel_key, jj))
-        return jj, p, kth, avail, sel
 
     def eval_candidates(node_free, C_tab, T_tab, runs, pend):
         """Score every pending slot against the SAME node-free table in
@@ -775,23 +846,9 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
                 placer, outage)                                   # [Wc, S]
         with _stage("select"):
             keys = jax.vmap(lambda j: jax.random.fold_in(sel_key, j))(jjs)
-            if tiered:
-                c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
-                    tt, ps, C_tab[ps], T_tab[ps], runs[ps], avails,
-                    C_pred[ps], T_pred[ps])
-                sels_x = select_batched(
-                    policy, c_rows=c_x, t_rows=t_x, runs_rows=runs_x,
-                    avail_rows=avail_x, k=k_of(jjs), c_pred_rows=cp_x,
-                    t_pred_rows=tp_x, keys=keys)                  # [Wc]
-                fs = (sels_x // S).astype(jnp.int32)
-                sels = sels_x % S
-            else:
-                sels = select_batched(
-                    policy, c_rows=C_tab[ps], t_rows=T_tab[ps],
-                    runs_rows=runs[ps], avail_rows=avails, k=k_of(jjs),
-                    c_pred_rows=C_pred[ps], t_pred_rows=T_pred[ps],
-                    keys=keys)                                    # [Wc]
-                fs = jnp.zeros(Wc, jnp.int32)
+        fs, sels = _select_tiered(policy, tt, ps, C_tab, T_tab, runs,
+                                  avails, lambda: k_of(jjs), C_pred, T_pred,
+                                  keys)                           # [Wc]
         factors = jax.vmap(lambda j: _fault_factor(fault_key, j, fvec))(jjs)
         with _stage("alloc"):
             idx = jnp.arange(Wc)
@@ -823,117 +880,55 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
             forced = size == Wc                   # window full: FCFS fallback
             head_valid = pend[0] < J
 
-        if easy_eval == "batched":
-            # one batched evaluation of all Wc slots; slot 0 is the head
-            jjs, ps, sels, fs, starts, T_acts, factors, needs, trials = \
-                eval_candidates(node_free, C_tab, T_tab, runs, pend)
-            with _stage("select"):
-                hj, p_h, sel_h = jjs[0], ps[0], sels[0]
-                r_h = starts[0]                   # head reservation
-                place_head = head_valid & (forced | (r_h <= now))
+        # one batched evaluation of all Wc slots; slot 0 is the head
+        jjs, ps, sels, fs, starts, T_acts, factors, needs, trials = \
+            eval_candidates(node_free, C_tab, T_tab, runs, pend)
+        with _stage("select"):
+            hj, p_h, sel_h = jjs[0], ps[0], sels[0]
+            r_h = starts[0]                   # head reservation
+            place_head = head_valid & (forced | (r_h <= now))
 
-            # EASY no-delay guard for ALL candidates at once: a trial can
-            # only delay the head on the head's RESERVED system, so one
-            # per-row kth query over the trials' sel_h rows answers every
-            # candidate (rows untouched by a trial reproduce r_h exactly,
-            # so their guard passes as it must)
-            # (every kth mode is bit-exact, so absent an explicit placer
-            # the recheck picks the cheapest: one sort op over [Wc, maxN]
-            # beats Wc radix walks inside a scan)
-            with _stage("earliest"):
-                kth_h2 = kth_free_time(
-                    trials[:, sel_h, :],
-                    jnp.broadcast_to(_job_need(arrs, hj, p_h, sel_h), (Wc,)),
-                    force=placer or "sort")
-                avail_h2 = jnp.maximum(arrival[hj], kth_h2)       # [Wc]
-                if outage is not None:
-                    # only sel_h's windows apply; [1, W0, 2] broadcasts
-                    # the shared push over the [Wc] candidate vector
-                    avail_h2 = _push_out_of_outage(avail_h2,
-                                                   outage[sel_h][None])
-                ok = avail_h2 <= r_h                              # [Wc]
+        # EASY no-delay guard for ALL candidates at once: a trial can
+        # only delay the head on the head's RESERVED system, so one
+        # per-row kth query over the trials' sel_h rows answers every
+        # candidate (rows untouched by a trial reproduce r_h exactly,
+        # so their guard passes as it must)
+        # (every kth mode is bit-exact, so absent an explicit placer
+        # the recheck picks the cheapest: one sort op over [Wc, maxN]
+        # beats Wc radix walks inside a scan)
+        with _stage("earliest"):
+            kth_h2 = kth_free_time(
+                trials[:, sel_h, :],
+                jnp.broadcast_to(_job_need(arrs, hj, p_h, sel_h), (Wc,)),
+                force=placer or "sort")
+            avail_h2 = jnp.maximum(arrival[hj], kth_h2)       # [Wc]
+            if outage is not None:
+                # only sel_h's windows apply; [1, W0, 2] broadcasts
+                # the shared push over the [Wc] candidate vector
+                avail_h2 = _push_out_of_outage(avail_h2,
+                                               outage[sel_h][None])
+            ok = avail_h2 <= r_h                              # [Wc]
 
-            with _stage("select"):
-                # first-fit == masked argmin over slot index (Wc = none)
-                idx = jnp.arange(Wc)
-                elig = jnp.where(idx == 0, place_head,
-                                 head_valid & ~place_head & (pend < J) & ok)
-                chosen = jnp.min(jnp.where(elig, idx, Wc))
-                placed = chosen < Wc
-                ci = jnp.minimum(chosen, Wc - 1)
+        with _stage("select"):
+            # first-fit == masked argmin over slot index (Wc = none)
+            idx = jnp.arange(Wc)
+            elig = jnp.where(idx == 0, place_head,
+                             head_valid & ~place_head & (pend < J) & ok)
+            chosen = jnp.min(jnp.where(elig, idx, Wc))
+            placed = chosen < Wc
+            ci = jnp.minimum(chosen, Wc - 1)
 
-            with _stage("alloc"):
-                # gather the chosen slot: its trial allocation was
-                # computed against the real starting node_free, so it IS
-                # the placement
-                jj, p, sel, f = jjs[ci], ps[ci], sels[ci], fs[ci]
-                factor = factors[ci]
-                T_act = T_acts[ci]
-                start = starts[ci]
-                need = needs[ci]
-                j_pl = jnp.where(placed, pend[ci], J)
-                node_free = jnp.where(placed, trials[ci], node_free)
-        else:
-            # head-of-queue reservation from current node-free times
-            h = pend[0]
-            hj, p_h, _, avail_h, sel_h = sel_for(h, node_free, C_tab, T_tab,
-                                                 runs)
-            with _stage("select"):
-                r_h = avail_h[sel_h]
-                place_head = head_valid & (forced | (r_h <= now))
-
-                # EASY backfill: first pending job (arrival order) whose
-                # tentative allocation cannot delay the head's
-                # reservation on its reserved system
-                chosen = jnp.where(place_head, 0, Wc)  # slot; Wc = none
-                may_backfill = head_valid & ~place_head
-            for ci in range(1, Wc):
-                with _stage("select"):
-                    b = pend[ci]
-                    live = may_backfill & (b < J) & (chosen == Wc)
-                bj, p_b, kth_b, avail_b, sel_b = sel_for(b, node_free, C_tab,
-                                                         T_tab, runs)
-                with _stage("alloc"):
-                    s_b = avail_b[sel_b]
-                    T_b = T_true[p_b, sel_b] * _fault_factor(
-                        fault_key, bj, fvec)
-                scales_b = _job_scales(arrs, bj, p_b, sel_b)
-                if scales_b is not None:
-                    with _stage("job"):
-                        T_b = T_b * scales_b[0]
-                with _stage("alloc"):
-                    fin_b = s_b + T_b
-                    trial = _alloc(node_free, sel_b, kth_b[sel_b],
-                                   _job_need(arrs, bj, p_b, sel_b), fin_b)
-                with _stage("earliest"):
-                    _, avail_h2 = _earliest(trial, _job_need(arrs, hj, p_h),
-                                            arrival[hj], placer, outage)
-                    ok = avail_h2[sel_h] <= r_h
-                with _stage("select"):
-                    chosen = jnp.where(live & ok, ci, chosen)
-
-            # place the chosen job (if any): same math as the FCFS step
-            with _stage("select"):
-                placed = chosen < Wc
-                j_pl = jnp.where(placed, pend[jnp.minimum(chosen, Wc - 1)],
-                                 J)
-            jj, p, kth, avail, sel = sel_for(j_pl, node_free, C_tab, T_tab,
-                                             runs)
-            with _stage("fault"):
-                f = jnp.int32(0)                  # unrolled path is untier
-                factor = _fault_factor(fault_key, jj, fvec)
-                T_act = T_true[p, sel] * factor
-            scales = _job_scales(arrs, jj, p, sel)
-            if scales is not None:
-                with _stage("job"):
-                    T_act = T_act * scales[0]
-            with _stage("alloc"):
-                start = avail[sel]
-                need = _job_need(arrs, jj, p, sel)
-                node_free = jnp.where(
-                    placed,
-                    _alloc(node_free, sel, kth[sel], need, start + T_act),
-                    node_free)
+        with _stage("alloc"):
+            # gather the chosen slot: its trial allocation was
+            # computed against the real starting node_free, so it IS
+            # the placement
+            jj, p, sel, f = jjs[ci], ps[ci], sels[ci], fs[ci]
+            factor = factors[ci]
+            T_act = T_acts[ci]
+            start = starts[ci]
+            need = needs[ci]
+            j_pl = jnp.where(placed, pend[ci], J)
+            node_free = jnp.where(placed, trials[ci], node_free)
 
         with _stage("fault"):
             # learned tables always absorb BASE (tier-0) observations;
@@ -949,15 +944,8 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
         with _stage("alloc"):
             finish = start + T_act
 
-        with _stage("learn"):
-            n = runs[p, sel].astype(jnp.float32)
-            C_tab = C_tab.at[p, sel].set(jnp.where(
-                placed, _running_mean(C_tab[p, sel], n, C_act, scales),
-                C_tab[p, sel]))
-            T_tab = T_tab.at[p, sel].set(jnp.where(
-                placed, _running_mean(T_tab[p, sel], n, T_upd, scales),
-                T_tab[p, sel]))
-            runs = runs.at[p, sel].add(jnp.where(placed, 1, 0))
+        C_tab, T_tab, runs = _learn(C_tab, T_tab, runs, p, sel, C_act,
+                                    T_upd, scales, mask=placed)
 
         with _stage("account"):
             was_backfill = placed & (chosen > 0)
@@ -977,9 +965,7 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
                 add = jnp.where(
                     placed, jnp.stack([E_act, wait, (wait + T_act) / T_act]),
                     0.0)
-                y = add - comps
-                t = sums + y
-                acc = (t, (t - sums) - y,
+                acc = (*_kahan(sums, comps, add),
                        jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
                        busy.at[sel].add(jnp.where(placed, T_act * need,
                                                   0.0)),
@@ -998,15 +984,12 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
     carry0 = (arrs["free0"], *tabs0, acc0, pend0, jnp.zeros((), jnp.int32))
 
     def finish(carry, ys):
-        node_free, C_tab, T_tab, runs, acc, pend, nbf = carry
+        _, C_tab, T_tab, runs, acc, _, nbf = carry
         tabs = {"C_tab": C_tab, "T_tab": T_tab, "runs": runs,
                 "n_backfilled": nbf}
         if totals_only:
             sums, _, fin_max, busy, wait_max = acc
-            return {"total_energy": sums[0], "makespan": fin_max,
-                    "total_wait": sums[1], "slowdown_sum": sums[2],
-                    "max_wait": wait_max, "busy": busy,
-                    **_power_totals(arrs, fin_max, busy), **tabs}
+            return _totals_results(arrs, tabs, sums, fin_max, wait_max, busy)
 
         # scatter per-step outputs back to arrival order; sentinels drop
         j_pl, sel_s, start_s, fin_s, wait_s, E_s, T_s, bf_s, f_s = ys
@@ -1020,18 +1003,8 @@ def _easy_pieces(arrs: dict, policy: Policy, placer: str | None,
         T_act = scat(T_s, jnp.float32)
         backfilled = scat(bf_s, bool)
         tier = scat(f_s, jnp.int32)
-        nodes = _job_nodes(arrs, prog, sel)                      # [J]
-        busy = jnp.zeros(S, jnp.float32).at[sel].add(T_act * nodes)
-        makespan = fin.max()
-        return {
-            "system": sel, "start": start, "finish": fin, "wait": wait,
-            "energy": E, "runtime": T_act, "nodes": nodes,
-            "backfilled": backfilled, "tier": tier,
-            "total_energy": E.sum(), "makespan": makespan,
-            "total_wait": wait.sum(), "max_wait": wait.max(),
-            "slowdown_sum": ((wait + T_act) / T_act).sum(), "busy": busy,
-            **_power_totals(arrs, makespan, busy), **tabs,
-        }
+        return _job_results(arrs, tabs, sel, start, fin, wait, E, T_act,
+                            tier, backfilled)
 
     return step, carry0, finish
 
@@ -1206,7 +1179,7 @@ def make_event_step(policy: Policy, placer: str | None = None,
         T_true, C_true, E_true = (arrs["T_true"], arrs["C_true"],
                                   arrs["E_true"])
         T_pred, C_pred = arrs["T_pred"], arrs["C_pred"]
-        n_req, prog, arrival = arrs["n_req"], arrs["prog"], arrs["arrival"]
+        prog, arrival = arrs["prog"], arrs["arrival"]
         outage = arrs.get("outage")
         w_pow, idle_w = arrs["w_pow"], arrs["idle_w"]
         # per-job effective K at use (NaN -> the policy leaf; elementwise
@@ -1273,24 +1246,9 @@ def make_event_step(policy: Policy, placer: str | None = None,
                                             t0f[:, None], placer, outage)
         with _stage("select"):
             keys = jax.vmap(lambda j: jax.random.fold_in(sel_key, j))(jjs)
-            if tiered:
-                S = T_true.shape[1]
-                c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
-                    tt, ps, C_tab[ps], T_tab[ps], runs[ps], avails,
-                    C_pred[ps], T_pred[ps])
-                sels_x = select_batched(
-                    policy, c_rows=c_x, t_rows=t_x, runs_rows=runs_x,
-                    avail_rows=avail_x, k=k_of(jjs), c_pred_rows=cp_x,
-                    t_pred_rows=tp_x, keys=keys)                 # [Wc]
-                fs = (sels_x // S).astype(jnp.int32)
-                sels = sels_x % S
-            else:
-                sels = select_batched(
-                    policy, c_rows=C_tab[ps], t_rows=T_tab[ps],
-                    runs_rows=runs[ps], avail_rows=avails, k=k_of(jjs),
-                    c_pred_rows=C_pred[ps], t_pred_rows=T_pred[ps],
-                    keys=keys)                                   # [Wc]
-                fs = jnp.zeros(Wc, jnp.int32)
+        fs, sels = _select_tiered(policy, tt, ps, C_tab, T_tab, runs,
+                                  avails, lambda: k_of(jjs), C_pred, T_pred,
+                                  keys)                          # [Wc]
         with _stage("alloc"):
             starts_res = avails[idx, sels]                       # [Wc]
 
@@ -1423,16 +1381,11 @@ def make_event_step(policy: Policy, placer: str | None = None,
             T_upd = T_true[p, sel] * fac_tot
         if scales is not None:
             with _stage("job"):
-                T_upd = T_upd * scales[0][ci]
-        with _stage("learn"):
-            n = runs[p, sel].astype(jnp.float32)
-            C_tab = C_tab.at[p, sel].set(jnp.where(
-                final, _running_mean(C_tab[p, sel], n, C_upd, scales),
-                C_tab[p, sel]))
-            T_tab = T_tab.at[p, sel].set(jnp.where(
-                final, _running_mean(T_tab[p, sel], n, T_upd, scales),
-                T_tab[p, sel]))
-            runs = runs.at[p, sel].add(jnp.where(final, 1, 0))
+                # (without per-job runtimes the scale is one, for all slots)
+                ts = scales[0]
+                T_upd = T_upd * (ts[ci] if jnp.ndim(ts) else ts)
+        C_tab, T_tab, runs = _learn(C_tab, T_tab, runs, p, sel, C_upd,
+                                    T_upd, scales, mask=final)
 
         with _stage("account"):
             busy = busy.at[sel].add(jnp.where(placed, T_act * need, 0.0))
@@ -1492,10 +1445,7 @@ def make_event_step(policy: Policy, placer: str | None = None,
                 # Kahan update applied ONLY on placement steps, so the
                 # FCFS op sequence matches the arrival-indexed core bit
                 # for bit
-                y = add - comps
-                t = sums + y
-                acc = (jnp.where(placed, t, sums),
-                       jnp.where(placed, (t - sums) - y, comps),
+                acc = (*_kahan(sums, comps, add, mask=placed),
                        jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
                        jnp.maximum(wait_max, jnp.where(final, wait_tot,
                                                        0.0)))
@@ -1531,17 +1481,14 @@ def _event_results(arrs, totals_only, ys, carry):
     totals accumulator, or scatter the per-step (attempt-energy,
     final-attempt fields) output channels back to arrival order.  Takes
     the final carry (EventCarry or ConsCarry — same field names)."""
-    n_req, prog = arrs["n_req"], arrs["prog"]
-    J = prog.shape[0]
+    J = arrs["prog"].shape[0]
     busy, peak, cdel = carry.busy, carry.peak, carry.cdel
     tabs = {"C_tab": carry.C_tab, "T_tab": carry.T_tab, "runs": carry.runs,
             "n_backfilled": carry.nbf}
     if totals_only:
         sums, _, fin_max, wait_max = carry.acc
-        return {"total_energy": sums[0], "makespan": fin_max,
-                "total_wait": sums[1], "slowdown_sum": sums[2],
-                "max_wait": wait_max, "busy": busy,
-                **_power_totals(arrs, fin_max, busy, peak, cdel), **tabs}
+        return _totals_results(arrs, tabs, sums, fin_max, wait_max, busy,
+                               peak, cdel)
 
     j_add, E_s, j_fin = ys["j_add"], ys["E"], ys["j_fin"]
     sel_s, s0_s, fin_s = ys["sys"], ys["s0"], ys["finish"]
@@ -1556,17 +1503,8 @@ def _event_results(arrs, totals_only, ys, carry):
     T_act = scat(T_s, jnp.float32)
     backfilled = scat(bf_s, bool)
     tier = scat(ys["tier"], jnp.int32)
-    nodes = _job_nodes(arrs, prog, sel)                          # [J]
-    makespan = finish.max()
-    return {
-        "system": sel, "start": start, "finish": finish, "wait": wait,
-        "energy": E, "runtime": T_act, "nodes": nodes,
-        "backfilled": backfilled, "tier": tier,
-        "total_energy": E.sum(), "makespan": makespan,
-        "total_wait": wait.sum(), "max_wait": wait.max(),
-        "slowdown_sum": ((wait + T_act) / T_act).sum(), "busy": busy,
-        **_power_totals(arrs, makespan, busy, peak, cdel), **tabs,
-    }
+    return _job_results(arrs, tabs, sel, start, finish, wait, E, T_act, tier,
+                        backfilled, busy, peak, cdel)
 
 
 class ConsCarry(NamedTuple):
@@ -1689,7 +1627,7 @@ def make_cons_step(policy: Policy, placer: str | None = None,
         T_true, C_true, E_true = (arrs["T_true"], arrs["C_true"],
                                   arrs["E_true"])
         T_pred, C_pred = arrs["T_pred"], arrs["C_pred"]
-        n_req, prog, arrival = arrs["n_req"], arrs["prog"], arrs["arrival"]
+        prog, arrival = arrs["prog"], arrs["arrival"]
         outage = arrs.get("outage")
         w_pow, idle_w = arrs["w_pow"], arrs["idle_w"]
         # per-job effective K at use (see make_event_step's k_of)
@@ -1799,16 +1737,9 @@ def make_cons_step(policy: Policy, placer: str | None = None,
                 avail_f = jax.vmap(
                     lambda td: earliest_fit(jp, p, t0, td, node_free, slots)
                 )(Tdur_f)                                        # [F, S]
-                with _stage("select"):
-                    c_x, t_x, runs_x, avail_x, cp_x, tp_x = _tier_rows(
-                        tt, p, C_tab[p], T_tab[p], runs[p], avail_f,
-                        C_pred[p], T_pred[p])
-                    sel_x = select(
-                        policy, c_row=c_x, t_row=t_x, runs_row=runs_x,
-                        avail_row=avail_x, k=k_of(jp), c_pred_row=cp_x,
-                        t_pred_row=tp_x, key=key)
-                    f = (sel_x // S).astype(jnp.int32)
-                    sel = sel_x % S
+                f, sel = _select_tiered(policy, tt, p, C_tab, T_tab, runs,
+                                        avail_f, lambda: k_of(jp), C_pred,
+                                        T_pred, key)
                 with _stage("push"):
                     start = avail_f[f, sel]
                     T_act = Tdur_f[f, sel]
@@ -1818,14 +1749,10 @@ def make_cons_step(policy: Policy, placer: str | None = None,
                 with _stage("fault"):
                     Tdur = T_true[p] * factor_t                      # [S]
                 avail_p = earliest_fit(jp, p, t0, Tdur, node_free, slots)
-                with _stage("select"):
-                    sel = select(
-                        policy, c_row=C_tab[p], t_row=T_tab[p],
-                        runs_row=runs[p], avail_row=avail_p, k=k_of(jp),
-                        c_pred_row=C_pred[p], t_pred_row=T_pred[p],
-                        key=key)
+                f, sel = _select_tiered(policy, None, p, C_tab, T_tab, runs,
+                                        avail_p, lambda: k_of(jp), C_pred,
+                                        T_pred, key)
                 with _stage("push"):
-                    f = jnp.int32(0)
                     start = avail_p[sel]
                     T_act = Tdur[sel]
                     E_res = E_true[p, sel] * factor
@@ -1957,15 +1884,8 @@ def make_cons_step(policy: Policy, placer: str | None = None,
         if scales is not None:
             with _stage("job"):
                 T_upd = T_upd * scales[0]
-        with _stage("learn"):
-            n = runs[p, sel].astype(jnp.float32)
-            C_tab = C_tab.at[p, sel].set(jnp.where(
-                final, _running_mean(C_tab[p, sel], n, C_upd, scales),
-                C_tab[p, sel]))
-            T_tab = T_tab.at[p, sel].set(jnp.where(
-                final, _running_mean(T_tab[p, sel], n, T_upd, scales),
-                T_tab[p, sel]))
-            runs = runs.at[p, sel].add(jnp.where(final, 1, 0))
+        C_tab, T_tab, runs = _learn(C_tab, T_tab, runs, p, sel, C_upd,
+                                    T_upd, scales, mask=final)
 
         with _stage("account"):
             busy = busy.at[sel].add(jnp.where(placed, T_act * need, 0.0))
@@ -2014,10 +1934,7 @@ def make_cons_step(policy: Policy, placer: str | None = None,
                     E_act,
                     jnp.where(final, wait_tot, 0.0),
                     jnp.where(final, (wait_tot + T_tot) / T_tot, 0.0)])
-                y = add - comps
-                t = sums + y
-                acc = (jnp.where(placed, t, sums),
-                       jnp.where(placed, (t - sums) - y, comps),
+                acc = (*_kahan(sums, comps, add, mask=placed),
                        jnp.maximum(fin_max, jnp.where(placed, finish, 0.0)),
                        jnp.maximum(wait_max, jnp.where(final, wait_tot,
                                                        0.0)))
@@ -2048,31 +1965,28 @@ def make_cons_step(policy: Policy, placer: str | None = None,
 
 
 @partial(jax.jit, static_argnames=("warm_start", "placer", "totals_only",
-                                   "easy_eval", "core", "retries"))
+                                   "core", "retries"))
 def _batched_run(arrs, policy, seeds, faults, *, warm_start, placer,
-                 totals_only, easy_eval="batched", core="arrival",
-                 retries=False):
+                 totals_only, core="arrival", retries=False):
     """vmap the scan core over a flat batch axis: policy leaves [B], seeds
     [B], faults [B, 4].  One compile per (shapes, policy metadata,
-    warm_start, placer, totals_only, easy_eval, core, retries)."""
+    warm_start, placer, totals_only, core, retries)."""
     return jax.vmap(
         lambda pol, sd, fv: _scan_sim(arrs, pol, warm_start, placer,
-                                      totals_only, sd, fv, easy_eval,
-                                      core, retries))(
+                                      totals_only, sd, fv, core, retries))(
         policy, seeds, faults)
 
 
 #: static argnames shared by the sharded/chunked grid entries; ``mesh``
 #: (a hashable jax.sharding.Mesh, or None = single-device) is static so
 #: shard_map specializes per mesh like every other compile key
-_GRID_STATICS = ("warm_start", "placer", "totals_only", "easy_eval",
-                 "core", "retries", "mesh")
+_GRID_STATICS = ("warm_start", "placer", "totals_only", "core", "retries",
+                 "mesh")
 
 
 @partial(jax.jit, static_argnames=_GRID_STATICS)
 def _sharded_run(arrs, policy, seeds, faults, *, mesh, warm_start, placer,
-                 totals_only, easy_eval="batched", core="arrival",
-                 retries=False):
+                 totals_only, core="arrival", retries=False):
     """``_batched_run`` with the flat batch axis partitioned over a 1-D
     ``("grid",)`` mesh (launch.mesh.make_grid_mesh): each device vmaps
     its B/n slice of the (policy leaves, seeds, faults) batch against
@@ -2083,8 +1997,8 @@ def _sharded_run(arrs, policy, seeds, faults, *, mesh, warm_start, placer,
     def body(arrs_, pol, sd, fv):
         return jax.vmap(
             lambda p_, s_, f_: _scan_sim(arrs_, p_, warm_start, placer,
-                                         totals_only, s_, f_, easy_eval,
-                                         core, retries))(pol, sd, fv)
+                                         totals_only, s_, f_, core,
+                                         retries))(pol, sd, fv)
     return jax.shard_map(
         body, mesh=mesh, check_vma=False,
         in_specs=(_replicated, _grid_spec, _grid_spec, _grid_spec),
@@ -2093,16 +2007,15 @@ def _sharded_run(arrs, policy, seeds, faults, *, mesh, warm_start, placer,
 
 @partial(jax.jit, static_argnames=_GRID_STATICS)
 def _chunk_init(arrs, policy, seeds, faults, *, mesh, warm_start, placer,
-                totals_only, easy_eval="batched", core="arrival",
-                retries=False):
+                totals_only, core="arrival", retries=False):
     """Initial [B]-leading carries of the chunked campaign (sharded over
     ``mesh`` when given, so the carry is born device-resident on its
     shard and never gathers)."""
     def body(arrs_, pol, sd, fv):
         return jax.vmap(
             lambda p_, s_, f_: _sim_pieces(
-                arrs_, p_, warm_start, placer, totals_only, s_, f_,
-                easy_eval, core, retries).carry0)(pol, sd, fv)
+                arrs_, p_, warm_start, placer, totals_only, s_, f_, core,
+                retries).carry0)(pol, sd, fv)
     if mesh is None:
         return body(arrs, policy, seeds, faults)
     return jax.shard_map(
@@ -2113,8 +2026,8 @@ def _chunk_init(arrs, policy, seeds, faults, *, mesh, warm_start, placer,
 
 @partial(jax.jit, static_argnames=_GRID_STATICS + ("nsteps",))
 def _chunk_advance(arrs, policy, seeds, faults, carries, xs, *, mesh,
-                   nsteps, warm_start, placer, totals_only,
-                   easy_eval="batched", core="arrival", retries=False):
+                   nsteps, warm_start, placer, totals_only, core="arrival",
+                   retries=False):
     """Advance every batch lane ``nsteps`` scan steps: the per-lane step
     closure is the monolithic scan's own (``_sim_pieces``), the carry is
     threaded in and out, and ``xs`` is the host-sliced window of the
@@ -2124,8 +2037,7 @@ def _chunk_advance(arrs, policy, seeds, faults, carries, xs, *, mesh,
     def body(arrs_, pol, sd, fv, carry, xs_):
         def lane(p_, s_, f_, c_):
             pieces = _sim_pieces(arrs_, p_, warm_start, placer,
-                                 totals_only, s_, f_, easy_eval, core,
-                                 retries)
+                                 totals_only, s_, f_, core, retries)
             return jax.lax.scan(pieces.step, c_, xs_, length=nsteps)
         return jax.vmap(lane)(pol, sd, fv, carry)
     if mesh is None:
@@ -2139,16 +2051,16 @@ def _chunk_advance(arrs, policy, seeds, faults, carries, xs, *, mesh,
 
 @partial(jax.jit, static_argnames=_GRID_STATICS)
 def _chunk_finish(arrs, policy, seeds, faults, carries, ys, *, mesh,
-                  warm_start, placer, totals_only, easy_eval="batched",
-                  core="arrival", retries=False):
+                  warm_start, placer, totals_only, core="arrival",
+                  retries=False):
     """The routed core's result epilogue over final carries (+ the
     reassembled per-step outputs on the full path; None when
     ``totals_only``) — the same ops the monolithic scan's finish runs."""
     def body(arrs_, pol, sd, fv, carry, ys_):
         return jax.vmap(
             lambda p_, s_, f_, c_, y_: _sim_pieces(
-                arrs_, p_, warm_start, placer, totals_only, s_, f_,
-                easy_eval, core, retries).finish(c_, y_))(
+                arrs_, p_, warm_start, placer, totals_only, s_, f_, core,
+                retries).finish(c_, y_))(
             pol, sd, fv, carry, ys_)
     if mesh is None:
         return body(arrs, policy, seeds, faults, carries, ys)
@@ -2160,8 +2072,7 @@ def _chunk_finish(arrs, policy, seeds, faults, carries, ys, *, mesh,
 
 
 def _run_chunked(arrs, policy, seeds, faults, *, chunk, mesh, warm_start,
-                 placer, totals_only, easy_eval="batched", core="arrival",
-                 retries=False):
+                 placer, totals_only, core="arrival", retries=False):
     """Stream the campaign scan through fixed-size windows of ``chunk``
     steps: jitted per-chunk advances thread the carry, per-step outputs
     (full path only) spill to host per chunk and are reassembled for the
@@ -2171,8 +2082,7 @@ def _run_chunked(arrs, policy, seeds, faults, *, chunk, mesh, warm_start,
     state end to end — no [B, J]-shaped intermediate ever materializes,
     which is what lets a 10^6-job trace stream through device memory."""
     kw = dict(mesh=mesh, warm_start=warm_start, placer=placer,
-              totals_only=totals_only, easy_eval=easy_eval, core=core,
-              retries=retries)
+              totals_only=totals_only, core=core, retries=retries)
     xs, length = _stream_xs(arrs, policy, core, retries)
     chunk = max(1, int(chunk))
     args = (arrs, policy, seeds, faults)
@@ -2242,10 +2152,6 @@ class Scheduler:
     queue:      queue-discipline spec overriding the policy's metadata:
                 "fcfs" | "easy_backfill[:window=W]" |
                 "conservative[:window=W]" (None = keep the policy's own)
-    easy_eval:  EASY candidate-evaluation strategy (static): "batched"
-                (default — one [W, S] kth-free call per step) or
-                "unrolled" (the historical per-slot loop, kept as the
-                bit-identity reference; ~W x slower at large windows)
     power_cap:  SCC power cap in Watts — a scalar, or a 1-D grid that
                 leaf-batches with k/ucb_scale (cap sweeps share one jit).
                 Overrides the policy's ``power_cap`` leaf; any finite cap
@@ -2290,7 +2196,7 @@ class Scheduler:
     def __init__(self, policy: str | Policy = "paper", *,
                  placer: str | None = None, faults=None, seeds=0,
                  warm_start: bool = False, queue: str | None = None,
-                 easy_eval: str = "batched", power_cap=None,
+                 power_cap=None,
                  engine: str | None = None, core=_CORE_UNSET,
                  shards=None, chunk=None):
         if core is not _CORE_UNSET:
@@ -2309,9 +2215,6 @@ class Scheduler:
         if power_cap is not None:
             self.policy = replace(self.policy,
                                   power_cap=np.asarray(power_cap, np.float32))
-        if easy_eval not in ("batched", "unrolled"):
-            raise ValueError(f"easy_eval {easy_eval!r} not in "
-                             "('batched', 'unrolled')")
         if engine not in (None, "arrival", "events"):
             raise ValueError(f"engine {engine!r} not in (None, 'arrival', "
                              "'events')")
@@ -2335,7 +2238,6 @@ class Scheduler:
                                  f"got {chunk}")
         self.chunk = chunk
         self.engine = engine
-        self.easy_eval = easy_eval
         self.placer = placer
         self.warm_start = bool(warm_start)
         if faults is None or isinstance(faults, FaultConfig):
@@ -2422,8 +2324,7 @@ class Scheduler:
         polb = replace(pol, k=kb, ucb_scale=ub, power_cap=pb,
                        freq_weight=fwb)
         common = dict(warm_start=self.warm_start, placer=self.placer,
-                      totals_only=totals_only, easy_eval=self.easy_eval,
-                      core=core, retries=retries)
+                      totals_only=totals_only, core=core, retries=retries)
         axes, lead = [], []
         for name, present, size in (("fault", has_fault_axis, F),
                                     ("policy", has_policy_axis, G),

@@ -128,12 +128,6 @@ def main():
     ap = argparse.ArgumentParser()
     add_policy_options(ap, engine=True)     # the shared grammar (cliargs)
     add_scale_options(ap)                   # --shards / --chunk
-    ap.add_argument("--easy-eval", default="batched",
-                    choices=("batched", "unrolled"),
-                    help="EASY candidate evaluation: batched (one [W, S] "
-                         "kth-free call per step) or the historical "
-                         "unrolled per-slot loop (bit-identical, ~W x "
-                         "slower; debugging/A-B only)")
     ap.add_argument("--sweep-k", default="",
                     help="comma-separated K values (fractions)")
     ap.add_argument("--jobs", type=int, default=0,
@@ -180,8 +174,7 @@ def main():
         seeds = [args.seed + i for i in range(max(args.campaign_seeds, 1))]
         res = Scheduler(pol.with_params(k=ks), faults=faults, seeds=seeds,
                         warm_start=not args.cold, engine=engine,
-                        easy_eval=args.easy_eval, **scale).run(
-            w, totals_only=args.totals_only)
+                        **scale).run(w, totals_only=args.totals_only)
         E = np.asarray(res.total_energy)            # [K, R]
         M = np.asarray(res.makespan)
         W = np.asarray(res.total_wait)
@@ -198,8 +191,7 @@ def main():
         ks = np.array([float(x) for x in args.sweep_k.split(",")], np.float32)
         res = Scheduler(pol.with_params(k=ks), faults=faults,
                         seeds=args.seed, warm_start=not args.cold,
-                        engine=engine,
-                        easy_eval=args.easy_eval, **scale).run(w)
+                        engine=engine, **scale).run(w)
         E = np.asarray(res.total_energy)
         M = np.asarray(res.makespan)
         print("K,energy_J,makespan_s,dE%,dT%")
@@ -209,8 +201,7 @@ def main():
         return
 
     r = Scheduler(pol, faults=faults, seeds=args.seed,
-                  warm_start=not args.cold, engine=engine,
-                  easy_eval=args.easy_eval, **scale).run(w)
+                  warm_start=not args.cold, engine=engine, **scale).run(w)
     sel = np.asarray(r.system)
     k_str = np.format_float_positional(float(np.asarray(pol.k)), trim="-")
     q_str = pol.queue if pol.queue == "fcfs" else \

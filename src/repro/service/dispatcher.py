@@ -28,7 +28,6 @@ identical remaining decisions.
 from __future__ import annotations
 
 import time
-from functools import partial
 
 import numpy as np
 import jax
@@ -36,7 +35,7 @@ import jax.numpy as jnp
 
 from repro.core.engine import (
     BIG, FaultConfig, Scheduler, Workload, _fault_vec, _job_nodes,
-    _power_totals, _workload_arrays, cons_carry0, event_carry0,
+    _job_totals, _workload_arrays, cons_carry0, event_carry0,
     event_context, make_cons_step, make_event_step,
 )
 from repro.core.policy import Policy
@@ -334,7 +333,7 @@ class Dispatcher:
     # ------------------------------------------------------------ result
     def result(self) -> SimResult:
         """The realized session as a ``SimResult`` over the submitted
-        jobs — totals computed with the batch epilogue's jnp expressions
+        jobs — totals computed by the batch epilogue's ``_job_totals``
         over the accumulated per-job channels, under one jit (the power
         totals' multiply-subtract must fuse exactly as it does inside
         the batch scan's graph), so a full session matches
@@ -342,15 +341,11 @@ class Dispatcher:
         n = self.n_submitted
         arrs, carry = self._arrs, self._carry
 
-        @partial(jax.jit, static_argnames=("n",))
-        def totals(E, wait, T_act, finish, busy, peak, cdel, n):
-            makespan = finish.max() if n else jnp.float32(0.0)
-            return dict(
-                total_energy=E.sum(), makespan=makespan,
-                total_wait=wait.sum(),
-                slowdown_sum=((wait + T_act) / T_act).sum(),
-                max_wait=wait.max() if n else jnp.float32(0.0),
-                **_power_totals(arrs, makespan, busy, peak, cdel))
+        @jax.jit
+        def totals(E, wait, T_act, finish, busy, peak, cdel):
+            makespan = finish.max() if finish.size else jnp.float32(0.0)
+            return _job_totals(arrs, E, wait, T_act, makespan, busy, peak,
+                               cdel)
 
         E = jnp.asarray(self._E[:n])
         wait = jnp.asarray(self._wait[:n])
@@ -359,7 +354,7 @@ class Dispatcher:
         sel = jnp.asarray(self._sys[:n])
         prog = arrs["prog"][:n]
         tot = totals(E, wait, T_act, finish, carry.busy, carry.peak,
-                     carry.cdel, n)
+                     carry.cdel)
         return SimResult(
             **tot,
             busy=carry.busy, C_tab=carry.C_tab, T_tab=carry.T_tab,

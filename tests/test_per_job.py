@@ -5,9 +5,9 @@ and its own node count on each system; the scheduler still predicts and
 learns per class.  Pinned here, on seeded small facilities and streams of
 several widths with ties in arrivals and in node free times:
 
-- every core (arrival FCFS, EASY batched and unrolled, the event core
-  under FCFS, EASY and a power cap, conservative, DVFS tiers) against the
-  float64 mirror ``simulate_py``;
+- every core (arrival FCFS, EASY, the event core under FCFS, EASY and a
+  power cap, conservative, DVFS tiers) against the float64 mirror
+  ``simulate_py``;
 - the chunked and sharded campaign paths and the service (``Dispatcher``,
   ``SessionPool``) against the monolithic batch run, bit for bit;
 - the arrival core's campaign grid against the benchmark's plain
@@ -15,7 +15,8 @@ several widths with ties in arrivals and in node free times:
 - ``workload_from_arrays`` giving each job its trace runtime on the
   reference system and its own width;
 - a workload without per-job columns lowering to the program it lowered
-  to before the columns existed, with the same stage table.
+  to before the columns existed, and one with them lowering to a pinned
+  program, each with its stage table.
 """
 
 import hashlib
@@ -71,8 +72,6 @@ def rigid_workload(n_nodes, J=36, P=4, seed=0, gap=6.0):
 CORES = {
     "arrival": (dict(), dict()),
     "easy": (dict(mode="easy_backfill", queue_window=4), dict()),
-    "easy_unrolled": (dict(mode="easy_backfill", queue_window=3),
-                      dict(easy_eval="unrolled")),
     "events": (dict(core="events"), dict()),
     "events_easy": (dict(core="events", queue="easy_backfill",
                          queue_window=4), dict()),
@@ -110,8 +109,9 @@ def assert_matches_mirror(w, fields, extra):
                                   rp["backfilled"])
     # each job held its own width
     sel = np.asarray(rj.system)
-    np.testing.assert_array_equal(np.asarray(rj.nodes),
-                                  w.n_req_job[np.arange(len(sel)), sel])
+    np.testing.assert_array_equal(
+        np.asarray(rj.nodes), w.n_req[w.prog, sel] if w.n_req_job is None
+        else w.n_req_job[np.arange(len(sel)), sel])
     return rj
 
 
@@ -124,6 +124,16 @@ def test_every_core_matches_the_mirror(core, facility):
     cls_T = w.T_true[w.prog, np.asarray(rj.system)]
     assert not np.allclose(np.asarray(rj.runtime)
                            / np.asarray(rj.runtime).min(), cls_T / cls_T.min())
+
+
+@pytest.mark.parametrize("column", ["t_scale", "n_req_job"])
+@pytest.mark.parametrize("core", list(CORES))
+def test_one_column_alone_matches_the_mirror(core, column):
+    """Per-job widths without per-job runtimes, and runtimes without
+    widths: each core runs the column it has."""
+    w = replace(rigid_workload(FACILITIES["uneven"], seed=len(core)),
+                **{column: None})
+    assert_matches_mirror(w, *CORES[core])
 
 
 def test_mirror_without_columns_is_unchanged():
@@ -274,48 +284,67 @@ def test_trace_replay_keeps_each_jobs_runtime_and_width(calibrate):
 
 
 #: sha256 prefixes of each program's StableHLO text (no source
-#: locations) and of its stage table, as the engine lowered them before
-#: per-job columns existed, with today's kth-free walk (CPU,
-#: ``placer="jnp"`` unless named).  A workload without per-job columns
-#: must lower to exactly these.
+#: locations) and of its stage table (CPU, ``placer="jnp"`` unless named).
+#: The class-only entries are as the engine lowered them before per-job
+#: columns existed, with today's kth-free walk: a workload without per-job
+#: columns must lower to exactly these.  The ``rigid`` entries pin a
+#: workload with both per-job columns (the arrival core's campaign, as a
+#: trace replay runs it, and the event core) as the engine lowered it
+#: before its placement stages became shared helpers.
 PROGRAMS = {
     "arrival": ("b7ecccb64ee9e38b", "936ebd643483a13b"),
     "arrival_pallas": ("069ef7ea7a266118", "e74609028bfaca64"),
     "easy": ("c1e112851129743a", "83a7ba0304366fb9"),
-    "easy_unrolled": ("758b59f5cd03c509", "a84b792efefa9af5"),
     "events": ("59bed6a1e1e8c8ea", "31a598e871275057"),
     "cons": ("832ddfbff5b0487b", "7a29f567105c816f"),
     "chunk": ("a83dfd6375c5d3f5", "935045f5d3dfcee0"),
     "full": ("de9af62e8648a378", "6e9a503b0ec8ddb0"),
+    "rigid": ("d7e36bad6ff8fc15", "bdd1fe87221b038c"),
+    "rigid_events": ("d237b0fa9a2f53bf", "60e7e1e5b6bc328d"),
 }
 SPECS = {"arrival": {}, "arrival_pallas": dict(placer="pallas_interpret"),
          "easy": dict(queue="easy_backfill:window=4"),
-         "easy_unrolled": dict(queue="easy_backfill:window=3",
-                               easy_eval="unrolled"),
          "events": dict(engine="events"),
          "cons": dict(queue="conservative:window=4"),
-         "chunk": dict(chunk=8), "full": {}}
+         "chunk": dict(chunk=8), "full": {},
+         "rigid": {}, "rigid_events": dict(engine="events")}
+WITH_COLUMNS = ("rigid", "rigid_events")
 
 
 def _digest(x) -> str:
     return hashlib.sha256(x.encode()).hexdigest()[:16]
 
 
-@pytest.mark.parametrize("name", list(PROGRAMS))
-def test_workload_without_columns_lowers_to_the_same_program(name):
-    w = make_npb_workload(JSCC_SYSTEMS, repeats=4,
-                          arrivals=np.arange(20, dtype=np.float32) * 7.0)
+def _program_digests(w, name, totals):
+    """(StableHLO digest, stage-table digest, stages) of ``name``'s
+    campaign program over ``w``."""
     pol = make_policy("paper").with_params(
         k=np.asarray([0.0, 0.2], np.float32))
     s = Scheduler(pol, faults=FAULTS[:1] + (FaultConfig(0.05, 2.0),),
                   warm_start=True, **{"placer": "jnp", **SPECS[name]})
-    totals = name != "full"
     text = s.lower(w, totals_only=totals).as_text()
     s.run(w, totals_only=totals)
     table = obs.stage_table()
-    assert "job" not in set(table.values())
-    assert (_digest(text), _digest(repr(sorted(table.items())))) \
-        == PROGRAMS[name]
+    return (_digest(text), _digest(repr(sorted(table.items()))),
+            set(table.values()))
+
+
+@pytest.mark.parametrize("name", [n for n in PROGRAMS
+                                  if n not in WITH_COLUMNS])
+def test_workload_without_columns_lowers_to_the_same_program(name):
+    w = make_npb_workload(JSCC_SYSTEMS, repeats=4,
+                          arrivals=np.arange(20, dtype=np.float32) * 7.0)
+    text, table, stages = _program_digests(w, name, name != "full")
+    assert "job" not in stages
+    assert (text, table) == PROGRAMS[name]
+
+
+@pytest.mark.parametrize("name", WITH_COLUMNS)
+def test_workload_with_columns_lowers_to_the_same_program(name):
+    w = rigid_workload(FACILITIES["jscc"], J=20, seed=9)
+    text, table, stages = _program_digests(w, name, True)
+    assert "job" in stages
+    assert (text, table) == PROGRAMS[name]
 
 
 @pytest.mark.parametrize("spec", [dict(), dict(engine="events"),
